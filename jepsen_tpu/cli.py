@@ -211,7 +211,7 @@ def single_test_cmd(test_fn: Callable[[Dict[str, Any]], Dict[str, Any]],
 
     if args.cmd == "test":
         from jepsen_tpu.ops.cache import init_compilation_cache
-        init_compilation_cache(args.store)
+        init_compilation_cache()
         opts = test_opts_to_map(args)
         for k, v in vars(args).items():
             if k not in opts and v is not None:
@@ -251,15 +251,13 @@ def single_test_cmd(test_fn: Callable[[Dict[str, Any]], Dict[str, Any]],
                     jdir = None
                 fleet_cls = ProcFleet if args.procs else Fleet
                 service = fleet_cls(workers=args.workers,
-                                    store_base=args.store,
                                     journal_dir=jdir,
                                     max_lanes=args.max_lanes,
                                     max_queue_cells=args.max_queue,
                                     telemetry_s=args.telemetry_s)
             else:
                 from jepsen_tpu.serve import CheckService
-                service = CheckService(store_base=args.store,
-                                       max_lanes=args.max_lanes,
+                service = CheckService(max_lanes=args.max_lanes,
                                        max_queue_cells=args.max_queue)
         if args.recorder:
             setter = getattr(service, "set_recorder", None)
@@ -299,7 +297,6 @@ def single_test_cmd(test_fn: Callable[[Dict[str, Any]], Dict[str, Any]],
         service = Fleetport(listen_host=lhost or "0.0.0.0",
                             listen_port=int(lport),
                             lease_s=args.lease_s,
-                            store_base=args.store,
                             journal_dir=jdir,
                             max_lanes=args.max_lanes,
                             max_queue_cells=args.max_queue,
@@ -411,7 +408,7 @@ def test_all_cmd(tests_fn: Callable[[Dict[str, Any]], List[Dict[str, Any]]],
     service = None
     if not args.no_service:
         from jepsen_tpu.serve import CheckService
-        service = CheckService(store_base=args.store)
+        service = CheckService()
     try:
         summary = core.run_tests(tests_fn(dict(opts)),
                                  workers=max(1, args.campaign_workers),

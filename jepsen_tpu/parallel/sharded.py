@@ -11,7 +11,7 @@ are psum-reduced so all shards agree.
 
 The host driver mirrors the single-chip lessons (wgl_tpu.check): LOOKAHEAD
 chunks stay in flight so the per-chunk flags transfer overlaps device
-compute (chunk-boundary polls dominate on tunneled/DCN-attached hosts), an
+compute (each chunk-boundary poll is a device→host round trip), an
 overflow resumes from the pre-chunk snapshot at a peak-informed capacity
 instead of restarting the whole history, and the engine drops back to a
 cheaper per-round shape once a crash-burst's transient demand passes.
@@ -27,13 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level ...
-    from jax import shard_map as _shard_map
-    _NO_CHECK = {"check_vma": False}
-except ImportError:  # ... older versions only under experimental, and the
-    # replication-check kwarg is spelled check_rep there
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NO_CHECK = {"check_rep": False}
+from jax import shard_map as _shard_map
 
 from jepsen_tpu.checker.prep import PreparedHistory, prepare
 from jepsen_tpu.checker.wgl_tpu import (EV_NOP, LOOKAHEAD, _chunk_slicer,
@@ -80,12 +74,12 @@ def _sharded_runner(model: JaxModel, window: int, capacity_per_shard: int,
                 repl)
     out_specs = ((sharded, sharded, sharded) + (repl,) * 14 + (sharded,),
                  repl)
-    # Replication checking off (check_vma / legacy check_rep): closure dedup
-    # sorts the *gathered* global row set, so every shard computes
-    # bit-identical "replicated" scalars (counts, flags), but the
-    # varying-axes checker can't prove that post-all_gather.
+    # Replication checking off (check_vma): closure dedup sorts the
+    # *gathered* global row set, so every shard computes bit-identical
+    # "replicated" scalars (counts, flags), but the varying-axes checker
+    # can't prove that post-all_gather.
     fn = jax.jit(_shard_map(run_chunk, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, **_NO_CHECK))
+                            out_specs=out_specs, check_vma=False))
     _CACHE[key] = fn
     return fn
 
@@ -199,8 +193,8 @@ def check_sharded(model: JaxModel,
         return jax.device_put(jnp.asarray(x), NamedSharding(mesh, P()))
 
     # Whole event stream uploaded once (replicated); chunks are sliced
-    # device-side — a per-chunk host->device put is a blocking RPC on
-    # tunneled/DCN-attached hosts (see wgl_tpu.check).
+    # device-side — a per-chunk host->device put blocks the dispatch
+    # loop (see wgl_tpu.check).
     ev_dev = put_repl(ev)
     slice_chunk = _chunk_slicer(chunk)
 
@@ -222,9 +216,9 @@ def check_sharded(model: JaxModel,
     # overflow is safe because the failed/overflow lanes gate all updates in
     # event_step — speculative chunks are simply discarded on resume.
     # Pipelining pays where the device→host flags transfer has real latency
-    # (tunneled TPU, DCN-attached pod); on the host-platform CPU mesh the
-    # transfer is a memcpy and extra in-flight chunks only cost memory
-    # (measured ~20% slower), so keep the pipeline depth at 1 there.
+    # (an accelerator); on the host-platform CPU mesh the transfer is a
+    # memcpy and extra in-flight chunks only cost memory (measured ~20%
+    # slower), so keep the pipeline depth at 1 there.
     lookahead = (LOOKAHEAD
                  if mesh.devices.flat[0].platform != "cpu" else 1)
     while True:

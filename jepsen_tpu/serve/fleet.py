@@ -178,6 +178,7 @@ class FleetWorker:
         return {"worker": self.wid,
                 "alive": bool(ping.get("alive")),
                 "circuit": self.breaker.state,
+                "platform": ping.get("platform"),
                 "queue-depth": ping.get("queue-depth"),
                 "inflight-cells": ping.get("inflight-cells"),
                 "generation": self.generation,
@@ -421,7 +422,6 @@ class Fleet:
     anywhere they take a service."""
 
     def __init__(self, workers: int = 3, *,
-                 store_base: Optional[str] = None,
                  journal_dir: Optional[str] = None,
                  max_lanes: int = 64,
                  max_queue_cells: int = 4096,
@@ -452,8 +452,7 @@ class Fleet:
         device_sets = _device_sets(n) if pin_devices else [[]] * n
         self.workers: List[FleetWorker] = self._make_workers(
             n, buckets.worker_lane_share(max_lanes, n), device_sets,
-            store_base=store_base, mesh=mesh, capacity=capacity,
-            max_capacity=max_capacity,
+            mesh=mesh, capacity=capacity, max_capacity=max_capacity,
             fail_threshold=breaker_fail_threshold,
             open_s=breaker_open_s)
         self.router = Router(self.workers)
@@ -508,8 +507,7 @@ class Fleet:
         self._hb_thread.start()
 
     def _make_workers(self, n: int, lanes_each: int,
-                      device_sets: List[list], *,
-                      store_base: Optional[str], mesh,
+                      device_sets: List[list], *, mesh,
                       capacity: Optional[int], max_capacity: int,
                       fail_threshold: int,
                       open_s: float) -> List["FleetWorker"]:
@@ -522,8 +520,7 @@ class Fleet:
             def make() -> CheckService:
                 return CheckService(
                     max_queue_cells=self.max_queue_cells,
-                    max_lanes=lanes_each,
-                    store_base=store_base, mesh=mesh,
+                    max_lanes=lanes_each, mesh=mesh,
                     capacity=capacity, max_capacity=max_capacity,
                     device=devs[0] if devs else None)
             return make
@@ -1400,7 +1397,6 @@ class ProcFleet(Fleet):
         self.worker_ready_timeout_s = worker_ready_timeout_s
         self.proxies: List[PairProxy] = []
         self._sup_lock = threading.Lock()
-        self._store_base = kw.get("store_base")
         # subprocess workers already pin nothing useful from the parent;
         # device pinning is the worker process's own business
         kw.setdefault("pin_devices", False)
@@ -1416,8 +1412,7 @@ class ProcFleet(Fleet):
         self._sup_thread.start()
 
     def _make_workers(self, n: int, lanes_each: int,
-                      device_sets: List[list], *,
-                      store_base: Optional[str], mesh,
+                      device_sets: List[list], *, mesh,
                       capacity: Optional[int], max_capacity: int,
                       fail_threshold: int,
                       open_s: float) -> List[FleetWorker]:
@@ -1434,7 +1429,6 @@ class ProcFleet(Fleet):
             self.proxies.append(proxy)
             workers.append(ProcWorker(
                 i, self._make_proc_service(i, lanes, proxy,
-                                           store_base=store_base,
                                            capacity=capacity,
                                            max_capacity=max_capacity),
                 proxy, devices=[],
@@ -1442,7 +1436,6 @@ class ProcFleet(Fleet):
         return workers
 
     def _make_proc_service(self, i: int, lanes: int, proxy: PairProxy, *,
-                           store_base: Optional[str],
                            capacity: Optional[int], max_capacity: int):
         from jepsen_tpu.serve.transport import ProcWorkerService
         from jepsen_tpu.serve.worker_main import (SubprocessWorker,
@@ -1460,7 +1453,6 @@ class ProcFleet(Fleet):
                 launcher = SubprocessWorker(
                     name, os.path.join(log_dir, f"{name}.log"),
                     args={"max-lanes": lanes, "max-queue": mqc,
-                          "store-base": store_base,
                           "capacity": capacity,
                           "max-capacity": max_capacity,
                           "telemetry-s": tele_s},
@@ -1470,7 +1462,6 @@ class ProcFleet(Fleet):
                     name,
                     lambda: CheckService(max_queue_cells=mqc,
                                          max_lanes=lanes,
-                                         store_base=store_base,
                                          capacity=capacity,
                                          max_capacity=max_capacity),
                     telemetry_s=tele_s)

@@ -10,7 +10,7 @@ rules.  See docs/serving.md.
 
 Usage::
 
-    with CheckService(store_base="store") as svc:
+    with CheckService() as svc:
         req = svc.submit(history, kind="wgl", model="cas-register")
         result = req.wait()
         # or one-shot:
@@ -94,16 +94,21 @@ class CheckService:
                  max_queue_cells: int = 4096,
                  max_lanes: int = 64,
                  default_deadline_s: Optional[float] = None,
-                 store_base: Optional[str] = None,
                  mesh=None,
                  capacity: Optional[int] = None,
                  max_capacity: int = 65536,
                  age_s: Optional[float] = None,
                  device=None):
         # Shared init: repeated service processes skip XLA compiles.
+        import jax
         from jepsen_tpu.ops.cache import init_compilation_cache
         from jepsen_tpu.serve.scheduler import DEFAULT_AGE_S
-        init_compilation_cache(store_base)
+        init_compilation_cache()
+        #: the platform this service's device engines run on, as JAX
+        #: reports it — ping()/status carry it so a worker that got no
+        #: chip says "cpu" instead of looking like a chip worker
+        self.platform = (device.platform if device is not None
+                         else jax.default_backend())
         self.max_queue_cells = max_queue_cells
         self.default_deadline_s = default_deadline_s
         self.metrics = Metrics()
@@ -332,6 +337,7 @@ class CheckService:
         The fleet's health checker and ``GET /healthz`` both read this."""
         from jepsen_tpu.engine.fission import fission_threshold
         return {"alive": self.alive(),
+                "platform": self.platform,
                 "queue-depth": self._sched.depth(),
                 "inflight-cells": self._sched.inflight(),
                 "inflight-requests": self._inflight(),

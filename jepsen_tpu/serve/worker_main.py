@@ -5,8 +5,9 @@ process launchers that put it there.
 :class:`~jepsen_tpu.serve.fleet.ProcFleet` supervisor spawns per worker
 slot: it builds one local :class:`~jepsen_tpu.serve.service.CheckService`,
 wraps it in a :class:`WorkerServer` speaking the serve/transport.py frame
-protocol, prints one ``{"ready": true, "port": N, "pid": P}`` line on
-stdout (the launcher's readiness handshake), and serves until SIGTERM.
+protocol, prints one ``{"ready": true, "port": N, "pid": P, "platform":
+"cpu"|"tpu"}`` line on stdout (the launcher's readiness handshake, naming
+the JAX platform the worker actually got), and serves until SIGTERM.
 
 Three layers live here:
 
@@ -450,6 +451,7 @@ class SubprocessWorker:
         self.log_path = log_path
         self.ready_timeout_s = ready_timeout_s
         self.port: Optional[int] = None
+        self.platform: Optional[str] = None  # from the ready line
         # where a client dials this worker back.  A wildcard bind
         # (0.0.0.0/::) is not dialable; local supervision reaches it on
         # loopback, remote fleets advertise a real host via REGISTER.
@@ -465,8 +467,11 @@ class SubprocessWorker:
             os.path.dirname(os.path.abspath(__file__))))
         penv = dict(os.environ)
         penv["PYTHONPATH"] = root + os.pathsep + penv.get("PYTHONPATH", "")
-        penv.setdefault("JAX_PLATFORMS", os.environ.get(
-            "JAX_PLATFORMS", "cpu"))
+        # Nothing assigns a worker process a chip yet (ROADMAP R1), and a
+        # chip belongs to one process, so workers run on XLA-CPU unless
+        # the parent's environment names a platform.  The ready line and
+        # status() report the platform the worker actually got.
+        penv.setdefault("JAX_PLATFORMS", "cpu")
         penv.update(env or {})
         self._log = open(log_path, "ab")
         self.proc = subprocess.Popen(
@@ -505,6 +510,7 @@ class SubprocessWorker:
         if not msg.get("ready"):
             raise RuntimeError(f"worker {self.name} bad ready line: {msg}")
         self.port = int(msg["port"])
+        self.platform = msg.get("platform")
         return self.port
 
     def alive(self) -> bool:
@@ -546,7 +552,7 @@ class SubprocessWorker:
     def status(self) -> Dict[str, Any]:
         return {"kind": "subprocess", "pid": self.proc.pid,
                 "alive": self.alive(), "port": self.port,
-                "log": self.log_path}
+                "platform": self.platform, "log": self.log_path}
 
 
 class ThreadWorker:
@@ -582,7 +588,8 @@ class ThreadWorker:
 
     def status(self) -> Dict[str, Any]:
         return {"kind": "thread", "pid": os.getpid(),
-                "alive": self.alive(), "port": self.server.port}
+                "alive": self.alive(), "port": self.server.port,
+                "platform": self.service.platform}
 
 
 # ---------------------------------------------------------------------------
@@ -713,7 +720,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--port", type=int, default=0)
     ap.add_argument("--max-lanes", type=int, default=64)
     ap.add_argument("--max-queue", type=int, default=4096)
-    ap.add_argument("--store-base", default=None)
     ap.add_argument("--capacity", type=int, default=None)
     ap.add_argument("--max-capacity", type=int, default=None)
     ap.add_argument("--max-frame", type=int, default=MAX_FRAME_BYTES)
@@ -739,8 +745,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         level=logging.INFO, stream=sys.stderr,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     svc_kw: Dict[str, Any] = dict(max_lanes=args.max_lanes,
-                                  max_queue_cells=args.max_queue,
-                                  store_base=args.store_base)
+                                  max_queue_cells=args.max_queue)
     if args.capacity is not None:
         svc_kw["capacity"] = args.capacity
     if args.max_capacity is not None:
@@ -767,7 +772,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     signal.signal(signal.SIGTERM, _on_signal)
     signal.signal(signal.SIGINT, _on_signal)
     print(json.dumps({"ready": True, "port": server.port,
-                      "pid": os.getpid()}), flush=True)
+                      "pid": os.getpid(),
+                      "platform": service.platform}), flush=True)
     while not stop.is_set():
         # the wait is the whole main thread's job; everything else runs
         # on the accept/conn/waiter threads

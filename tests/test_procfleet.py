@@ -201,6 +201,9 @@ class TestWireWorker:
         ping = wire.ping()
         assert ping["alive"] and ping["reachable"]
         assert wire.healthz()["ok"]
+        # the worker says which platform it actually got (conftest: cpu)
+        assert ping["platform"] == "cpu"
+        assert wire.launcher.status()["platform"] == "cpu"
 
     def test_duplicate_submit_same_id_runs_once(self, wire):
         s = _raw_conn(wire)
@@ -371,6 +374,9 @@ class TestSubprocessFleet:
             assert f.check(clean_history(seed=20), kind="wgl",
                            model="cas-register",
                            deadline_s=120.0)["valid"] is True
+            # the ready line names the platform the child really got
+            assert f.workers[0].service.launcher.status()[
+                "platform"] == "cpu"
             pid = f.workers[0].service.launcher.proc.pid
             os.kill(pid, signal.SIGKILL)
             deadline = time.monotonic() + 60

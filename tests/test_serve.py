@@ -584,21 +584,46 @@ class TestSatellites:
         res = checker.check({"name": "t"}, h, {})
         assert list(res["results"]) == history_keys(h)
 
-    def test_compilation_cache_cpu_gated(self, tmp_path, monkeypatch):
+    def test_compilation_cache_cpu_gated(self, monkeypatch):
         from jepsen_tpu.ops.cache import init_compilation_cache
         monkeypatch.delenv("JEPSEN_TPU_CACHE_CPU", raising=False)
         # CPU backend without the override: stays off, never raises
-        assert init_compilation_cache(str(tmp_path)) == ""
+        assert init_compilation_cache() == ""
 
-    def test_compilation_cache_dir_layout(self, tmp_path, monkeypatch):
-        import os
+    @pytest.fixture
+    def cache_config(self, monkeypatch):
+        """The persistent cache armed on the CPU backend, with JAX's
+        process-global cache settings put back afterwards."""
         import jax
-        from jepsen_tpu.ops.cache import init_compilation_cache
         monkeypatch.setenv("JEPSEN_TPU_CACHE_CPU", "1")
-        before = jax.config.jax_compilation_cache_dir
-        try:
-            d = init_compilation_cache(str(tmp_path))
-            assert d.endswith(os.path.join("cache", "xla"))
-            assert os.path.isdir(d)
-        finally:
-            jax.config.update("jax_compilation_cache_dir", before)
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_compile_time_secs")
+        before = {k: getattr(jax.config, k) for k in keys}
+        yield jax.config
+        for k, v in before.items():
+            jax.config.update(k, v)
+
+    def test_compilation_cache_fixed_dir_any_cwd(self, tmp_path,
+                                                 monkeypatch, cache_config):
+        import os
+        import jepsen_tpu
+        from jepsen_tpu.ops import cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.chdir(tmp_path)
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(jepsen_tpu.__file__)))
+        d = cache.init_compilation_cache()
+        # one fixed in-checkout path: not cwd, not any store argument
+        assert d == os.path.join(checkout, "store", "cache", "xla")
+        assert cache_config.jax_compilation_cache_dir == d
+        assert os.path.isdir(d)
+
+    def test_compilation_cache_env_dir_left_alone(self, tmp_path,
+                                                  monkeypatch, cache_config):
+        from jepsen_tpu.ops.cache import init_compilation_cache
+        # JAX reads the variable itself at import; with it set the
+        # program writes no directory of its own over JAX's
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = cache_config.jax_compilation_cache_dir
+        init_compilation_cache()
+        assert cache_config.jax_compilation_cache_dir == before
