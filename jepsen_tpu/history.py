@@ -161,6 +161,16 @@ class History(Sequence):
             self.ops.append(o)
         self._pairs: Optional[np.ndarray] = None
 
+    @classmethod
+    def adopt(cls, ops: List[Op]) -> "History":
+        """A history that takes ``ops`` as its own list: every element an
+        :class:`Op` whose ``index`` is already its position, so nothing is
+        copied or checked."""
+        h = object.__new__(cls)
+        h.ops = ops
+        h._pairs = None
+        return h
+
     # -- Sequence protocol -------------------------------------------------
     def __len__(self) -> int:
         return len(self.ops)

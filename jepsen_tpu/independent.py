@@ -5,6 +5,8 @@ Parity: jepsen.independent (jepsen/src/jepsen/independent.clj): ops carry
 (sequential_generator) or k keys across disjoint thread groups
 (concurrent_generator, independent.clj:213-239); the checker splits the
 history per key and checks each sub-history (independent.clj:266-317).
+The split is one pass over the history (:func:`subhistories`), whatever
+the number of keys.
 
 TPU-first difference: when the sub-checker is a device-tier linearizable
 checker, the per-key sub-histories are checked as ONE vmapped batch sharded
@@ -94,6 +96,27 @@ def subhistory(k, history: History) -> History:
         elif kk == k:
             out.append(op.with_(value=op.value[1]))
     return History(out, reindex=True)
+
+
+def subhistories(history: History) -> Dict[Any, History]:
+    """Every key's sub-history from one pass over ``history``, keys in
+    first-appearance order: equal, op for op and index for index, to
+    ``{k: subhistory(k, history) for k in history_keys(history)}``."""
+    subs: Dict[Any, List[Op]] = {}
+    nemesis: List[Op] = []  # keyless nemesis ops so far: a later key's prefix
+    for op in history:
+        k = key_of(op)
+        if k is not None:
+            ops = subs.get(k)
+            if ops is None:
+                ops = subs[k] = [o.with_(index=i)
+                                 for i, o in enumerate(nemesis)]
+            ops.append(op.with_(value=op.value[1], index=len(ops)))
+        elif op.process == NEMESIS:
+            nemesis.append(op)
+            for ops in subs.values():
+                ops.append(op.with_(index=len(ops)))
+    return {k: History.adopt(ops) for k, ops in subs.items()}
 
 
 class _WrapKey(gen.Generator):
@@ -258,8 +281,8 @@ class IndependentChecker(Checker):
 
     def check(self, test, history, opts=None):
         with span("entry.split", entries=len(history)) as sp:
-            keys = history_keys(history)
-            subs = {k: subhistory(k, history) for k in keys}
+            subs = subhistories(history)
+            keys = list(subs)
             sp.set(keys=len(keys))
         results: Dict[Any, Dict[str, Any]] = {}
 

@@ -18,7 +18,7 @@ from __future__ import annotations
 from typing import List
 
 from jepsen_tpu.history import NEMESIS
-from jepsen_tpu.independent import history_keys, key_of, subhistory
+from jepsen_tpu.independent import key_of, subhistories
 from jepsen_tpu.serve import buckets
 from jepsen_tpu.serve.request import Cell, KIND_ELLE, KIND_WGL, Request
 
@@ -60,8 +60,7 @@ def decompose(req: Request) -> List[Cell]:
     queue.  Sets ``req.cells`` as a side effect."""
     ident = _engine_identity(req)
     if _splittable(req):
-        subs = [(k, subhistory(k, req.history))
-                for k in history_keys(req.history)]
+        subs = list(subhistories(req.history).items())
     else:
         subs = [(None, req.history)]
     cells = []

@@ -19,9 +19,10 @@ from jepsen_tpu import core
 from jepsen_tpu.checker import Stats, wgl_cpu
 from jepsen_tpu.checker.elle import ElleChecker
 from jepsen_tpu.checker.linearizable import Linearizable
-from jepsen_tpu.history import History
+from jepsen_tpu.history import History, INFO, NEMESIS, Op
 from jepsen_tpu.independent import (
-    DEFAULT_WORKERS, IndependentChecker, history_keys, worker_count,
+    DEFAULT_WORKERS, IndependentChecker, history_keys, subhistory,
+    worker_count,
 )
 from jepsen_tpu.models import CASRegister, get_model
 from jepsen_tpu.serve import (
@@ -103,6 +104,36 @@ class TestDecompose:
         # values unwrapped in the sub-histories
         assert all(not isinstance(op.value, tuple) or len(op.value) != 2
                    for c in cells for op in c.history)
+
+    def test_multi_key_cells_equal_per_key_scans(self):
+        # keys interleaved, a nemesis op ahead of every key and one ahead
+        # of the last key only: the cells are what history_keys + one
+        # subhistory scan per key give (key order, histories, buckets)
+        per_key = [[op.with_(process=op.process + 10 * k,
+                             value=(k, op.value))
+                    for op in cas_register_history(30 + 20 * k,
+                                                   concurrency=3, seed=40 + k)]
+                   for k in (2, 0, 1)]
+        nem = Op(process=NEMESIS, type=INFO, f="start")
+        ops = [nem]
+        for i in range(max(map(len, per_key))):
+            if i == 5:
+                ops.append(nem.with_(f="stop"))
+            ops.extend(ks[i] for ks in per_key[:2 if i < 5 else 3]
+                       if i < len(ks))
+        h = History(ops, reindex=True)
+        req = Request(h, "wgl", {"model": get_model("cas-register")})
+        cells = decompose(req)
+        assert [c.key for c in cells] == history_keys(h) == [2, 0, 1]
+        for c in cells:
+            want = subhistory(c.key, h)
+            assert c.history == want
+            assert [o.index for o in c.history] == list(range(len(want)))
+            assert c.bucket[2:] == buckets.wgl_bucket(want)
+            assert c.request is req
+            assert [o.f for o in c.history if o.process == NEMESIS] == [
+                "start", "stop"]
+        assert cells[2].history[1].f == "stop" != cells[0].history[1].f
 
     def test_partially_keyed_never_splits(self):
         h = cas_register_history(40, seed=6)
