@@ -14,6 +14,6 @@ Two axes, matching how the reference scales analysis (SURVEY.md §2.4, §5.7):
 """
 
 from jepsen_tpu.parallel.mesh import make_mesh  # noqa: F401
-from jepsen_tpu.parallel.batch import check_batch  # noqa: F401
+from jepsen_tpu.parallel.batch import batch_stats, check_batch  # noqa: F401
 from jepsen_tpu.parallel.megabatch import check_megabatch  # noqa: F401
 from jepsen_tpu.parallel.sharded import check_sharded  # noqa: F401

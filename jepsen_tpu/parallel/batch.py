@@ -21,6 +21,7 @@ and lanes progress at fully independent rates with no idle steps.
 
 from __future__ import annotations
 
+import threading
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -45,7 +46,29 @@ from jepsen_tpu.engine.ladder import (
 from jepsen_tpu.engine.witness import refuted_result
 from jepsen_tpu.history import History
 from jepsen_tpu.models.base import JaxModel
-from jepsen_tpu.obs.recorder import span
+from jepsen_tpu.obs.recorder import instant, span
+
+# ---------------------------------------------------------------------------
+# Counters (fission_stats idiom): what the lanes asked for against what the
+# batch shape made the device span
+# ---------------------------------------------------------------------------
+
+_STATS_LOCK = threading.Lock()
+_STATS = {"events_useful": 0, "events_dispatched": 0}
+
+
+def batch_stats() -> Dict[str, int]:
+    """Sums over every ``_run_lanes`` pass of this process, in events, so
+    that closure rounds (steps that hold a lane's cursor) count on neither
+    side: ``events_dispatched``, the event slots the passes spanned (padded
+    lanes x the furthest cursor: every lane rides until the last one
+    stops), and ``events_useful``, those of them that held an event of a
+    lane the pass answered.  The rest is what one shared shape costs
+    (lane padding, lanes shorter than the longest) and what a restart from
+    event 0 costs (an overflowed lane's events count for nothing here and
+    are spanned again at the next rung)."""
+    with _STATS_LOCK:
+        return dict(_STATS)
 
 
 def donate_carry_argnums() -> tuple:
@@ -152,6 +175,9 @@ def check_batch(model: JaxModel,
                         "wgl-tpu-batch", f"capacity exceeded at {cap}",
                         **{"capacity-exceeded": True})
                 break
+            for lane in retry:
+                instant("drivers.lane_retry", lane=lane, cap_from=cap,
+                        cap_to=nxt)
             lanes = retry
             cap = nxt
         return out  # type: ignore[return-value]
@@ -179,7 +205,7 @@ def _run_lanes(model: JaxModel, preps, window: int, cap: int,
     gathers the event at the lane's own absolute ``consumed`` cursor, so
     lanes progress at fully independent rates and the host just re-invokes
     until every lane's cursor passes its stream (or fails/overflows)."""
-    with span("drivers.run_lanes", lanes=len(preps), cap=cap):
+    with span("drivers.run_lanes", lanes=len(preps), cap=cap) as sp:
         b = len(preps)
         bpad = b
         if mesh is not None:
@@ -221,9 +247,11 @@ def _run_lanes(model: JaxModel, preps, window: int, cap: int,
                             + [0] * (bpad - b), np.int32)
         failed = np.zeros(bpad, bool)
         overflow = np.zeros(bpad, bool)
+        dispatches = 0
         while True:
             with span("drivers.dispatch"):
                 carry, flags = vrun(carry, batch_dev)
+            dispatches += 1
             with span("drivers.poll"):
                 fl = np.asarray(flags)          # [bpad, 5]
             failed = fl[:, 0].astype(bool)
@@ -237,6 +265,14 @@ def _run_lanes(model: JaxModel, preps, window: int, cap: int,
             if not (~failed & ~overflow
                     & ((consumed < lane_len) | stalled)).any():
                 break
+        # a lane's own events behind its cursor (the chunk's NOP tail is none)
+        done = np.minimum(consumed[:b], [len(p) for p in preps])
+        counts = {"events_useful": int(done[~overflow[:b]].sum()),
+                  "events_dispatched": bpad * int(done.max())}
+        sp.set(dispatches=dispatches, **counts)
+        with _STATS_LOCK:
+            for k, v in counts.items():
+                _STATS[k] += v
 
         failed_op = np.asarray(carry[7])[:b]
         explored = np.asarray(carry[9])[:b]

@@ -239,6 +239,70 @@ class TestOfflinePath:
         assert witness["args"]["configs"] > 0
         assert res["results"][0]["witness"]["valid"] is False
 
+    def test_batch_retries_are_instants_and_a_pass_closes_with_counts(
+            self, rec, model):
+        """A batch that starts at capacity 4: every lane sent up a rung is
+        one ``drivers.lane_retry`` under ``drivers.check_batch``, each pass
+        one ``drivers.run_lanes`` that closes with what it dispatched, and
+        ``batch_stats()`` grows by the same sums."""
+        from jepsen_tpu.parallel import batch_stats, check_batch
+        lanes = [cas_register_history(40, concurrency=4, crash_p=0.02,
+                                      seed=100 + i) for i in range(6)]
+        before = batch_stats()
+        res = check_batch(model, lanes, capacity=4, max_capacity=4096)
+        assert [r["valid"] for r in res] == [True] * 6
+        evs = rec.snapshot()
+        names = by_name(evs)
+        assert set(names) - {"compile.first_call"} == {
+            "drivers.check_batch", "prepare", "drivers.run_lanes",
+            "drivers.stage", "drivers.dispatch", "drivers.poll",
+            "drivers.lane_retry"}
+        root, = names["drivers.check_batch"]
+        passes, retries = names["drivers.run_lanes"], \
+            names["drivers.lane_retry"]
+        assert len(passes) >= 2 and passes[0]["args"]["lanes"] == 6
+        for e in retries:
+            assert parent_of(evs, e) is root and "dur-s" not in e
+            assert set(e["args"]) == {"lane", "cap_from", "cap_to"}
+            assert e["args"]["cap_to"] == 8 * e["args"]["cap_from"]
+        assert len(retries) == sum(p["args"]["lanes"] for p in passes[1:])
+        for p in passes:
+            assert set(p["args"]) == {"lanes", "cap", "dispatches",
+                                      "events_useful", "events_dispatched"}
+            assert p["args"]["dispatches"] == sum(
+                parent_of(evs, d) is p for d in names["drivers.dispatch"])
+            # a pass in which every lane overflowed has nothing useful
+            assert 0 <= p["args"]["events_useful"] \
+                <= p["args"]["events_dispatched"] > 0
+        assert passes[-1]["args"]["events_useful"] > 0
+        stats = batch_stats()
+        assert set(stats) == {"events_useful", "events_dispatched"}
+        for k in stats:
+            assert stats[k] - before[k] == sum(p["args"][k] for p in passes)
+
+    def test_a_lane_that_leaves_for_fission_is_no_retry(self, rec, model,
+                                                        monkeypatch):
+        """Past the fission threshold the lanes that still overflow leave
+        the batch for ``split_check``: no ``drivers.lane_retry``, no second
+        pass."""
+        from jepsen_tpu.engine import fission
+        from jepsen_tpu.parallel import check_batch
+        monkeypatch.setenv("JTPU_FISSION_THRESHOLD", "4")
+        left = []
+        monkeypatch.setattr(
+            fission, "split_check",
+            lambda model, h, **kw: left.append(h) or {
+                "valid": "unknown", "analyzer": fission.ANALYZER})
+        lanes = [cas_register_history(40, concurrency=4, crash_p=0.02,
+                                      seed=100 + i) for i in range(3)]
+        res = check_batch(model, lanes, capacity=4, max_capacity=4096,
+                          fission=True)
+        names = by_name(rec.snapshot())
+        assert "drivers.lane_retry" not in names
+        assert len(names["drivers.run_lanes"]) == 1
+        assert [r["analyzer"] for r in res].count(fission.ANALYZER) \
+            == len(left) > 0
+
     def test_discards_counted_once_each(self, rec, model):
         """A ladder that climbs from 4: every speculative chunk a grow
         throws away is one ``drivers.discard``, and the ``drivers.check``
