@@ -12,10 +12,11 @@ fixed-shape device buffers:
   so a refuted history stops in O(prefix).
 - At a RETURN event the engine expands the configuration closure: a nested
   vmap applies the model step to every (configuration × pending op) pair —
-  [C, W] parallel model steps per round — then the union is deduplicated and
-  compacted by a multi-key sort (ops/dedup.py).  Closure repeats to fixpoint
-  (no genuinely-new kept candidate), then configurations lacking the
-  returning op are pruned.
+  [C, W] parallel model steps per round — the few valid candidates are
+  picked out of that grid by rank and select, and their union with the set
+  is deduplicated and compacted by a multi-key sort (both in ops/dedup.py).
+  Closure repeats to fixpoint (no genuinely-new kept candidate), then
+  configurations lacking the returning op are pruned.
 - Closure is skipped when the set is already closed: pruning on a bit
   preserves closedness (expansions of a surviving configuration also carried
   the bit), so closure is only needed after new ENTERs — the ``dirty`` flag.
@@ -63,7 +64,7 @@ from jepsen_tpu.models.base import JaxModel
 from jepsen_tpu.obs.recorder import instant, span
 from jepsen_tpu.ops import dedup as _dedup
 from jepsen_tpu.ops.cache import init_compilation_cache
-from jepsen_tpu.ops.dedup import compact_rows, sort_dedup_compact
+from jepsen_tpu.ops.dedup import compact_grid, sort_dedup_compact
 
 EV_NOP = 2
 
@@ -108,6 +109,10 @@ CLOSURE_WORK_BUDGET = int(_os.environ.get("JTPU_CLOSURE_BUDGET", "4000000"))
 LEAN_GHOST_MAX = int(_os.environ.get("JTPU_LEAN_GHOSTS", "0"))
 
 
+#: Largest capacity whose candidate compaction fetches rows by one-hot
+#: matmul (see :func:`compaction_form`).  A shape rule, not a knob.
+MATMUL_COMPACT_MAX_C = 8192
+
 #: ``jax.named_scope`` names on the phases of :func:`make_engine`, so that a
 #: device op in a trace says which phase it belongs to (metadata only: the
 #: program is the same with or without them).  In order: the expansion
@@ -135,6 +140,42 @@ def engine_window(window: int) -> int:
     kept as the single source of truth for callers that build
     window-shaped carries outside carry0, e.g. parallel.sharded)."""
     return window
+
+
+def compaction_form(C: int) -> str:
+    """The form of ``ops.dedup.compact_grid`` an engine of capacity ``C``
+    builds: static shape in, static choice out, no knob.  The one-hot
+    matmul costs C x NC and wins up to C 8,192; above, two gathers of NC
+    rows are cheaper (scripts/compact_bench.py on a v5e, table in PERF.md:
+    at C 16,384 the matmul takes 1.7 times the blocks' time, at 4,096 the
+    blocks 1.6 to 2.1 times the matmul's)."""
+    return "matmul" if C <= MATMUL_COMPACT_MAX_C else "blocks"
+
+
+def compact_candidates(step, mask, states, win_ops, cv, NC: int,
+                       form: str):
+    """The first ``NC`` valid cells of the [C, W] candidate grid ``cv`` as
+    candidate rows, in row-major order: ``(cand_mask [NC, MW], cand_states
+    [NC, S], valid [NC], total)``, rows past ``total`` zeroed and cells
+    past ``NC`` dropped — bit for bit what ``ops.dedup.compact_rows`` gives
+    on the flattened grid of ``mask[c] | slot_masks[w]`` and
+    ``step(states[c], win_ops[w])``, without building either: a cell's
+    columns are functions of (c, w), so ``compact_grid`` finds the pair
+    and the ``NC`` successors are stepped here."""
+    W, MW = cv.shape[1], mask.shape[1]
+    (row_mask, row_states), slot, valid, total = compact_grid(
+        cv, [mask, states], NC, form)
+    bit = jnp.left_shift(jnp.uint32(1), (slot % 32).astype(jnp.uint32))
+    slot_mask = jnp.where(jnp.arange(MW)[None, :] == (slot // 32)[:, None],
+                          bit[:, None], jnp.uint32(0))
+    # win_ops[slot] as a one-hot select over [NC, W]: dense, no gather
+    at = slot[:, None] == jnp.arange(W)[None, :]
+    f, a, b = (jnp.where(at, win_ops[None, :, i], 0).sum(1)
+               for i in range(3))
+    ns, _ok = jax.vmap(step)(row_states, f, a, b)
+    keep = valid[:, None]
+    return (jnp.where(keep, row_mask | slot_mask, jnp.uint32(0)),
+            jnp.where(keep, ns.astype(jnp.int32), 0), valid, total)
 
 
 # carry = (mask, states, valid, win_ops, active, dirty, failed, failed_op,
@@ -309,12 +350,20 @@ def make_engine(model: JaxModel, window: int, capacity: int,
         # dropper, whose successors subsume the dropped row's successors.
         #
         # **Candidate compaction** — the valid candidates of a round are
-        # usually far fewer than the C*W expansion grid, so they compact
-        # (stable sort + payload carry, ops.dedup.compact_rows — TPU
-        # scatters serialize per update) into a small buffer and the
-        # merge sorts C + NC rows instead of C*(W+1).  Four merge widths
-        # are compiled (NC = C/2, C, 4C, and the full C*W grid) and
-        # selected per round by the (shard-uniform) candidate count.
+        # usually far fewer than the C*W expansion grid (by the delta rule
+        # the grid is all rows x the one or two fresh slots, then the few
+        # new rows x all slots), so they compact into a small buffer and
+        # the merge sorts C + NC rows instead of C*(W+1).  The compaction
+        # is rank and select (compact_candidates): a candidate is
+        # mask[c] | slot_masks[w] and step(states[c], win_ops[w]), so only
+        # its (c, w) is found, from a running count over the grid's rows,
+        # and the NC successors are stepped from there; the grid itself
+        # is validity bits and is never built as rows (building them and
+        # stable-sorting all C*W to move the few to the front costs
+        # 0.52 ms a round at C 4,096 x W 60 against 0.03 ms, PERF.md).
+        # Four merge widths are compiled (NC = C/2, C, 4C, and the full
+        # C*W grid, the only one that still builds every row) and selected
+        # per round by the (shard-uniform) candidate count.
         #
         # ``budget`` caps the fixpoint iterations of THIS call: a closure
         # that runs out pauses (returns converged=False) with the partial —
@@ -393,16 +442,13 @@ def make_engine(model: JaxModel, window: int, capacity: int,
             return new_mask, new_states, out_valid, cur_new2, total, \
                 new_rows, ovf | ovf2
 
-        def compact_to(cand_mask, cand_states, cv, NC):
-            """Compact the [C, W] candidate grid's valid rows into NC rows
-            (stable sort + gather; a scatter here serialized over all C*W
-            grid rows on TPU and was the closure's single hottest op —
-            see ops.dedup.compact_rows)."""
+        def compact_to(mask, states, cv, NC):
+            """The round's valid candidates as NC rows (rank and select
+            over the grid, see compact_candidates)."""
             with jax.named_scope("wgl.compact"):
-                (cm, cs), cvv, _total = compact_rows(
-                    [cand_mask.reshape(C * W, MW),
-                     cand_states.reshape(C * W, S)],
-                    cv.reshape(C * W), NC)
+                cm, cs, cvv, _total = compact_candidates(
+                    step, mask, states, win_ops, cv, NC,
+                    compaction_form(C))
             return cm, cs, cvv
 
         def cond(c):
@@ -413,7 +459,7 @@ def make_engine(model: JaxModel, window: int, capacity: int,
             mask, states, valid, cur_new, count, _, ovf, it = c
             # Full-window expansion grid, gated by the delta rule.
             with jax.named_scope("wgl.expand"):
-                cand_states, ok = expand(states, win_ops)      # [C, W, S]
+                _, ok = expand(states, win_ops)                # [C, W]
                 has = ((mask[:, None, :]
                         & slot_masks[None, :, :]) != 0).any(-1)
                 round0 = it == 0
@@ -422,7 +468,6 @@ def make_engine(model: JaxModel, window: int, capacity: int,
                 cv = row_gate[:, None] & slot_gate[None, :] & ~has & ok
                 if enable is not None:  # lane-level gate (single-round)
                     cv = cv & enable
-                cand_mask = mask[:, None, :] | slot_masks[None, :, :]
                 nv = cv.sum().astype(jnp.int32)
             nv_max = (lax.pmax(nv, axis_name)
                       if axis_name is not None else nv)
@@ -431,16 +476,26 @@ def make_engine(model: JaxModel, window: int, capacity: int,
             def merge_compacted(NC):
                 def f(args):
                     mask, states, valid, cur_new, ovf = args
-                    cm, cs, cvv = compact_to(cand_mask, cand_states, cv, NC)
+                    cm, cs, cvv = compact_to(mask, states, cv, NC)
                     return merge_rows(mask, states, valid, cm, cs, cvv, ovf)
                 return f
 
+            def grid_rows(mask, states):
+                """Every cell of the grid as a candidate row: only the
+                full-width merges build it (the compacted ones step their
+                NC successors themselves), so it is made inside their
+                branch and costs the usual round nothing."""
+                with jax.named_scope("wgl.expand"):
+                    cand_states, _ = expand(states, win_ops)   # [C, W, S]
+                    cand_mask = mask[:, None, :] | slot_masks[None, :, :]
+                return (cand_mask.reshape(C * W, MW),
+                        cand_states.reshape(C * W, S))
+
             def merge_full(args):
                 mask, states, valid, cur_new, ovf = args
-                return merge_rows(mask, states, valid,
-                                  cand_mask.reshape(C * W, MW),
-                                  cand_states.reshape(C * W, S),
-                                  cv.reshape(C * W), ovf)
+                flat_mask, flat_states = grid_rows(mask, states)
+                return merge_rows(mask, states, valid, flat_mask,
+                                  flat_states, cv.reshape(C * W), ovf)
 
             def merge_full_tiled(args):
                 """Full-grid merge as a fold over candidate tiles, each
@@ -460,8 +515,7 @@ def make_engine(model: JaxModel, window: int, capacity: int,
                 the this-round frontier through the folds (origin-2
                 protocol in merge_rows)."""
                 mask, states, valid, cur_new, ovf = args
-                flat_mask = cand_mask.reshape(C * W, MW)
-                flat_states = cand_states.reshape(C * W, S)
+                flat_mask, flat_states = grid_rows(mask, states)
                 flat_cv = cv.reshape(C * W)
                 budget_rows = max(_dedup.WIDE_SORT_ROWS // num_shards - C,
                                   C)

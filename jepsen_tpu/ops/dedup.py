@@ -1,15 +1,37 @@
-"""Sort-based row deduplication with fixed-capacity compaction.
+"""Row deduplication and compaction in fixed-capacity buffers.
 
 The TPU search's configuration sets live in fixed-shape buffers; after each
 closure expansion the union of (existing ∪ candidate) rows must be
-deduplicated and compacted back to capacity.  Rows are fully described by
-their key columns, so a multi-operand lexicographic ``lax.sort`` (invalid rows
-keyed last), a neighbour-equality pass, and a stable-sort compaction
-(compact_rows — TPU scatters serialize per update, sorts don't) do the
-whole job with static shapes — no host round-trips, no dynamic allocation.
+deduplicated and compacted back to capacity, with static shapes, no host
+round-trips and no dynamic allocation.  Three pieces:
 
-This replaces what knossos does with JVM hash sets of configuration objects;
-sort+compare is the shape XLA tiles well.
+- :func:`sort_dedup_compact`: rows are fully described by their key
+  columns, so a multi-operand lexicographic ``lax.sort`` (invalid rows keyed
+  last), a neighbour-equality pass and a compaction do the dedup.  This
+  replaces what knossos does with JVM hash sets of configuration objects.
+- :func:`compact_rows`: the compaction of an arbitrary bag of rows, one
+  stable sort keyed on ``~keep`` with the columns as payload.  Its cost is
+  the sort's, whatever the number kept: right for the dedup's own C + NC
+  rows, where most are kept.
+- :func:`compact_grid`: the compaction of a [C, W] grid whose cells are
+  functions of (row, slot), by rank and select: a running count by row
+  says which row and which of its set cells each output is, and nothing
+  but the NC outputs is ever moved.  The closure's candidate compaction
+  (``checker.wgl_tpu.compact_candidates``) keeps a few hundred to a few
+  thousand of C*W cells, and did it with :func:`compact_rows` until PR 28:
+  one 245,760-row sort a round, 58% of the 10k-op crash cell's verdict.
+
+What a TPU v5e charges (``scripts/compact_bench.py`` and the traces of
+the benchmark's cells; PERF.md, PR 28).  The old candidate compaction at
+C 4,096 x W 60, grid and sort: 0.52 ms a round; rank and select 0.03 ms.
+A gather runs one element after another, 5 to 8 ns each (a binary search
+of 12 rounds over 2,048 outputs: 0.19 ms; each 6,144-element gather of the
+subsumption probe below: 0.04 ms), so a gather is cheap only where its
+OUTPUT is small, and a search by gathers never is.  A [NC, C] one-hot
+times a narrow table on the MXU, the one-hot made inside the dot's fusion,
+beats every form that gathers up to C 8,192.  Scatters were measured
+slower than sorts by the sessions before the benchmark and have not been
+measured since: nothing here scatters.
 """
 
 from __future__ import annotations
@@ -19,6 +41,7 @@ from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: Subsumption probe count (earlier in-group rows checked per row).  Read at
 #: import time; engines embed it in their cache keys (see wgl_tpu.make_engine)
@@ -49,29 +72,24 @@ SUBSUME = os.environ.get("JTPU_SUBSUME", "1") != "0"
 def compact_rows(cols: Sequence[jnp.ndarray], keep: jnp.ndarray,
                  capacity: int):
     """Stable compaction of the rows where ``keep`` into ``capacity``-row
-    buffers via one stable sort + GATHER — no scatter.
+    buffers via one stable sort (no scatter).
 
-    TPU scatters serialize over their updates (a C*W-row grid compaction
-    measured 60 us per scatter — the single hottest op in the whole
-    closure, 42% of device time), while sorts and gathers are parallel
-    (the same merge's 1536-row variadic sort: 6 us).  A single stable
-    2-operand sort of ``(~keep, iota)`` ranks the kept rows' indices
-    first, in order — the whole inverse map in one parallel op; the rows
-    then GATHER into place.  Rows past the kept count are masked to zero
-    to keep the old scatter semantics (callers rely on valid-gating, but
-    zeroed tails keep artifacts reproducible).  Rows past ``capacity``
-    are silently truncated, exactly like the scatter's ``mode="drop"`` —
-    callers detect that via ``total``.
+    A single stable sort keyed on ``~keep`` with every column riding as a
+    payload operand puts the kept rows first, in order: payloads do not
+    enter the comparator (``num_keys=1``), the permutation network carries
+    them.  The cost is that of sorting all ``n`` rows, however few are
+    kept, so this is for bags of rows of which most are kept
+    (:func:`sort_dedup_compact`'s final step); a grid of which few are,
+    and whose rows can be recomputed from their position, goes through
+    :func:`compact_grid`.  Rows past the kept count are zeroed (callers
+    gate on ``valid``, but zeroed tails keep artifacts reproducible).
+    Rows past ``capacity`` are silently truncated — callers detect that
+    via ``total``.
 
     Returns ``(out_cols, out_valid, total)``.
     """
     n = keep.shape[0]
     total = jnp.sum(keep.astype(jnp.int32))
-    # One stable single-KEY sort with every column riding along as a
-    # payload operand: payloads don't enter the comparator (num_keys=1),
-    # they are just carried by the permutation network — so the kept rows
-    # land first, in order, with zero per-column gathers (TPU row-gathers
-    # serialize like scatters; 4 of them cost 30 us/round before this).
     flat, meta = [], []
     for c in cols:
         if c.ndim == 1:
@@ -111,6 +129,97 @@ def compact_rows(cols: Sequence[jnp.ndarray], keep: jnp.ndarray,
                                    for j in range(m)], axis=-1))
             k += m
     return outs, out_valid, total
+
+
+def compact_grid(cv: jnp.ndarray, row_cols: Sequence[jnp.ndarray],
+                 capacity: int, form: str = "matmul"):
+    """The first ``capacity`` set cells of the bool grid ``cv`` ([C, W]), in
+    row-major order, by rank and select: for each of them its row's entry
+    of every column in ``row_cols`` (each [C, n], 32-bit) and its
+    slot.  The same cells in the same order as :func:`compact_rows` on the
+    flattened grid, ``valid`` and ``total`` alike, without moving the C*W
+    cells: where a cell's columns are functions of (row, slot), finding
+    the pair is enough.
+
+    1. ``incl`` = running count of set cells by row (C elements), ``excl``
+       the same less the row's own: output ``j`` is the cell of rank
+       ``j - excl[r]`` in the one row ``r`` with ``excl[r] <= j < incl[r]``.
+    2. A table row holds the row's cells packed into bit words, its
+       ``excl`` and its ``row_cols``; each output fetches its row's entry:
+
+       - ``"matmul"``: the one-hot [capacity, C] of step 1 times the
+         table's bytes on the MXU (exact: 0/1 and 0..255 are exact in
+         bf16, one product is non-zero, float32 accumulates), the one-hot
+         made inside the dot's fusion and never stored.  No search, no
+         gather; C x capacity work, the cheapest form up to C 8,192;
+       - ``"blocks"``: ``r`` by a two-level count (which block of about
+         sqrt(C) rows, then which row inside the gathered block), then one
+         row gather of the table.  Two gathers of ``capacity`` rows (about
+         7 ns a row on a v5e) and no C x capacity term, for large C.
+    3. The slot is the position of the row's (k+1)-th set cell, counted
+       with popcounts over [capacity, W].
+
+    Returns ``(out_cols, slot, valid, total)``.  ``valid[j] = j < total``;
+    rows past ``total`` hold whatever the fetch found there and are the
+    caller's to zero; cells past ``capacity`` are dropped silently, which
+    callers detect through ``total``.
+    """
+    C, W = cv.shape
+    n_words = (W + 31) // 32
+    cnt = cv.sum(1, dtype=jnp.int32)
+    incl = jnp.cumsum(cnt)
+    excl = incl - cnt
+    total = incl[-1]
+    j = jnp.arange(capacity, dtype=jnp.int32)
+    bit_of = np.uint32(1) << (np.arange(W) % 32).astype(np.uint32)
+    words = [(cv[:, lo:lo + 32] * jnp.asarray(bit_of[lo:lo + 32])[None, :])
+             .sum(1, dtype=jnp.uint32) for lo in range(0, W, 32)]
+
+    def as_words(c):
+        return jax.lax.bitcast_convert_type(c, jnp.uint32)
+
+    table = jnp.concatenate([jnp.stack(words, -1), as_words(excl[:, None])]
+                            + [as_words(c) for c in row_cols], axis=1)
+    K = table.shape[1]
+    if form == "matmul":
+        onehot = (excl[None, :] <= j[:, None]) & (j[:, None] < incl[None, :])
+        table_bytes = jnp.stack([(table >> (8 * i)) & 0xFF
+                                 for i in range(4)], -1).reshape(C, 4 * K)
+        got = jnp.dot(onehot.astype(jnp.bfloat16),
+                      table_bytes.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+        got = got.astype(jnp.uint32).reshape(capacity, K, 4)
+        got = (got[..., 0] | (got[..., 1] << 8) | (got[..., 2] << 16)
+               | (got[..., 3] << 24))
+    else:
+        B = 1 << ((C - 1).bit_length() + 1) // 2
+        n_blocks = -(-C // B)
+        # padding reads ``total``: never <= a j that is valid
+        blocks = jnp.concatenate(
+            [incl, jnp.full(n_blocks * B - C, total)]).reshape(n_blocks, B)
+        blk = jnp.minimum(
+            (blocks[None, :, -1] <= j[:, None]).sum(1, dtype=jnp.int32),
+            n_blocks - 1)
+        row = blk * B + (jnp.take(blocks, blk, axis=0)
+                         <= j[:, None]).sum(1, dtype=jnp.int32)
+        got = jnp.take(table, jnp.minimum(row, C - 1), axis=0)
+    k = j - got[:, n_words].astype(jnp.int32)
+    # below[j, p] = set cells of the row at positions <= p
+    below = 0
+    for i in range(n_words):
+        upto = np.where(np.arange(W) // 32 > i, np.uint32(0xFFFFFFFF),
+                        np.where(np.arange(W) // 32 == i,
+                                 (bit_of << np.uint32(1)) - np.uint32(1),
+                                 np.uint32(0))).astype(np.uint32)
+        below = below + jax.lax.population_count(
+            got[:, i, None] & jnp.asarray(upto)[None, :]).astype(jnp.int32)
+    slot = jnp.minimum((below <= k[:, None]).sum(1, dtype=jnp.int32), W - 1)
+    outs, at = [], n_words + 1
+    for c in row_cols:
+        outs.append(jax.lax.bitcast_convert_type(
+            got[:, at:at + c.shape[1]], c.dtype))
+        at += c.shape[1]
+    return outs, slot, j < total, total
 
 
 def _lex_perm(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:

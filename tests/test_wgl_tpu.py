@@ -1,6 +1,9 @@
 """Device engine vs CPU oracle: differential testing on golden and
 synthesized histories (runs on the virtual-CPU jax backend in CI)."""
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -108,6 +111,173 @@ class TestCompactRows:
             assert a.tolist() == b.tolist()
         assert ref[1].tolist() == got[1].tolist()
         assert int(ref[2]) == int(got[2])
+
+
+def _grid_case(C, W, density, seed, model):
+    """A random [C, W] candidate grid: the engine's inputs, and the two
+    flattened columns the sort used to carry."""
+    rng = np.random.default_rng(seed)
+    MW, S = (W + 31) // 32, model.state_size
+    mask = rng.integers(0, 2**32, (C, MW), dtype=np.uint32)
+    states = rng.integers(0, 5, (C, S)).astype(np.int32)
+    win_ops = np.zeros((W, 6), np.int32)
+    win_ops[:, 0] = rng.integers(0, 3, W)
+    win_ops[:, 1:3] = rng.integers(0, 5, (W, 2))
+    cv = rng.random((C, W)) < density
+    return tuple(jnp.asarray(x) for x in (mask, states, win_ops, cv))
+
+
+@functools.lru_cache(maxsize=None)
+def _compact_bench():
+    """scripts/compact_bench.py as a module: it holds the one copy of the
+    compaction as it was before rank and select (``sort_compaction``)."""
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "scripts" \
+        / "compact_bench.py"
+    spec = importlib.util.spec_from_file_location("compact_bench", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def sort_compaction(step, mask, states, win_ops, cv, NC, form=None):
+    """The reference: every cell's row, then one stable sort of all C*W of
+    them (``compact_rows``); ``form`` is taken and ignored so that it can
+    stand in for ``compact_candidates`` inside an engine."""
+    return _compact_bench().sort_compaction(step, mask, states, win_ops,
+                                            cv, NC)
+
+
+class TestCompactGrid:
+    """Candidate compaction by rank and select is ``compact_rows`` on the
+    flattened grid, bit for bit: columns, ``valid`` and ``total``."""
+
+    FORMS = ("matmul", "blocks")
+    new = staticmethod(jax.jit(wgl_tpu.compact_candidates,
+                               static_argnums=(0, 5, 6)))
+    old = staticmethod(jax.jit(sort_compaction, static_argnums=(0, 5)))
+
+    @staticmethod
+    def _same(got, want):
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("density", [0.0, 0.01, 0.2, 1.0])
+    @pytest.mark.parametrize("W", [8, 12, 45, 60])
+    def test_equals_the_sort_on_the_flattened_grid(self, W, density):
+        model = get_model("cas-register")
+        args = _grid_case(24, W, density, 1000 * W + int(100 * density),
+                          model)
+        total = int(args[3].sum())
+        # NC below, at and above the count of valid cells
+        for NC in sorted({max(1, total // 2), max(1, total), total + 7}):
+            want = self.old(model.step, *args, NC)
+            assert int(want[3]) == total
+            for form in self.FORMS:
+                self._same(self.new(model.step, *args, NC, form), want)
+
+    @pytest.mark.parametrize("form", FORMS)
+    def test_state_of_several_lanes(self, form):
+        # multi-register: 3 state lanes ride the fetch as 3 columns
+        model = get_model("multi-register")
+        args = _grid_case(37, 20, 0.3, 5, model)
+        self._same(self.new(model.step, *args, 64, form),
+                   self.old(model.step, *args, 64))
+
+    @pytest.mark.parametrize("form", FORMS)
+    def test_under_vmap_lanes_of_different_density(self, form):
+        model = get_model("cas-register")
+        lanes = [_grid_case(32, 12, d, i, model)
+                 for i, d in enumerate([0.0, 0.05, 1.0])]
+        batched = tuple(jnp.stack(x) for x in zip(*lanes))
+        got = jax.jit(jax.vmap(lambda m, s, o, c: wgl_tpu.compact_candidates(
+            model.step, m, s, o, c, 40, form)))(*batched)
+        for i, lane in enumerate(lanes):
+            self._same([g[i] for g in got],
+                       self.old(model.step, *lane, 40))
+
+    def test_the_chip_tool_runs_and_its_forms_agree(self):
+        tool = _compact_bench()
+        rows = [tool.bench(get_model("cas-register"), 32, 12, 16, lanes,
+                           form, 2, 0.3)
+                for lanes in (0, 2) for form in tool.FORMS]
+        assert len({r["checksum"] for r in rows[:3]}) == 1
+        assert len({r["checksum"] for r in rows[3:]}) == 1
+        assert all(r["candidates_per_round"] > 16 for r in rows)
+
+    def test_form_by_capacity(self):
+        assert [wgl_tpu.compaction_form(c)
+                for c in (256, 4096, 8192, 16384, 65536)] == \
+            ["matmul"] * 3 + ["blocks"] * 2
+
+
+class TestCompactionInTheEngine:
+    """The engines with rank and select against the same engines with the
+    old sort, and against the host oracle, on ghost-bearing histories (an
+    engine's ``configs-explored`` is its own count, not the oracle's: the
+    old compaction is what it has to equal)."""
+
+    @staticmethod
+    def _refuted(seed):
+        return corrupt_reads(cas_register_history(
+            120, concurrency=8, crash_p=0.04, seed=seed), n=1, seed=seed)
+
+    @staticmethod
+    def _fresh_engines(monkeypatch):
+        # engines are cached by shape: a patched compaction needs new ones
+        from collections import OrderedDict
+        from jepsen_tpu.engine import cache
+        monkeypatch.setattr(cache.CACHE, "_d", OrderedDict())
+
+    @staticmethod
+    def _agree(got, want, host):
+        assert got["valid"] is want["valid"] is host["valid"]
+        assert got["configs-explored"] == want["configs-explored"]
+        assert got.get("op", {}).get("index") == \
+            want.get("op", {}).get("index") == \
+            host.get("op", {}).get("index")
+
+    def test_single_history_engine_in_all_three_widths(self, monkeypatch):
+        seen = []
+        real = wgl_tpu.compact_candidates
+
+        def spy(step, mask, states, win_ops, cv, NC, form):
+            out = real(step, mask, states, win_ops, cv, NC, form)
+            jax.debug.callback(
+                lambda t, C=cv.shape[0]: seen.append((NC, C, int(t))),
+                out[3])
+            return out
+
+        model, h = get_model("cas-register"), self._refuted(16)
+        self._fresh_engines(monkeypatch)
+        monkeypatch.setattr(wgl_tpu, "compact_candidates", spy)
+        got = wgl_tpu.check(model, h, capacity=128, chunk=64)
+        jax.effects_barrier()
+        self._fresh_engines(monkeypatch)
+        monkeypatch.setattr(wgl_tpu, "compact_candidates", sort_compaction)
+        want = wgl_tpu.check(model, h, capacity=128, chunk=64)
+        self._agree(got, want, wgl_cpu.check(CASRegister(), h))
+        assert got["valid"] is False and got["op"]["index"] == 88
+        # a round ran in each compacted width: C/2, C and 4C
+        ran = {(2 * NC) // C for NC, C, total in seen if total > 0}
+        assert ran >= {1, 2, 8}, sorted(ran)
+
+    def test_check_batch(self, monkeypatch):
+        from jepsen_tpu.parallel import check_batch
+        model = get_model("cas-register")
+        hs = [self._refuted(16), self._refuted(7),
+              cas_register_history(120, concurrency=8, crash_p=0.04,
+                                   seed=8)]
+        self._fresh_engines(monkeypatch)
+        got = check_batch(model, hs, capacity=128)
+        self._fresh_engines(monkeypatch)
+        monkeypatch.setattr(wgl_tpu, "compact_candidates", sort_compaction)
+        want = check_batch(model, hs, capacity=128)
+        assert [g["valid"] for g in got] == [False, False, True]
+        for h, g, w in zip(hs, got, want):
+            self._agree(g, w, wgl_cpu.check(CASRegister(), h))
 
 
 class TestLeanEngine:
