@@ -24,8 +24,9 @@ host oracles (``wgl_cpu``, host ``elle``) for verdict parity:
   5  report   compile-cache directory non-empty; one JSON report line,
               then the device stamp
 
-The histories are ``bench.py``'s shapes and, at ``--seed 0``, its seeds;
-``--seed`` shifts every generator seed.  Each phase runs its host
+The histories are the benchmark's shapes (``BENCHMARK.json``: 10k-op
+cas-register histories, 200-op crash-bearing keyed lanes) from ``synth``'s
+generators; ``--seed`` shifts every generator seed.  Each phase runs its host
 oracles on a second thread beside the device work: XLA compiles release
 the interpreter lock, so a cold run hides the oracles (about a quarter of
 the serial wall) inside them.  Walls are therefore cold observations of
@@ -213,7 +214,7 @@ def phase_hard(seed: int, n_ops: int = 10_000,
 # ---------------------------------------------------------------------------
 
 def keyed_lanes(seed: int, n: int, n_ops: int) -> List[Any]:
-    """``bench.py``'s lane shape: short crash-bearing per-key histories,
+    """The keyed lane shape: short crash-bearing per-key histories,
     every fourth refuted by one corrupted read."""
     from jepsen_tpu.synth import cas_register_history, corrupt_reads
     hs = [cas_register_history(n_ops, concurrency=6, crash_p=0.005,
@@ -406,8 +407,7 @@ def phase_report(watch: CompileWatch) -> Dict[str, Any]:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0,
-                    help="shift every generator seed (default 0: "
-                         "bench.py's own histories)")
+                    help="shift every generator seed (default 0)")
     args = ap.parse_args(argv)
     t_start = time.monotonic()
     out = phase_device()

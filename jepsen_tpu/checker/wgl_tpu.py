@@ -41,7 +41,6 @@ device-local slice of the deduplicated global set.
 from __future__ import annotations
 
 import math
-import os as _os
 from collections import deque
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -93,7 +92,7 @@ LOOKAHEAD = 2
 # unchanged.  At capacity 65536 this is 61 iterations/dispatch, which
 # stays inside the watchdog even when rounds take the full-grid fallback
 # merge.)
-CLOSURE_WORK_BUDGET = int(_os.environ.get("JTPU_CLOSURE_BUDGET", "4000000"))
+CLOSURE_WORK_BUDGET = 4_000_000
 
 #: Histories with at most this many ghost (crashed/info) ops run the LEAN
 #: engine (``gwords=0``): ghost bits stay plain identity mask bits and the
@@ -106,7 +105,7 @@ CLOSURE_WORK_BUDGET = int(_os.environ.get("JTPU_CLOSURE_BUDGET", "4000000"))
 #: 2.2M configs and forced capacity 16384 (18.5 s vs 6.6 s): the antichain
 #: collapse matters at ANY ghost count, so lean is only for histories with
 #: no ghosts at all, where it saves the machinery with nothing to lose.
-LEAN_GHOST_MAX = int(_os.environ.get("JTPU_LEAN_GHOSTS", "0"))
+LEAN_GHOST_MAX = 0
 
 
 #: Largest capacity whose candidate compaction fetches rows by one-hot
@@ -132,14 +131,6 @@ def closure_budget(capacity: int) -> int:
     pass that product so one dispatch's wall-clock stays at the same bound
     everywhere."""
     return max(16, CLOSURE_WORK_BUDGET // max(1, capacity))
-
-
-def engine_window(window: int) -> int:
-    """The slot count an engine built for ``window`` actually uses (the
-    delta closure expands the full window at once, so no block padding —
-    kept as the single source of truth for callers that build
-    window-shaped carries outside carry0, e.g. parallel.sharded)."""
-    return window
 
 
 def compaction_form(C: int) -> str:
@@ -234,10 +225,6 @@ def make_engine(model: JaxModel, window: int, capacity: int,
     progress at fully independent rates with no idle steps.
     """
     assert window > 0
-    # Callers building window-shaped carries outside carry0
-    # (parallel.sharded) must use
-    # engine_window() for the same padding.
-    window = engine_window(window)
     # work_budget: None = capacity-scaled default; <= 0 = unlimited
     # (escape hatch for callers that manage their own bounds — the
     # shipped drivers all pass a real budget: the batch driver resumes

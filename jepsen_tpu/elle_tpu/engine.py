@@ -39,6 +39,7 @@ from jepsen_tpu.engine.fallback import (
 from jepsen_tpu.engine.groups import (
     MAX_LANES_PER_GROUP, bounded_group_cap,
 )
+from jepsen_tpu.engine.ladder import pad_words
 from jepsen_tpu.history import History
 
 log = logging.getLogger(__name__)
@@ -101,7 +102,6 @@ def check_batch(histories: Sequence[History],
     # Floor padding shares the ladder's word rounding with padded_n —
     # one derivation, so the serve elle bucket and a floorless call land
     # on identical rungs.
-    from jepsen_tpu.engine.ladder import pad_words
     n_pad = max(padded_n(encs), pad_words(n_pad_floor))
     cap = group_cap(n_pad)
     use_device = engine != "cpu" and available()

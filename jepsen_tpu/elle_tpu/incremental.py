@@ -61,7 +61,7 @@ import numpy as np
 from jepsen_tpu.elle_tpu.closure import _layer, lane_flags_fn
 from jepsen_tpu.elle_tpu.encode import KINDS, EncodedHistory, encode
 from jepsen_tpu.engine.budget import Deadline
-from jepsen_tpu.engine.ladder import pad_words
+from jepsen_tpu.engine.ladder import MIN_N_BUCKET, pad_words, pow2_at_least
 from jepsen_tpu.monitor.epochs import ElleEpochEngine
 
 
@@ -241,10 +241,9 @@ class IncrementalElleEngine(ElleEpochEngine):
 
     def _incremental_check(self, h) -> Dict[str, Any]:
         from jepsen_tpu.elle_tpu.anomalies import finish_lane
-        from jepsen_tpu.serve import buckets
 
         enc = encode(h, self.workload)
-        n_pad = buckets.pow2_at_least(max(1, enc.n), buckets.MIN_N_BUCKET)
+        n_pad = pow2_at_least(max(1, enc.n), MIN_N_BUCKET)
         edges = _edge_set(enc)
         warm = self._warm(enc, edges, n_pad)
         if warm and self._state is not None:

@@ -8,8 +8,8 @@ compile for, where do compiled engines live, how long may I run, what
 happens when the device fails, and what evidence must a refutation
 carry.  This package owns the one answer to each:
 
-- ``ladder``   — the pow2 bucket/shape ladder (derivations in
-  serve/buckets.py; the engine-side shape/chunk/window math here);
+- ``ladder``   — the pow2 bucket/shape ladder: the rungs and the
+  shape/chunk/window/capacity math derived from them;
 - ``cache``    — the bounded LRU compiled-engine cache and its shared
   process-wide instance;
 - ``groups``   — lane grouping under the 512-lane vmap cap;

@@ -5,7 +5,7 @@ vmapped batch engine, megabatch's grouped runners) pins jitted
 executables whose size scales with window*capacity*chunk; a service that
 sees many shapes would grow an unbounded dict without end.  One shared
 LRU keeps the hot buckets resident across *all* consumers — the bucket
-ladder (serve/buckets.py) bounds the key universe, this cache bounds the
+ladder (engine/ladder.py) bounds the key universe, this cache bounds the
 resident set — and its hit/miss/eviction counters feed the serve metrics
 endpoint (an eviction storm means the ladder is too fine).
 

@@ -60,6 +60,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.history import FAIL, History, INFO, INVOKE, OK, Op
 from jepsen_tpu.models.base import JaxModel, UNKNOWN32
 from jepsen_tpu.obs.hist import HistogramSet
@@ -515,7 +516,6 @@ def subproblem_floors(subs: Sequence[History]) -> Tuple[int, int]:
     dispatch — every lane rides the same compiled shape, and both floors
     are ladder images (never raw history shapes): the TRACE02 seam the
     trace lint runs the real derivation through."""
-    from jepsen_tpu.engine import ladder
     return (max(ladder.width_bucket(h) for h in subs),
             max(ladder.events_bucket(h) for h in subs))
 
@@ -534,12 +534,11 @@ def _dispatch_subproblems(model: JaxModel, subs: Sequence[History], *,
     w_floor, ev_floor = subproblem_floors(subs)
     from jepsen_tpu.parallel.megabatch import megabatch_enabled
     if len(subs) >= 4 and megabatch_enabled() \
-            and ev_floor <= _mega_events_max():
+            and ev_floor <= ladder.MEGA_EVENTS_MAX:
         from jepsen_tpu.parallel.megabatch import check_megabatch
-        from jepsen_tpu.serve.buckets import mega_lane_bucket
         out = check_megabatch(model, list(subs), max_capacity=threshold,
                               window_floor=w_floor, ev_floor=ev_floor,
-                              lanes=mega_lane_bucket(len(subs)))
+                              lanes=ladder.mega_lane_bucket(len(subs)))
     else:
         from jepsen_tpu.parallel.batch import check_batch
         out = check_batch(model, list(subs),
@@ -552,11 +551,6 @@ def _dispatch_subproblems(model: JaxModel, subs: Sequence[History], *,
                     args={"lanes": len(subs), "ev_floor": ev_floor,
                           "w_floor": w_floor})
     return out
-
-
-def _mega_events_max() -> int:
-    from jepsen_tpu.serve.buckets import MEGA_EVENTS_MAX
-    return MEGA_EVENTS_MAX
 
 
 def _escalate(model: JaxModel, history: History, *, capacity: int,

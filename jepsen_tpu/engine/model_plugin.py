@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Optional
 
 from jepsen_tpu.checker.core import Checker
+from jepsen_tpu.engine.ladder import pow2_at_least
 from jepsen_tpu.history import History
 
 
@@ -62,7 +63,6 @@ def derive_queue_slots(history: History,
     one compiled engine shape."""
     if "slots" in kw:
         return {}
-    from jepsen_tpu.engine.ladder import pow2_at_least
     n_enq = sum(1 for op in history
                 if op.invoke_ and op.f == "enqueue")
     n_enq = max(n_enq, sum(1 for op in history

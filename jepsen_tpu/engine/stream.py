@@ -8,7 +8,7 @@ configuration search in Python.  This module keeps the same frontier
 monitor epochs — donated in place, never re-uploaded — and each epoch
 dispatches ONLY the ops that arrived since the last one, padded onto
 the epoch-events rung of the shape ladder
-(:func:`jepsen_tpu.serve.buckets.epoch_events_bucket`).  Per-epoch cost
+(:func:`jepsen_tpu.engine.ladder.epoch_events_bucket`).  Per-epoch cost
 is therefore bounded by new-ops work, flat in total history length.
 
 Division of labour:
@@ -56,7 +56,10 @@ from jepsen_tpu.checker.wgl_tpu import (
     CLOSURE_WORK_BUDGET, EV_ENTER, EV_NOP, EV_RETURN, make_engine,
 )
 from jepsen_tpu.engine.cache import CACHE as _ENGINE_CACHE
-from jepsen_tpu.engine.ladder import next_capacity, round_window
+from jepsen_tpu.engine.ladder import (
+    MAX_WGL_CAPACITY, MIN_EVENTS_BUCKET, MIN_WIDTH_BUCKET,
+    epoch_events_bucket, next_capacity, pow2_at_least, round_window,
+    wgl_start_capacity)
 from jepsen_tpu.monitor.epochs import KeyFrontier, WglEpochEngine
 from jepsen_tpu.obs.hist import timed_first_call
 from jepsen_tpu.ops import dedup as _dedup
@@ -73,11 +76,10 @@ def stream_engine_rungs(width: int, n_new: int):
     epoch-events bucket) pair — the raw inputs are quantized here, so
     equal buckets always compile equal shapes (the TRACE02 stream leg
     asserts exactly this)."""
-    from jepsen_tpu.serve import buckets
-    wb = buckets.pow2_at_least(max(1, width), buckets.MIN_WIDTH_BUCKET)
+    wb = pow2_at_least(max(1, width), MIN_WIDTH_BUCKET)
     return (round_window(wb),
-            buckets.wgl_start_capacity(buckets.MIN_EVENTS_BUCKET, wb),
-            buckets.epoch_events_bucket(n_new))
+            wgl_start_capacity(MIN_EVENTS_BUCKET, wb),
+            epoch_events_bucket(n_new))
 
 
 def monitor_dispatcher(service):
@@ -143,12 +145,11 @@ class DeviceKeyFrontier:
     def __init__(self, jax_model, model, max_configs: int = 2_000_000,
                  capacity: Optional[int] = None,
                  max_capacity: Optional[int] = None, dispatcher=None):
-        from jepsen_tpu.serve import buckets
         self.jax_model = jax_model
         self.model = model
         self.max_configs = max_configs
         self.capacity_opt = capacity
-        self.max_capacity = (buckets.MAX_WGL_CAPACITY
+        self.max_capacity = (MAX_WGL_CAPACITY
                              if max_capacity is None else max_capacity)
         self.prefix: List[Any] = []
         self.result: Optional[Dict[str, Any]] = None
@@ -268,7 +269,6 @@ class DeviceKeyFrontier:
 
     def _advance_device(self) -> None:
         import jax.numpy as jnp
-        from jepsen_tpu.serve import buckets
         cur = self._cursor
         if cur._next_slot > self._window:
             self._grow_window(cur._next_slot)
@@ -276,7 +276,7 @@ class DeviceKeyFrontier:
         while (self.result is None and self.exploded is None
                and self._host is None and self._applied < len(rows)):
             remaining = len(rows) - self._applied
-            b = buckets.epoch_events_bucket(remaining)
+            b = epoch_events_bucket(remaining)
             take = min(remaining, b)
             chunk = np.zeros((b, 10), np.int32)
             chunk[:, 0] = EV_NOP

@@ -172,7 +172,7 @@ def signature_stability_findings(
             f"{len(buckets)} buckets over {len(samples)} sample shapes "
             f"— a raw shape is leaking into the engine signature",
             hint="every signature component must be derived from the "
-                 "bucket (serve/buckets.py), never from the history")]
+                 "bucket (engine/ladder.py), never from the history")]
     return []
 
 
@@ -180,31 +180,30 @@ def ladder_findings(samples: Sequence[Tuple[int, int, int]] =
                     DEFAULT_SAMPLES) -> List[Finding]:
     """Check the real serve-path derivations against the ladder."""
     from jepsen_tpu.checker.wgl_tpu import _round_window
-    from jepsen_tpu.engine.ladder import mega_chunk, state_capacity
-    from jepsen_tpu.serve import buckets
+    from jepsen_tpu.engine import ladder
 
     findings = []
 
     def wgl_bucket(s):
         e, w, l = s
-        # the numeric ladder under buckets.events_bucket/width_bucket
-        return (buckets.pow2_at_least(e, buckets.MIN_EVENTS_BUCKET),
-                buckets.pow2_at_least(w, buckets.MIN_WIDTH_BUCKET),
-                buckets.lane_bucket(l))
+        # the numeric ladder under events_bucket/width_bucket
+        return (ladder.pow2_at_least(e, ladder.MIN_EVENTS_BUCKET),
+                ladder.pow2_at_least(w, ladder.MIN_WIDTH_BUCKET),
+                ladder.lane_bucket(l))
 
     def wgl_signature(s):
         eb, wb, lb = wgl_bucket(s)
         # exactly what scheduler._dispatch_wgl hands the batch engine
         # (register family: state width 1, the ladder's base rung)
-        return (_round_window(wb), buckets.wgl_start_capacity(eb, wb),
-                mega_chunk(lb, eb, 1), lb)
+        return (_round_window(wb), ladder.wgl_start_capacity(eb, wb),
+                ladder.mega_chunk(lb, eb, 1), lb)
 
     findings.extend(signature_stability_findings(
         samples, wgl_signature, wgl_bucket, "wgl serve path",
         path="jepsen_tpu/serve/scheduler.py"))
 
     def elle_bucket(s):
-        return (buckets.pow2_at_least(max(1, s[0]), buckets.MIN_N_BUCKET),)
+        return (ladder.pow2_at_least(max(1, s[0]), ladder.MIN_N_BUCKET),)
 
     def elle_signature(s):
         n = s[0]
@@ -233,7 +232,7 @@ def ladder_findings(samples: Sequence[Tuple[int, int, int]] =
         return History(ops)
 
     def queue_bucket(s):
-        return (buckets.pow2_at_least(max(1, s[0]), 8),)
+        return (ladder.pow2_at_least(max(1, s[0]), 8),)
 
     def queue_signature(s):
         return (derive_queue_slots(_enq_history(s[0]), {})["slots"],)
@@ -255,17 +254,17 @@ def ladder_findings(samples: Sequence[Tuple[int, int, int]] =
 
     def state_bucket(s):
         e, w, l = s
-        return (buckets.pow2_at_least(e, buckets.MIN_EVENTS_BUCKET),
-                buckets.pow2_at_least(max(8, w), buckets.MIN_WIDTH_BUCKET),
-                buckets.mega_lane_bucket(l),
-                buckets.state_width_bucket(_queue_state_width(s)))
+        return (ladder.pow2_at_least(e, ladder.MIN_EVENTS_BUCKET),
+                ladder.pow2_at_least(max(8, w), ladder.MIN_WIDTH_BUCKET),
+                ladder.mega_lane_bucket(l),
+                ladder.state_width_bucket(_queue_state_width(s)))
 
     def state_signature(s):
         eb, wb, lb, _ = state_bucket(s)
         raw_width = _queue_state_width(s)
-        return (mega_chunk(lb, eb, raw_width),
-                state_capacity(eb, wb, raw_width),
-                buckets.state_width_bucket(raw_width))
+        return (ladder.mega_chunk(lb, eb, raw_width),
+                ladder.state_capacity(eb, wb, raw_width),
+                ladder.state_width_bucket(raw_width))
 
     findings.extend(signature_stability_findings(
         samples, state_signature, state_bucket, "megabatch state-width",
@@ -296,14 +295,14 @@ def ladder_findings(samples: Sequence[Tuple[int, int, int]] =
 
     def fission_bucket(s):
         e, w, l = s
-        return (buckets.pow2_at_least(max(1, e), buckets.MIN_EVENTS_BUCKET),
-                buckets.pow2_at_least(max(1, w), buckets.MIN_WIDTH_BUCKET),
-                buckets.mega_lane_bucket(l))
+        return (ladder.pow2_at_least(max(1, e), ladder.MIN_EVENTS_BUCKET),
+                ladder.pow2_at_least(max(1, w), ladder.MIN_WIDTH_BUCKET),
+                ladder.mega_lane_bucket(l))
 
     def fission_signature(s):
         e, w, l = s
         subs = [_sub_history(e, w)] * min(3, max(1, l))
-        return subproblem_floors(subs)[::-1] + (buckets.mega_lane_bucket(l),)
+        return subproblem_floors(subs)[::-1] + (ladder.mega_lane_bucket(l),)
 
     findings.extend(signature_stability_findings(
         samples, fission_signature, fission_bucket, "fission sub-dispatch",
@@ -321,8 +320,8 @@ def ladder_findings(samples: Sequence[Tuple[int, int, int]] =
 
     def stream_bucket(s):
         e, w, _ = s
-        return (buckets.pow2_at_least(max(1, w), buckets.MIN_WIDTH_BUCKET),
-                buckets.epoch_events_bucket(e))
+        return (ladder.pow2_at_least(max(1, w), ladder.MIN_WIDTH_BUCKET),
+                ladder.epoch_events_bucket(e))
 
     def stream_signature(s):
         e, w, _ = s

@@ -2,7 +2,7 @@
 
 The serving layer's whole compile-cache story rests on one discipline:
 every shape that reaches a device engine (pad targets, window floors,
-chunk sizes) comes from ``serve/buckets.py``'s power-of-two ladder, so
+chunk sizes) comes from ``engine/ladder.py``'s power-of-two ladder, so
 the set of compiled signatures is bounded by the ladder, not by the
 traffic.  One call site that pads to a raw history length (``len(h)``,
 ``max(p.window ...)``) silently reopens an unbounded compile cache —
@@ -15,7 +15,7 @@ The rule audits engine entry points called from serve/ (``check_batch``,
 - shape-carrying kwargs (``window_floor``, ``n_pad_floor``, ``chunk``,
   ``n_pad``, ``b_pad``, ``window``, ``pad_to``), when present, must be
   *bucket-derived*: reference a ``*bucket*``/``*floor*``/``pow2`` name,
-  a ``buckets.`` helper, or the canonical ``_batch_chunk`` derivation
+  a ``ladder.`` bucket helper, or the canonical ``batch_chunk`` derivation
   (literal ``0`` = "disabled" is also fine).  Non-zero literals and raw
   shape expressions fire;
 - a ``check_batch`` call *missing* its floor kwarg fires — the default
@@ -79,7 +79,7 @@ _MEGABATCH_FLOORS = ("window_floor", "ev_floor")
 
 _BUCKETISH_NAME = re.compile(r"bucket|floor|pow2", re.IGNORECASE)
 _BUCKETISH_FUNC = re.compile(
-    r"bucket|floor|pow2|_batch_chunk|mega_chunk|capacity")
+    r"bucket|floor|pow2|batch_chunk|mega_chunk|capacity")
 
 
 def _bucket_derived(node: ast.AST) -> bool:
@@ -156,7 +156,7 @@ def check(tree: ast.Module, src_lines: List[str],
                     RULE, path, value.lineno,
                     f"`{fname}(..., {kw_name}=...)` in {qn} passes a "
                     f"shape not derived from the bucket ladder",
-                    hint="derive it via serve/buckets.py (events_bucket/"
+                    hint="derive it via engine/ladder.py (events_bucket/"
                          "width_bucket/elle_bucket/...) so the compile "
                          "cache stays bounded by the ladder")
         if fname == "check_megabatch" and not _engine_is_cpu(node):

@@ -7,17 +7,17 @@ existing ``/metrics`` surface:
 - ``trace``    — trace-context ids minted at ``Request`` submit and
                  propagated on every wire frame, plus the Chrome
                  trace-event (Perfetto) conversion for merged traces.
-- ``hist``     — log-bucketed latency/compile-time histograms on the
-                 same pow2 ladder the serve shape buckets use, so the
-                 histogram buckets *are* the shape buckets.
+- ``hist``     — log-bucketed latency/compile-time histograms, their
+                 buckets powers of two like the engine's shape rungs.
 - ``recorder`` — a bounded process-wide ring of structured events with
                  an atomic Chrome-trace export (``RECORDER``), and
                  ``span``/``instant``: the checker path's layer
                  boundaries, for the ring and for ``jax.profiler``.
 
-Import discipline: nothing here imports ``jepsen_tpu.serve`` at module
-scope (serve's metrics layer imports us — the ladder reuse in ``hist``
-is a lazy import to keep the cycle open).
+Import discipline: a leaf.  The engine and the service both import
+this package, so nothing here imports ``jepsen_tpu.engine``,
+``jepsen_tpu.serve`` or anything else of the checker path, at module
+scope or inside a function (``tests/test_layering.py``).
 """
 
 from jepsen_tpu.obs.hist import (  # noqa: F401

@@ -1,14 +1,13 @@
 """Log-bucketed latency and compile-time histograms.
 
-Buckets ride the same pow2 ladder the serve shape buckets use
-(``serve.buckets.pow2_at_least``): an observation of ``s`` seconds
-lands in the bucket whose upper bound is the smallest power of two of
-microseconds >= ``s``.  That keeps the bucket universe bounded (a
-64-second tail is ~36 rungs from the 1 µs floor), makes histograms from
-different processes mergeable by plain bucket-wise addition (every
-process has the identical ladder), and means a compile-time histogram
-keyed by an engine-cache bucket key reports quantiles over exactly the
-shapes the compile cache distinguishes.
+Buckets are powers of two, like the rungs of the engine's shape ladder:
+an observation of ``s`` seconds lands in the bucket whose upper bound is
+the smallest power of two of microseconds >= ``s``.  That keeps the
+bucket universe bounded (a 64-second tail is ~36 rungs from the 1 µs
+floor), makes histograms from different processes mergeable by plain
+bucket-wise addition (every process has the identical ladder), and means
+a compile-time histogram keyed by an engine-cache bucket key reports
+quantiles over exactly the shapes the compile cache distinguishes.
 
 Percentiles are cumulative-walk upper bounds: ``p99`` is the upper edge
 of the first bucket at or past the 99th percentile of the count mass —
@@ -26,11 +25,10 @@ _FLOOR_US = 1
 
 
 def _bucket_of(us: int) -> int:
-    # lazy import: serve.metrics imports this module, and serve's package
-    # __init__ imports metrics — a module-scope import here would close
-    # an import cycle through jepsen_tpu.serve
-    from jepsen_tpu.serve.buckets import pow2_at_least
-    return pow2_at_least(max(us, _FLOOR_US), _FLOOR_US)
+    b = _FLOOR_US
+    while b < us:
+        b *= 2
+    return b
 
 
 class Histogram:

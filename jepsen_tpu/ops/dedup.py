@@ -36,21 +36,20 @@ measured since: nothing here scatters.
 
 from __future__ import annotations
 
-import os
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: Subsumption probe count (earlier in-group rows checked per row).  Read at
-#: import time; engines embed it in their cache keys (see wgl_tpu.make_engine)
-#: so changing it requires a fresh process, never a silent no-op.
-#: Default 3 (was 5): measured on hardware, probes 3 drop exactly the same
-#: rows on the crash-heavy hard tier and the subsumption ablation (same
-#: configs explored, same capacity trajectory) while the per-merge
-#: gather/compare chains cost ~9% of the easy-tier wall (7.5s -> 6.9s).
-N_PROBES = int(os.environ.get("JTPU_PROBES", "3"))
+#: Subsumption probe count (earlier in-group rows checked per row).  A
+#: constant of the program: engines embed it in their cache keys (see
+#: wgl_tpu.make_engine), and the tests that need another value patch it
+#: here.  3 (was 5): measured on hardware, probes 3 drop exactly the same
+#: rows on the crash-heavy hard tier (same configs explored, same capacity
+#: trajectory) while the per-merge gather/compare chains cost ~9% of the
+#: easy-tier wall (7.5s -> 6.9s).
+N_PROBES = 3
 
 #: Above this row count the dedup sorts with ``_lex_perm`` (a chain of
 #: 2-operand stable sorts composing a permutation) instead of one wide
@@ -59,14 +58,13 @@ N_PROBES = int(os.environ.get("JTPU_PROBES", "3"))
 #: outright; 2-operand sorts at the same row count compile in ~26 s and run
 #: in milliseconds.  1.06M-row x 7-operand variadic sorts are measured-good,
 #: so the threshold keeps the single-sort path for every small shape.
-WIDE_SORT_ROWS = int(os.environ.get("JTPU_WIDE_SORT_ROWS", "1200000"))
+WIDE_SORT_ROWS = 1_200_000
 
-#: Ablation switch for ghost subsumption (``JTPU_SUBSUME=0`` disables the
-#: subset-drop; ghost columns then act as plain identity columns, i.e. the
-#: classic 2^crashes configuration search).  Import-time constant, part of
-#: the engine cache key — exists so the bench can measure what subsumption
-#: buys on hardware.
-SUBSUME = os.environ.get("JTPU_SUBSUME", "1") != "0"
+#: Ghost subsumption (the subset-drop).  With it off, ghost columns act as
+#: plain identity columns, i.e. the classic 2^crashes configuration search:
+#: same verdicts, more configurations.  Part of the engine cache key; the
+#: tests patch it to False to hold the two searches against each other.
+SUBSUME = True
 
 
 def compact_rows(cols: Sequence[jnp.ndarray], keep: jnp.ndarray,

@@ -31,18 +31,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jepsen_tpu.checker.prep import PreparedHistory, prepare
 from jepsen_tpu.checker.wgl_tpu import EV_NOP, events_array, make_engine
-# The ladder/cache/group/budget/witness disciplines live in the shared
-# engine substrate; the historical names stay importable from here (the
-# serve scheduler, megabatch, tests, and external callers bind them).
 from jepsen_tpu.engine.budget import exhausted_result
-from jepsen_tpu.engine.cache import (
-    CACHE as _CACHE, EngineCache as _LRUCache, engine_cache_stats,  # noqa: F401
-)
+from jepsen_tpu.engine.cache import CACHE as _CACHE
 from jepsen_tpu.engine.groups import MAX_LANES_PER_GROUP, group_slices
-from jepsen_tpu.engine.ladder import (
-    LANE_EVENTS_PER_DISPATCH, batch_chunk as _batch_chunk, batch_shape,  # noqa: F401
-    mega_chunk, next_capacity,
-)
+from jepsen_tpu.engine.ladder import batch_shape, mega_chunk, next_capacity
 from jepsen_tpu.engine.witness import refuted_result
 from jepsen_tpu.history import History
 from jepsen_tpu.models.base import JaxModel
@@ -101,7 +93,7 @@ def check_batch(model: JaxModel,
     All lanes share one engine shape (window = max over histories, events
     NOP-padded to the longest).  With ``mesh``, lanes are sharded over the
     ``axis`` mesh axis; the batch is padded to a multiple of the axis size.
-    ``chunk=None`` picks the batch-size-scaled default (``_batch_chunk``).
+    ``chunk=None`` picks the batch-size-scaled default (``ladder.mega_chunk``).
     ``window_floor`` pads the shared window up to a caller-chosen bucket so
     successive batches of similar histories reuse one compiled engine (the
     serve scheduler's shape-bucketing lever; 0 = tightest window).

@@ -13,7 +13,7 @@ This module keeps the device saturated instead:
 
 * **Bucket bin-packing.**  Prepared histories are packed into the
   power-of-two bucket ladder (events x window x ghost-words x
-  state-width, the same ladder serve/buckets.py pins the compile cache
+  state-width, the ladder ``engine/ladder.py`` pins the compile cache
   to), so one compiled engine serves every lane of a bucket and the
   shape universe stays bounded.
 * **Model-agnostic carries.**  The engine carry layout is the same for
@@ -71,6 +71,8 @@ import numpy as np
 from jepsen_tpu.checker.prep import prepare
 from jepsen_tpu.checker.wgl_tpu import (EV_NOP, _round_window, chosen_gwords,
                                         events_array, make_engine)
+from jepsen_tpu.engine.ladder import (mega_chunk, pow2_at_least,
+                                      state_capacity, state_width_bucket)
 from jepsen_tpu.history import History
 from jepsen_tpu.models.base import JaxModel
 from jepsen_tpu.parallel.batch import (MAX_LANES_PER_GROUP, _CACHE,
@@ -92,7 +94,7 @@ STATUS_FAILED = 2
 STATUS_OVERFLOW = 3
 
 #: default cap on concurrently-resident lanes (across a bucket's groups);
-#: the lane-count ladder in serve/buckets.py (mega_lane_bucket) feeds
+#: the lane-count ladder (``engine.ladder.mega_lane_bucket``) feeds
 #: this from the scheduler side.
 DEFAULT_MAX_LANES = 4096
 
@@ -181,16 +183,8 @@ def _read_harvest(dev) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Bucketing (the same power-of-two ladder serve pins the compile cache to)
+# Bucketing (the engine.ladder rungs the compile cache is pinned to)
 # ---------------------------------------------------------------------------
-
-def _pow2_at_least(n: int, floor: int) -> int:
-    # One rung definition for the whole stack: delegate to the shared
-    # ladder (resolved lazily — the serve import behind it would cycle at
-    # module-import time).
-    from jepsen_tpu.engine.ladder import pow2_at_least
-    return pow2_at_least(n, max(1, floor))
-
 
 def _prep_bucket(p, window_floor: int, ev_floor: int, gw_b: int,
                  sw_b: int) -> Tuple[int, int, int, int]:
@@ -210,19 +204,14 @@ def _prep_bucket(p, window_floor: int, ev_floor: int, gw_b: int,
     per call (one model per call) but part of the key so the chunk and
     start-capacity derivations downstream are pure functions of the
     bucket tuple alone."""
-    ev_b = _pow2_at_least(max(1, len(p)), max(64, ev_floor))
-    w_b = _pow2_at_least(_round_window(max(p.window, window_floor)), 8)
+    ev_b = pow2_at_least(max(1, len(p)), max(64, ev_floor))
+    w_b = pow2_at_least(_round_window(max(p.window, window_floor)), 8)
     return (ev_b, w_b, gw_b, sw_b)
 
 
 def _call_gwords(preps) -> int:
     gw = max(chosen_gwords(p) for p in preps)
-    return 0 if gw == 0 else _pow2_at_least(gw, 1)
-
-
-def _default_capacity(ev_b: int, w_b: int, sw_b: int) -> int:
-    from jepsen_tpu.engine.ladder import state_capacity
-    return state_capacity(ev_b, w_b, sw_b)
+    return 0 if gw == 0 else pow2_at_least(gw, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -394,7 +383,6 @@ def check_megabatch(model: JaxModel,
     preps = [prepare(h, model) for h in histories]
 
     gw_b = _call_gwords(preps)
-    from jepsen_tpu.engine.ladder import state_width_bucket
     sw_b = state_width_bucket(model.state_size)
     buckets: "OrderedDict[Tuple[int, int, int, int], List[int]]" = \
         OrderedDict()
@@ -422,10 +410,9 @@ def _drain_bucket(model, histories, preps, bucket, idxs, out, *,
     """Run every history of one (events, window, gwords, state-width)
     bucket through a refilled set of lane groups, writing results into
     ``out``."""
-    from jepsen_tpu.engine.ladder import mega_chunk
     ev_b, w_b, gw_b, sw_b = bucket
     _bump(buckets=1)
-    width = min(_pow2_at_least(min(len(idxs), lanes), 1),
+    width = min(pow2_at_least(min(len(idxs), lanes), 1),
                 MAX_LANES_PER_GROUP)
     # Chunk and start capacity come off the state-width-aware ladder
     # shared with check_batch: pure functions of the bucket tuple, so a
@@ -435,7 +422,7 @@ def _drain_bucket(model, histories, preps, bucket, idxs, out, *,
     # Buffer rows are a pure function of the bucket (+1 trailing NOP row
     # that finished cursors clamp onto), never of the lanes present.
     rows = max(cc, ((ev_b + cc - 1) // cc) * cc) + 1
-    cap = capacity if capacity else _default_capacity(ev_b, w_b, sw_b)
+    cap = capacity if capacity else state_capacity(ev_b, w_b, sw_b)
     cap = min(cap, max_capacity)
     n_groups = max(1, min((len(idxs) + width - 1) // width,
                           max(1, lanes // width)))

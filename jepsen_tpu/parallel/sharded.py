@@ -85,8 +85,6 @@ def _sharded_runner(model: JaxModel, window: int, capacity_per_shard: int,
 
 
 def _initial_carry(model, window, cap, n, mesh, axis):
-    from jepsen_tpu.checker.wgl_tpu import engine_window
-    window = engine_window(window)  # match the engine's block padding
     MW = (window + 31) // 32
     gcap = cap * n
 

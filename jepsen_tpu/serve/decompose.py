@@ -17,9 +17,9 @@ from __future__ import annotations
 
 from typing import List
 
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.history import NEMESIS
 from jepsen_tpu.independent import key_of, subhistories
-from jepsen_tpu.serve import buckets
 from jepsen_tpu.serve.request import Cell, KIND_ELLE, KIND_WGL, Request
 
 
@@ -65,8 +65,8 @@ def decompose(req: Request) -> List[Cell]:
         subs = [(None, req.history)]
     cells = []
     for key, h in subs:
-        shape = (buckets.wgl_bucket(h) if req.kind == KIND_WGL
-                 else buckets.elle_bucket(h))
+        shape = (ladder.wgl_bucket(h) if req.kind == KIND_WGL
+                 else ladder.elle_bucket(h))
         cells.append(Cell(request=req, history=h, key=key,
                           bucket=(req.kind, ident) + shape))
     req.cells = cells

@@ -49,10 +49,9 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, TYPE_CHECKING
 
-from jepsen_tpu.engine import fission as engine_fission
+from jepsen_tpu.engine import fission as engine_fission, ladder
 from jepsen_tpu.obs.hist import HistogramSet
 from jepsen_tpu.obs.recorder import RECORDER
-from jepsen_tpu.serve import buckets
 from jepsen_tpu.serve.decompose import _engine_identity
 from jepsen_tpu.serve.metrics import mono_now
 from jepsen_tpu.serve.request import Cell, KIND_WGL, Request
@@ -223,7 +222,7 @@ def _make_children(req: Request, parent: Cell, mode: str, subs: List,
     ident = _engine_identity(req)
     now = mono_now()
     return [Cell(request=req, history=sub, key=parent.key,
-                 bucket=(req.kind, ident) + buckets.wgl_bucket(sub),
+                 bucket=(req.kind, ident) + ladder.wgl_bucket(sub),
                  enqueued=now,
                  fission={"group": gid, "mode": mode, "index": i,
                           "subproblems": len(subs)},

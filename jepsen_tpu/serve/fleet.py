@@ -45,13 +45,14 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from jepsen_tpu import atomic_io
 from jepsen_tpu.control.retry import RetryPolicy
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.net_proxy import PairProxy
 from jepsen_tpu.history import History, Op
 from jepsen_tpu.obs.hist import merge_hist_snapshots
 from jepsen_tpu.obs.recorder import RECORDER
 from jepsen_tpu.obs.slo import SloEngine, tenant_slo_specs
 from jepsen_tpu.obs.telemetry import TelemetryStore, telemetry_interval_s
-from jepsen_tpu.serve import buckets, fission_plane
+from jepsen_tpu.serve import fission_plane
 from jepsen_tpu.serve.aggregate import aggregate, expired_result
 from jepsen_tpu.serve.decompose import decompose
 from jepsen_tpu.serve.metrics import Metrics, mono_now
@@ -91,6 +92,40 @@ _WORKER_FAILURE_ERRORS = (
     "transport connection lost",
     "transport frame error",
 )
+
+
+#: floor of the per-worker lane ladder: a fleet worker never dispatches
+#: narrower groups than this, however many siblings share the device.
+MIN_WORKER_LANES = 8
+
+
+def worker_lane_share(total_lanes: int, n_workers: int) -> int:
+    """A fleet worker's per-dispatch lane budget when one device's lane
+    allowance is split across N workers: ceil-divide, then round UP onto
+    the power-of-two ladder (floor :data:`MIN_WORKER_LANES`).  Rounding
+    up — not down — keeps every worker's dispatches on the same ladder
+    rungs a solo service would use, so the fleet and the single-service
+    oracle share compiled-engine cache entries instead of doubling the
+    shape universe."""
+    n = max(1, n_workers)
+    share = (max(1, total_lanes) + n - 1) // n
+    return min(ladder.MAX_LANE_BUCKET,
+               ladder.pow2_at_least(share, MIN_WORKER_LANES))
+
+
+def proc_worker_lanes(total_lanes: int, n_workers: int,
+                      shared_host: bool = True) -> int:
+    """A ProcFleet worker's per-dispatch lane budget.  Out-of-process
+    workers on ONE host (today's shape: N subprocesses sharing the
+    host's device) still split the device's lane allowance, so the
+    budget divides exactly like :func:`worker_lane_share` — same ladder
+    rungs, same shared compile cache with the solo oracle.  Workers that
+    will land on their *own* hosts (``shared_host=False``, the
+    multi-host direction) each take the full rung: nothing is shared,
+    and dividing would just waste their private device."""
+    if not shared_host:
+        return worker_lane_share(total_lanes, 1)
+    return worker_lane_share(total_lanes, n_workers)
 
 
 def _device_sets(n: int) -> List[list]:
@@ -451,7 +486,7 @@ class Fleet:
         self._t0 = mono_now()
         device_sets = _device_sets(n) if pin_devices else [[]] * n
         self.workers: List[FleetWorker] = self._make_workers(
-            n, buckets.worker_lane_share(max_lanes, n), device_sets,
+            n, worker_lane_share(max_lanes, n), device_sets,
             mesh=mesh, capacity=capacity, max_capacity=max_capacity,
             fail_threshold=breaker_fail_threshold,
             open_s=breaker_open_s)
@@ -1416,7 +1451,7 @@ class ProcFleet(Fleet):
                       capacity: Optional[int], max_capacity: int,
                       fail_threshold: int,
                       open_s: float) -> List[FleetWorker]:
-        lanes = buckets.proc_worker_lanes(self.max_lanes, n)
+        lanes = proc_worker_lanes(self.max_lanes, n)
         if self._log_dir is None:
             import tempfile
             self._log_dir = tempfile.mkdtemp(prefix="procfleet-logs-")

@@ -42,7 +42,7 @@ from jepsen_tpu.clock import mono_now
 
 #: lanes one device contributes to a dispatch (the serve tier's
 #: max-lanes default per worker; 8 devices x 64 = the 512-lane ceiling
-#: in serve/buckets.MAX_LANE_BUCKET)
+#: in engine/ladder.MAX_LANE_BUCKET)
 LANES_PER_DEVICE = 64
 
 #: default lease duration, seconds (env-overridable)

@@ -33,8 +33,8 @@ import threading
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.obs.recorder import RECORDER, adopt
-from jepsen_tpu.serve import buckets
 from jepsen_tpu.serve.aggregate import aggregate, expired_result
 from jepsen_tpu.serve.metrics import mono_now
 from jepsen_tpu.serve.request import Cell, KIND_ELLE, KIND_WGL
@@ -56,9 +56,9 @@ class Scheduler:
         # host's devices instead of convoying on device 0.  None = the
         # backend default (the solo-service behaviour).
         self.device = device
-        self.max_lanes = max(1, min(max_lanes, buckets.MAX_LANE_BUCKET))
+        self.max_lanes = max(1, min(max_lanes, ladder.MAX_LANE_BUCKET))
         # None = derive the start capacity from each dispatch's bucket
-        # shape (buckets.wgl_start_capacity); an int pins the old fixed
+        # shape (ladder.wgl_start_capacity); an int pins the old fixed
         # knob for every dispatch.
         self.capacity = capacity
         self.max_capacity = max_capacity
@@ -272,7 +272,7 @@ class Scheduler:
         from jepsen_tpu.parallel.megabatch import megabatch_enabled
         if not (self.mesh is None and megabatch_enabled()
                 and len(bucket) >= 4 and bucket[0] == KIND_WGL
-                and bucket[2] <= buckets.MEGA_EVENTS_MAX):
+                and bucket[2] <= ladder.MEGA_EVENTS_MAX):
             return False
         from jepsen_tpu.engine.plugins import has_carry_descriptor
         ident = bucket[1]
@@ -284,7 +284,7 @@ class Scheduler:
         path packs up to the mega lane ladder (grouped vmaps reusing one
         executable), the barrier path stays at max_lanes."""
         if self._mega_eligible(bucket):
-            return buckets.mega_lane_bucket(buckets.MAX_MEGA_LANES)
+            return ladder.mega_lane_bucket(ladder.MAX_MEGA_LANES)
         return self.max_lanes
 
     def _take_group(self) -> List[Cell]:
@@ -406,7 +406,7 @@ class Scheduler:
             pad = len(lanes)
             padded = lanes
         else:
-            pad = buckets.lane_bucket(len(lanes), self.max_lanes)
+            pad = ladder.lane_bucket(len(lanes), self.max_lanes)
             padded = lanes + [lanes[0]] * (pad - len(lanes))
         for c in live:
             c.request.span("dispatch")
@@ -446,7 +446,7 @@ class Scheduler:
         """Resolve the wgl start capacity: per-request ``capacity`` engine
         opts win, then the ``JEPSEN_TPU_WGL_CAPACITY`` env override, then
         a service-level fixed knob, then the bucket-shape derivation
-        (buckets.wgl_start_capacity — the default).  Overflowing lanes
+        (ladder.wgl_start_capacity — the default).  Overflowing lanes
         still escalate automatically, so this only sets where the ladder
         starts."""
         explicit = [int(s.request.spec["capacity"]) for s in live
@@ -458,11 +458,11 @@ class Scheduler:
             return max(1, int(env))
         if self.capacity is not None:
             return int(self.capacity)
-        return buckets.wgl_start_capacity(ev_bucket, w_bucket)
+        return ladder.wgl_start_capacity(ev_bucket, w_bucket)
 
     def _dispatch_wgl(self, live: List[Cell], padded: List[Any],
                       mega: bool = False) -> List[Dict[str, Any]]:
-        from jepsen_tpu.parallel.batch import _batch_chunk, check_batch
+        from jepsen_tpu.parallel.batch import check_batch
         spec0 = live[0].request.spec
         _, _, ev_bucket, w_bucket = live[0].bucket
         cap = self._start_capacity(live, ev_bucket, w_bucket)
@@ -477,11 +477,11 @@ class Scheduler:
                 spec0["model"], padded, capacity=cap,
                 max_capacity=max_cap, window_floor=w_bucket,
                 ev_floor=ev_bucket,
-                lanes=buckets.mega_lane_bucket(len(padded)))
+                lanes=ladder.mega_lane_bucket(len(padded)))
         else:
             rs = check_batch(spec0["model"], padded, mesh=self.mesh,
                              capacity=cap, max_capacity=max_cap,
-                             chunk=_batch_chunk(len(padded), ev_bucket),
+                             chunk=ladder.batch_chunk(len(padded), ev_bucket),
                              window_floor=w_bucket,
                              fission=spec0.get("fission"))
         return [self._explain_witness(c, r) for c, r in zip(live, rs)]

@@ -113,7 +113,7 @@ class CheckService:
         self.default_deadline_s = default_deadline_s
         self.metrics = Metrics()
         # capacity None = per-bucket derived wgl start capacity (see
-        # buckets.wgl_start_capacity; JEPSEN_TPU_WGL_CAPACITY overrides)
+        # ladder.wgl_start_capacity; JEPSEN_TPU_WGL_CAPACITY overrides)
         self._sched = Scheduler(self.metrics, mesh=mesh,
                                 max_lanes=max_lanes, capacity=capacity,
                                 max_capacity=max_capacity,

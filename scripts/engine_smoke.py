@@ -14,15 +14,12 @@ For each plugin (``linearizable-queue``, ``linearizable-set``,
   3. an impossibly small capacity budget must degrade the verdict to
      ``unknown`` — never fabricate ``False`` on a valid history.
 
-Then the bench ``models`` tier runs in smoke mode for the hist/s per
-model line.  The full record — fuzz counts per plugin plus the bench
-tier — goes to the path given as argv[1] (default
-/tmp/engine_smoke.json); CI uploads it as an artifact.
+The record — fuzz counts per plugin — goes to the path given as argv[1]
+(default /tmp/engine_smoke.json); CI uploads it as an artifact.
 """
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -132,23 +129,6 @@ def budget_degrades_to_unknown():
     return res["valid"]
 
 
-def bench_models_tier():
-    env = dict(os.environ, JTPU_BENCH_SMOKE="1",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"), "--tier",
-         "models"],
-        capture_output=True, text=True, env=env, cwd=root, timeout=900)
-    tag = "JTPU_TIER_RESULT "
-    for line in reversed(out.stdout.splitlines()):
-        if line.startswith(tag):
-            return json.loads(line[len(tag):])
-    raise AssertionError(f"bench models tier emitted no result: "
-                         f"rc={out.returncode} "
-                         f"stderr={out.stderr[-1500:]}")
-
-
 def main():
     out_path = sys.argv[1] if len(sys.argv) > 1 else "/tmp/engine_smoke.json"
     record = {}
@@ -161,8 +141,6 @@ def main():
     record["opacity_checks"] = fuzz_opacity()
     log("budget exhaustion")
     record["budget_exhaustion_verdict"] = budget_degrades_to_unknown()
-    log("bench models tier (smoke)")
-    record["bench_models"] = bench_models_tier()
     record["wall_s"] = round(time.time() - t0, 1)
     with open(out_path, "w") as f:
         json.dump(record, f, indent=2)

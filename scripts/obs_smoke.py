@@ -23,8 +23,8 @@ actually resolved what happened:
   one entry per worker;
 - the flight recorder's toll is bounded: the same warmed CheckService
   campaign recorder-off vs recorder-on stays within a generous CI noise
-  band (the tight <2% budget is bench.py's ``obs`` tier on quiet
-  hardware, not a shared CI runner).
+  band (a tight budget needs quiet hardware, not a shared CI runner:
+  not measured, PERF.md section 7).
 
 Writes the full report to argv[1] (default /tmp/obs_smoke_report.json)
 and the Perfetto trace to argv[2] (default /tmp/obs_smoke_trace.json) —
@@ -61,8 +61,8 @@ from jepsen_tpu.synth import (
 
 N_WGL, N_ELLE, CLIENTS = 36, 12, 4
 DEADLINE_S = 60.0
-# CI noise band for the recorder toll; bench.py's obs tier owns the
-# tight <2% budget on quiet hardware.
+# CI noise band for the recorder toll; what tracing costs on the chip is
+# in PERF.md (PR 25).
 TOLL_BAND = 0.25
 
 

@@ -92,12 +92,14 @@ class TestGroups:
 # -- ladder ------------------------------------------------------------------
 
 class TestLadder:
-    def test_bucket_reexports_resolve_lazily(self):
-        # PEP 562 __getattr__ keeps engine.ladder importable mid-cycle;
-        # the names must still resolve to the serve ladder.
-        from jepsen_tpu.serve import buckets
-        assert ladder.pow2_at_least is buckets.pow2_at_least
-        assert ladder.wgl_bucket is buckets.wgl_bucket
+    def test_ladder_defines_its_own_rungs(self):
+        # one module defines the ladder: plain definitions, no lazy
+        # re-export, and the old serve-side path is gone
+        import importlib.util
+        assert ladder.pow2_at_least.__module__ == ladder.__name__
+        assert ladder.wgl_bucket.__module__ == ladder.__name__
+        assert "__getattr__" not in vars(ladder)
+        assert importlib.util.find_spec("jepsen_tpu.serve.buckets") is None
 
     def test_round_window(self):
         assert round_window(1) == 8

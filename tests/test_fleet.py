@@ -16,12 +16,15 @@ import urllib.request
 
 import pytest
 
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.history import History
 from jepsen_tpu.nemesis.registry import FaultRegistry
-from jepsen_tpu.serve import CheckService, buckets
+from jepsen_tpu.serve import CheckService
 from jepsen_tpu.serve.chaos import ChaosNemesis
 from jepsen_tpu.serve.decompose import decompose
-from jepsen_tpu.serve.fleet import Fleet, FleetJournal
+from jepsen_tpu.serve.fleet import (
+    MIN_WORKER_LANES, Fleet, FleetJournal, worker_lane_share,
+)
 from jepsen_tpu.serve.request import Request
 from jepsen_tpu.serve.router import (
     CLOSED, CircuitBreaker, HALF_OPEN, OPEN, Router, WorkerHealth,
@@ -196,10 +199,10 @@ class TestWorkerLaneShare:
     def test_rounds_up_onto_the_solo_ladder(self):
         # ceil(64/3)=22 -> 32: the same pow2 rung a solo service uses,
         # so fleet and oracle share compiled-engine cache entries
-        assert buckets.worker_lane_share(64, 3) == 32
-        assert buckets.worker_lane_share(64, 1) == 64
-        assert buckets.worker_lane_share(64, 64) == buckets.MIN_WORKER_LANES
-        assert buckets.worker_lane_share(4096, 1) == buckets.MAX_LANE_BUCKET
+        assert worker_lane_share(64, 3) == 32
+        assert worker_lane_share(64, 1) == 64
+        assert worker_lane_share(64, 64) == MIN_WORKER_LANES
+        assert worker_lane_share(4096, 1) == ladder.MAX_LANE_BUCKET
 
 
 # ---------------------------------------------------------------------------

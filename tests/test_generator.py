@@ -226,11 +226,10 @@ class TestValidate:
 class TestPerf:
     def test_scheduler_throughput(self):
         """The reference cites >20k ops/s for pure generator scheduling
-        (generator.clj:67-70).  The COMMITTED record lives in the bench
-        artifact's `scheduler` entry (bench.py tier_sched; last idle
-        hardware run: 27.3k pure-mix / 21.9k wrapped-stack ops/s,
-        best-of-3 as disclosed there) — this test's bar sits WELL below
-        it purely for load tolerance (the suite runs alongside TPU
+        (generator.clj:67-70).  The last idle-host run measured 27.3k
+        pure-mix / 21.9k wrapped-stack ops/s, best of 3 (no ledger row:
+        the generator is not on the benchmark's path) — this test's bar
+        sits WELL below it purely for load tolerance (the suite runs alongside TPU
         benches and real-daemon tests; a 3x slowdown under contention
         has been observed)."""
         import time

@@ -254,15 +254,16 @@ class TestShape01:
 
     def test_bucket_derived_accepted(self):
         fs = run_rule(shape01, """
-            from jepsen_tpu.parallel.batch import _batch_chunk, check_batch
-            from jepsen_tpu.serve import buckets
+            from jepsen_tpu.engine import ladder
+            from jepsen_tpu.parallel.batch import check_batch
 
             def dispatch(model, hs, padded):
-                w_bucket = max(buckets.width_bucket(h) for h in hs)
-                ev_bucket = max(buckets.events_bucket(h) for h in hs)
-                return check_batch(model, padded,
-                                   chunk=_batch_chunk(len(padded), ev_bucket),
-                                   window_floor=w_bucket)
+                w_bucket = max(ladder.width_bucket(h) for h in hs)
+                ev_bucket = max(ladder.events_bucket(h) for h in hs)
+                return check_batch(
+                    model, padded,
+                    chunk=ladder.batch_chunk(len(padded), ev_bucket),
+                    window_floor=w_bucket)
             """, self.PATH)
         assert fs == []
 
@@ -280,13 +281,13 @@ class TestShape01:
 
     def test_megabatch_ladder_shapes_accepted(self):
         fs = run_rule(shape01, """
+            from jepsen_tpu.engine import ladder
             from jepsen_tpu.parallel.megabatch import check_megabatch
-            from jepsen_tpu.serve import buckets
 
             def dispatch(model, hs, ev_bucket, w_bucket):
                 return check_megabatch(
                     model, hs, window_floor=w_bucket, ev_floor=ev_bucket,
-                    lanes=buckets.mega_lane_bucket(len(hs)))
+                    lanes=ladder.mega_lane_bucket(len(hs)))
             """, self.PATH)
         assert fs == []
 
@@ -660,14 +661,15 @@ class TestTraceTier:
         # built from the quantized bucket is stable (negative fixture),
         # one threading the RAW model width into chunk/capacity fans a
         # bucket out into many signatures (positive fixture).
-        from jepsen_tpu.engine.ladder import mega_chunk, state_capacity
+        from jepsen_tpu.engine.ladder import (
+            mega_chunk, state_capacity, state_width_bucket,
+        )
         from jepsen_tpu.lint.jaxpr_lint import signature_stability_findings
-        from jepsen_tpu.serve import buckets
         # several raw widths per rung: 5..8 share the 8-rung, 9..16 the 16
         samples = [(64, 8, w) for w in (5, 6, 7, 8, 9, 12, 16, 17, 30)]
 
         def bucket(s):
-            return (s[0], s[1], buckets.state_width_bucket(s[2]))
+            return (s[0], s[1], state_width_bucket(s[2]))
 
         def good_signature(s):
             # mega_chunk/state_capacity quantize internally — same rung,
@@ -1352,7 +1354,7 @@ class TestEnv01:
         assert run_rule(env01, """
             import os
             def knob():
-                return os.environ.get("JTPU_PROBES", "3")
+                return os.environ.get("JTPU_FISSION_THRESHOLD", "16384")
             """, self.PATH) == []
 
     def test_placeholder_family_row_matches(self):

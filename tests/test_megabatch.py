@@ -12,15 +12,15 @@ scheduler routing knob.  Everything runs on the CPU backend.
 import pytest
 
 from jepsen_tpu.checker import wgl_cpu
+from jepsen_tpu.engine import ladder
+from jepsen_tpu.engine.cache import EngineCache, engine_cache_stats
 from jepsen_tpu.models import CASRegister, get_model
-from jepsen_tpu.parallel import batch as pbatch
 from jepsen_tpu.parallel import megabatch as mb
-from jepsen_tpu.parallel.batch import _LRUCache, check_batch
+from jepsen_tpu.parallel.batch import check_batch
 from jepsen_tpu.parallel.megabatch import (
     SUMMARY_WIDTH, check_megabatch, megabatch_enabled, megabatch_stats,
     reset_megabatch_stats,
 )
-from jepsen_tpu.serve import buckets
 from jepsen_tpu.synth import cas_register_history, corrupt_reads
 
 
@@ -139,7 +139,7 @@ class TestReadbackDiscipline:
 
 class TestGroupReuses:
     def test_lru_counts_group_reuse_separately(self):
-        c = _LRUCache(4)
+        c = EngineCache(4)
         c.put("k", "v")
         assert c.get("k") == "v"
         assert c.get("k", group_reuse=True) == "v"
@@ -151,20 +151,20 @@ class TestGroupReuses:
     def test_megabatch_groups_reuse_one_executable(self, model,
                                                    monkeypatch):
         monkeypatch.setattr(mb, "MAX_LANES_PER_GROUP", 4)
-        before = pbatch.engine_cache_stats()["group_reuses"]
+        before = engine_cache_stats()["group_reuses"]
         check_megabatch(model,
                         [cas_register_history(20, concurrency=3,
                                               seed=40 + i)
                          for i in range(16)], lanes=16)
-        assert pbatch.engine_cache_stats()["group_reuses"] > before
+        assert engine_cache_stats()["group_reuses"] > before
 
 
 class TestLaneLadder:
     def test_mega_lane_bucket(self):
-        assert buckets.mega_lane_bucket(1) == 1
-        assert buckets.mega_lane_bucket(600) == 1024
-        assert buckets.mega_lane_bucket(5000) == buckets.MAX_MEGA_LANES
-        assert buckets.MAX_MEGA_LANES >= 512  # grouped-vmap territory
+        assert ladder.mega_lane_bucket(1) == 1
+        assert ladder.mega_lane_bucket(600) == 1024
+        assert ladder.mega_lane_bucket(5000) == ladder.MAX_MEGA_LANES
+        assert ladder.MAX_MEGA_LANES >= 512  # grouped-vmap territory
 
     def test_enabled_knob(self, monkeypatch):
         monkeypatch.delenv("JEPSEN_TPU_MEGABATCH", raising=False)
@@ -188,10 +188,10 @@ class TestStateWidthLadder:
 
     def test_bucket_universe_is_finite(self):
         widths = list(range(1, 130)) + [200, 500, 1000, 2000, 4096]
-        rungs = {buckets.state_width_bucket(w) for w in widths}
+        rungs = {ladder.state_width_bucket(w) for w in widths}
         assert rungs == {4, 8, 16, 32, 64, 128, 256, 512, 1024,
                          2048, 4096}
-        assert all(r >= buckets.MIN_STATE_WIDTH_BUCKET
+        assert all(r >= ladder.MIN_STATE_WIDTH_BUCKET
                    and (r & (r - 1)) == 0 for r in rungs)
 
     def test_derive_queue_slots_lands_on_ladder(self):
@@ -205,25 +205,25 @@ class TestStateWidthLadder:
             # the compiled ring width (2 header + slots) quantizes onto
             # the same pow2 state ladder the chunk/capacity key on
             width = 2 + slots
-            assert buckets.state_width_bucket(width) \
-                == buckets.pow2_at_least(width,
-                                         buckets.MIN_STATE_WIDTH_BUCKET)
+            assert ladder.state_width_bucket(width) \
+                == ladder.pow2_at_least(width,
+                                         ladder.MIN_STATE_WIDTH_BUCKET)
 
     def test_chunk_and_capacity_pure_functions_of_bucket(self):
         from jepsen_tpu.engine.ladder import mega_chunk, state_capacity
         # raw widths sharing a rung derive identical chunk/capacity
         for a, b in ((5, 8), (9, 16), (17, 32), (33, 64)):
-            assert buckets.state_width_bucket(a) \
-                == buckets.state_width_bucket(b)
+            assert ladder.state_width_bucket(a) \
+                == ladder.state_width_bucket(b)
             assert mega_chunk(64, 128, a) == mega_chunk(64, 128, b)
             assert state_capacity(128, 8, a) == state_capacity(128, 8, b)
         # the register rung is undamped: exactly the PR 6 derivations
-        assert mega_chunk(64, 128, 1) == pbatch._batch_chunk(64, 128)
-        assert state_capacity(64, 8, 1) == buckets.wgl_start_capacity(64, 8)
+        assert mega_chunk(64, 128, 1) == ladder.batch_chunk(64, 128)
+        assert state_capacity(64, 8, 1) == ladder.wgl_start_capacity(64, 8)
         # wider rungs damp monotonically and never break the floors
         caps = [state_capacity(64, 8, w) for w in (1, 8, 34, 128)]
         assert caps == sorted(caps, reverse=True)
-        assert all(c >= buckets.MIN_WGL_CAPACITY for c in caps)
+        assert all(c >= ladder.MIN_WGL_CAPACITY for c in caps)
         chunks = [mega_chunk(64, 2048, w) for w in (1, 8, 34, 128)]
         assert chunks == sorted(chunks, reverse=True)
         assert all(c >= 64 and c % 64 == 0 for c in chunks)
@@ -318,7 +318,7 @@ class TestRoutingRegistry:
         assert not s._mega_eligible(("elle", ("fifo-queue", ()), 64))
         assert not s._mega_eligible(
             ("wgl", ("cas-register", ()),
-             buckets.MEGA_EVENTS_MAX * 2, 8))
+             ladder.MEGA_EVENTS_MAX * 2, 8))
         monkeypatch.setenv("JEPSEN_TPU_MEGABATCH", "0")
         assert not s._mega_eligible(("wgl", ("cas-register", ()), 64, 8))
 

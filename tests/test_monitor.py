@@ -22,6 +22,7 @@ from jepsen_tpu import core
 from jepsen_tpu import generator as gen
 from jepsen_tpu.checker import Stats, compose, wgl_cpu
 from jepsen_tpu.checker.linearizable import Linearizable, linearizable
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.history import History, INVOKE, NEMESIS, Op
 from jepsen_tpu.independent import IndependentChecker, subhistory
 from jepsen_tpu.models import CASRegister
@@ -31,7 +32,6 @@ from jepsen_tpu.monitor.epochs import (
     ElleEpochEngine, KeyFrontier, WglEpochEngine,
 )
 from jepsen_tpu.monitor.tap import OpTap
-from jepsen_tpu.serve import buckets
 from jepsen_tpu.serve.metrics import Metrics, mono_now
 from jepsen_tpu.synth import (
     cas_register_history, corrupt_list_append, corrupt_reads,
@@ -564,19 +564,19 @@ class TestMonitoredRun:
 class TestServeSatellites:
     def test_wgl_start_capacity_preserves_old_default(self):
         # w=8 (the common small-history bucket) derives the old fixed 256
-        assert buckets.wgl_start_capacity(64, 8) == 256
-        assert buckets.wgl_start_capacity(1024, 8) == 256
+        assert ladder.wgl_start_capacity(64, 8) == 256
+        assert ladder.wgl_start_capacity(1024, 8) == 256
 
     def test_wgl_start_capacity_ladder(self):
-        assert buckets.wgl_start_capacity(64, 16) == 1024
-        assert buckets.wgl_start_capacity(64, 32) == 4096
+        assert ladder.wgl_start_capacity(64, 16) == 1024
+        assert ladder.wgl_start_capacity(64, 32) == 4096
         # small windows are capped by the true subset bound 2**w
-        assert buckets.wgl_start_capacity(64, 4) == 64
+        assert ladder.wgl_start_capacity(64, 4) == 64
         # long histories nudge the floor up one rung
-        assert buckets.wgl_start_capacity(4096, 16) == 2048
+        assert ladder.wgl_start_capacity(4096, 16) == 2048
         # ... but never past the global ceiling
-        assert buckets.wgl_start_capacity(8192, 512) \
-            == buckets.MAX_WGL_CAPACITY
+        assert ladder.wgl_start_capacity(8192, 512) \
+            == ladder.MAX_WGL_CAPACITY
 
     def _sched_cell(self, sched, history, deadline_s=None, spec=None,
                     bucket=("wgl", "m", 64, 8)):
@@ -592,7 +592,7 @@ class TestServeSatellites:
         s = Scheduler(Metrics())          # never started: pure resolution
         derived = self._sched_cell(s, h)
         assert s._start_capacity([derived], 64, 8) \
-            == buckets.wgl_start_capacity(64, 8)
+            == ladder.wgl_start_capacity(64, 8)
         # env override beats the derivation
         monkeypatch.setenv("JEPSEN_TPU_WGL_CAPACITY", "123")
         assert s._start_capacity([derived], 64, 8) == 123

@@ -19,6 +19,7 @@ from jepsen_tpu import core
 from jepsen_tpu.checker import Stats, wgl_cpu
 from jepsen_tpu.checker.elle import ElleChecker
 from jepsen_tpu.checker.linearizable import Linearizable
+from jepsen_tpu.engine import ladder
 from jepsen_tpu.history import History, INFO, NEMESIS, Op
 from jepsen_tpu.independent import (
     DEFAULT_WORKERS, IndependentChecker, history_keys, subhistory,
@@ -28,7 +29,6 @@ from jepsen_tpu.models import CASRegister, get_model
 from jepsen_tpu.serve import (
     CheckService, ServiceClosed, ServiceSaturated,
 )
-from jepsen_tpu.serve import buckets
 from jepsen_tpu.serve.decompose import decompose
 from jepsen_tpu.serve.request import Request
 from jepsen_tpu.synth import (
@@ -55,7 +55,7 @@ def svc():
     # where it stood when this module's service came up so assertions on
     # "recompiles" measure THIS module, not whichever test files ran
     # earlier in the same process.
-    from jepsen_tpu.parallel.batch import engine_cache_stats
+    from jepsen_tpu.engine.cache import engine_cache_stats
     baseline = engine_cache_stats()["misses"]
     with CheckService(max_lanes=16) as s:
         s.test_recompile_baseline = baseline
@@ -64,28 +64,28 @@ def svc():
 
 class TestBuckets:
     def test_pow2_ladder(self):
-        assert buckets.pow2_at_least(1, 64) == 64
-        assert buckets.pow2_at_least(64, 64) == 64
-        assert buckets.pow2_at_least(65, 64) == 128
-        assert buckets.pow2_at_least(300, 64) == 512
+        assert ladder.pow2_at_least(1, 64) == 64
+        assert ladder.pow2_at_least(64, 64) == 64
+        assert ladder.pow2_at_least(65, 64) == 128
+        assert ladder.pow2_at_least(300, 64) == 512
 
     def test_wgl_bucket_floor(self):
         h = cas_register_history(30, concurrency=3, seed=1)
-        ev, w = buckets.wgl_bucket(h)
+        ev, w = ladder.wgl_bucket(h)
         assert ev == 64 and w == 8
 
     def test_width_bucket_counts_open_ops(self):
         h = cas_register_history(400, concurrency=20, seed=2)
-        assert buckets.width_bucket(h) >= 16
+        assert ladder.width_bucket(h) >= 16
 
     def test_elle_bucket_floor(self):
         h = list_append_history(10, seed=3)
-        assert buckets.elle_bucket(h) == (32,)
+        assert ladder.elle_bucket(h) == (32,)
 
     def test_lane_bucket(self):
-        assert buckets.lane_bucket(1) == 1
-        assert buckets.lane_bucket(3) == 4
-        assert buckets.lane_bucket(9999) == buckets.MAX_LANE_BUCKET
+        assert ladder.lane_bucket(1) == 1
+        assert ladder.lane_bucket(3) == 4
+        assert ladder.lane_bucket(9999) == ladder.MAX_LANE_BUCKET
 
 
 class TestDecompose:
@@ -129,7 +129,7 @@ class TestDecompose:
             want = subhistory(c.key, h)
             assert c.history == want
             assert [o.index for o in c.history] == list(range(len(want)))
-            assert c.bucket[2:] == buckets.wgl_bucket(want)
+            assert c.bucket[2:] == ladder.wgl_bucket(want)
             assert c.request is req
             assert [o.f for o in c.history if o.process == NEMESIS] == [
                 "start", "stop"]
@@ -582,8 +582,8 @@ class TestMetricsSchema:
 
 class TestSatellites:
     def test_engine_lru_bounded_with_counters(self):
-        from jepsen_tpu.parallel.batch import _LRUCache
-        c = _LRUCache(2)
+        from jepsen_tpu.engine.cache import EngineCache
+        c = EngineCache(2)
         assert c.get("a") is None
         c.put("a", 1)
         c.put("b", 2)
@@ -595,9 +595,9 @@ class TestSatellites:
         assert s["hits"] == 1 and s["misses"] == 2 and s["evictions"] == 1
 
     def test_engine_cache_env_sizing(self, monkeypatch):
-        from jepsen_tpu.parallel import batch
-        assert batch._CACHE.capacity >= 1
-        assert set(batch.engine_cache_stats()) >= {
+        from jepsen_tpu.engine import cache
+        assert cache.CACHE.capacity >= 1
+        assert set(cache.engine_cache_stats()) >= {
             "hits", "misses", "evictions", "size", "capacity"}
 
     def test_worker_count_resolution(self, monkeypatch):

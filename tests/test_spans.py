@@ -165,6 +165,12 @@ class TestPrimitive:
         assert "JTPU_TRACE" not in inspect.getsource(wgl_tpu)
         assert not hasattr(rec_mod, "CATEGORIES")
 
+    def test_the_compiled_engine_reads_no_environment(self):
+        import inspect
+        from jepsen_tpu.ops import dedup
+        for mod in (wgl_tpu, dedup):
+            assert "environ" not in inspect.getsource(mod), mod.__name__
+
 
 class TestOfflinePath:
     """One ``core.analyze`` with the recorder on: the table of span names
