@@ -21,17 +21,23 @@ round-trips and no dynamic allocation.  Three pieces:
   thousand of C*W cells, and did it with :func:`compact_rows` until PR 28:
   one 245,760-row sort a round, 58% of the 10k-op crash cell's verdict.
 
-What a TPU v5e charges (``scripts/compact_bench.py`` and the traces of
-the benchmark's cells; PERF.md, PR 28).  The old candidate compaction at
-C 4,096 x W 60, grid and sort: 0.52 ms a round; rank and select 0.03 ms.
-A gather runs one element after another, 5 to 8 ns each (a binary search
-of 12 rounds over 2,048 outputs: 0.19 ms; each 6,144-element gather of the
-subsumption probe below: 0.04 ms), so a gather is cheap only where its
-OUTPUT is small, and a search by gathers never is.  A [NC, C] one-hot
-times a narrow table on the MXU, the one-hot made inside the dot's fusion,
-beats every form that gathers up to C 8,192.  Scatters were measured
-slower than sorts by the sessions before the benchmark and have not been
-measured since: nothing here scatters.
+What a TPU v5e charges (``scripts/compact_bench.py``,
+``scripts/probe_bench.py`` and the traces of the benchmark's cells;
+PERF.md, PR 28 and PR 30).  The old candidate compaction at C 4,096 x W
+60, grid and sort: 0.52 ms a round; rank and select 0.03 ms.  A gather
+runs one element after another, 5 to 8 ns each (a binary search of 12
+rounds over 2,048 outputs: 0.19 ms; one 6,144-element gather: 0.04 ms;
+262,144 elements, 512 rows x 512 lanes: 1.8 ms), so a gather is cheap only
+where its OUTPUT is small, and a search by gathers never is.  A [NC, C]
+one-hot times a narrow table on the MXU, the one-hot made inside the dot's
+fusion, beats every form that gathers up to C 8,192.  A static roll and a
+select over whole vectors are the cheap things: one step of
+:func:`head_words`' scan is 0.75 us at 6,144 rows x 2 ghost words (13
+steps, 9.8 us; the sort and three gathers it replaced: 147.5 us) and 3.2 us
+at 512 rows x 512 lanes (9 steps, 29.0 us against 5,420 us).  Scatters were
+measured slower than sorts by the sessions before the benchmark and have
+not been measured since: nothing here scatters, and since PR 30 nothing in
+:func:`sort_dedup_compact` under ``WIDE_SORT_ROWS`` gathers.
 """
 
 from __future__ import annotations
@@ -237,6 +243,36 @@ def _lex_perm(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
     return perm
 
 
+def head_words(is_head: jnp.ndarray,
+               cols: Sequence[jnp.ndarray]) -> List[jnp.ndarray]:
+    """For each row and each column of ``cols`` (all [n]), the column's
+    value at the nearest ``is_head`` row at or before the row: a segmented
+    copy scan by doubling, ``ceil(log2 n)`` steps of static rolls and
+    selects on whole vectors.
+
+    After the step of distance ``d``, ``have`` says that a head lies within
+    ``2d - 1`` rows behind, and the value is that head's.  The rolls wrap,
+    and that is harmless wherever row 0 is a head: row ``i`` has its head
+    by the time ``d`` passes ``i``, and takes nothing from the far end.
+    A row with no head at or before it gets an arbitrary row's value; the
+    caller masks those (only invalid rows are such).
+
+    No ``reduce_window`` (``lax.cummax`` inside a ``while_loop`` crashed the
+    TPU's compiler at about a million rows) and no strided slice
+    (``lax.associative_scan`` costs 1.2 to 8 times as much on a v5e:
+    ``scripts/probe_bench.py``).  At the largest merge the ladder reaches
+    (1,179,648 rows, 2 ghost words, 21 steps, inside a loop) the whole
+    dedup compiled on the chip in 237 s and ran in 11.2 ms a round.
+    """
+    n = is_head.shape[0]
+    have, vals, d = is_head, list(cols), 1
+    while d < n:
+        vals = [jnp.where(have, v, jnp.roll(v, d)) for v in vals]
+        have = have | jnp.roll(have, d)
+        d *= 2
+    return vals
+
+
 def sort_dedup_compact(cols: Sequence[jnp.ndarray],
                        valid: jnp.ndarray,
                        capacity: int,
@@ -304,36 +340,28 @@ def sort_dedup_compact(cols: Sequence[jnp.ndarray],
     drop = exact_same & jnp.roll(s_valid, 1)
 
     if s_ghost and SUBSUME:
-        # Group head per row: the index where the row's group starts.
-        # (cumsum + scatter/gather, NOT lax.cummax — cummax nested inside
-        # scan/while_loop control flow has crashed the TPU compiler at
-        # ~1M-row shapes; cumsum is already exercised by the compaction.)
+        # A group's head is its first row.  The rows are sorted by group,
+        # so a row's head is the nearest head at or before it, and its
+        # ghost words come down the sorted order (head_words); a gather
+        # would fetch them one row after another, 5-8 ns each.  A valid
+        # row is behind its head exactly when it is not one (row 0 heads
+        # the first group whenever any row is valid).
         is_head = s_valid & ~(same_as_prev & jnp.roll(s_valid, 1))
         idx = jnp.arange(n)
         seg = jnp.cumsum(is_head.astype(jnp.int32)) - 1
-        # Index of each group's head row, gather-side: one stable sort
-        # ranks the head rows' indices first, in order (scatters
-        # serialize on TPU — see compact_rows).
-        _, head_idx = jax.lax.sort(((~is_head).astype(jnp.int32),
-                                    idx.astype(jnp.int32)),
-                                   num_keys=1, is_stable=True)
-        head_of = jnp.take(head_idx, jnp.clip(seg, 0, n - 1))
-        in_group = s_valid & (head_of != idx) & (seg >= 0)
+        in_group = s_valid & ~is_head
         # Probe several earlier in-group rows: ANY earlier row with a
         # subset ghost bitset justifies the drop (its own drop reason, if
         # dropped, chains down to a kept subset).  A subset sorts before
         # its supersets, so probing the head plus a few nearby offsets
         # catches most dominated rows; leftovers only cost capacity.
-        # The head probe is the one true GATHER; the offset probes are
-        # static ROLLS guarded by a same-group check — a TPU row-gather
-        # serializes per element (3 probe gathers cost 31 us/round), a
-        # roll is parallel slices.  Equivalent hits: the old clamped
-        # probe max(idx-off, head_of) degenerated to the (already
-        # probed) head exactly when the roll's same-group guard fails.
+        # The offset probes are static rolls guarded by a same-group
+        # check: where the guard fails, the row ``off`` back lies before
+        # the head, and the head is probed already.
         subsumed = jnp.zeros(n, dtype=bool)
         hit = in_group
-        for c in s_ghost:
-            hit &= (c[jnp.maximum(head_of, 0)] & ~c) == 0
+        for c, head_c in zip(s_ghost, head_words(is_head, s_ghost)):
+            hit &= (head_c & ~c) == 0
         subsumed |= hit
         for off in (1, 2, 4, 8, 16)[:N_PROBES]:
             hit = in_group & (idx >= off) & (jnp.roll(seg, off) == seg)

@@ -280,8 +280,12 @@ class TestServiceIntegration:
 class TestProcFleetTracing:
     def test_wire_trace_fully_connected(self):
         from jepsen_tpu.serve.fleet import ProcFleet
+        # hedge_s: no hedge.  This test counts hops, and a first compile
+        # slower than the default hedge (2 s) sent the request to the
+        # sibling too: four hops on a busy machine (ROADMAP D11).
         fleet = ProcFleet(workers=2, spawn=False, max_lanes=8,
-                          capacity=64, default_deadline_s=60.0)
+                          capacity=64, default_deadline_s=60.0,
+                          hedge_s=60.0)
         try:
             req = fleet.submit(cas_register_history(30, seed=6),
                                kind="wgl", model="cas-register")

@@ -74,6 +74,206 @@ class TestDedup:
         assert bool(ref[4]) == bool(got[4])
 
 
+def _np_sort_dedup(cols, valid, capacity, ghost, origin=None):
+    """``sort_dedup_compact`` with ghost columns, in plain NumPy: lexsort,
+    each row's head as the first row of its (inv, cols) group, the subset
+    test word-wise against the head and against the rows ``off`` before it
+    in the same group, then the kept rows in order."""
+    from jepsen_tpu.ops import dedup
+    n = len(valid)
+    keys = [(~valid).astype(np.int32)] + list(cols) + list(ghost)
+    order = np.lexsort(tuple(reversed(keys)))
+    s_valid = valid[order]
+    s_cols = [c[order] for c in cols]
+    s_ghost = [g[order] for g in ghost]
+    idx = np.arange(n)
+    starts = np.ones(n, bool)
+    starts[1:] = (s_valid[1:] != s_valid[:-1]) | np.any(
+        [c[1:] != c[:-1] for c in s_cols], axis=0)
+    head = np.maximum.accumulate(np.where(starts, idx, 0))
+    in_group = s_valid & (head != idx)
+
+    def subset_of(at):
+        return np.all([(g[at] & ~g) == 0 for g in s_ghost], axis=0)
+
+    drop = in_group & subset_of(head)
+    for off in (1, 2, 4, 8, 16)[:dedup.N_PROBES]:
+        at = np.maximum(idx - off, 0)
+        drop |= in_group & (idx - off >= head) & subset_of(at)
+    keep = s_valid & ~drop
+    total = np.int32(keep.sum())
+
+    def fit(c):
+        out = np.zeros(capacity, c.dtype)
+        out[:min(total, capacity)] = c[keep][:capacity]
+        return out
+
+    out = ([fit(c) for c in s_cols + s_ghost],
+           np.arange(capacity) < total, total, total > capacity)
+    if origin is None:
+        return out
+    s_origin = origin[order]
+    return out + (np.any(keep & (s_origin == 1)), fit(s_origin))
+
+
+def _probe_case(kind, n, G, seed):
+    """cols, valid, ghost columns, origin: random rows in few groups with a
+    small ghost universe, so that subsets occur; ``kind`` bends one of
+    them."""
+    rng = np.random.default_rng([seed, n, G])
+    cols = [rng.integers(0, max(2, n // 12), n).astype(np.uint32),
+            rng.integers(-2, 2, n).astype(np.int32)]
+    valid = rng.random(n) < 0.7
+    if kind == "all_valid":
+        valid[:] = True
+    elif kind == "none_valid":
+        valid[:] = False
+    elif kind == "one_group":
+        cols = [np.full(n, 3, np.uint32), np.full(n, -1, np.int32)]
+    elif kind == "spanning_group":
+        cols = [np.full(n, 3, np.uint32), np.full(n, -1, np.int32)]
+        valid[:] = True
+    elif kind == "singletons":
+        cols[0] = rng.permutation(n).astype(np.uint32)
+    ghost = [rng.integers(0, 16, n).astype(np.uint32) for _ in range(G)]
+    origin = (rng.random(n) < 0.5).astype(np.int32)
+    return cols, valid, ghost, origin
+
+
+def _same_arrays(got, want):
+    got, want = (jax.tree_util.tree_leaves(x) for x in (got, want))
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert np.array_equal(g, w)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _dedup_jit(capacity, with_origin, cols, valid, ghost, origin):
+    return sort_dedup_compact(cols, valid, capacity, ghost_cols=ghost,
+                              origin=origin if with_origin else None)
+
+
+class TestHeadProbe:
+    """The subsumption probe reads its group head's ghost words from a
+    scan along the sorted rows: every array ``sort_dedup_compact`` returns
+    equals the NumPy reference's."""
+
+    KINDS = ("random", "all_valid", "none_valid", "one_group",
+             "spanning_group", "singletons")
+
+    @pytest.mark.parametrize("kind", KINDS)
+    @pytest.mark.parametrize("with_origin", [False, True])
+    @pytest.mark.parametrize("n", [8, 512, 1536, 6144])
+    @pytest.mark.parametrize("G", [1, 2])
+    def test_equals_the_numpy_reference(self, G, n, with_origin, kind):
+        cols, valid, ghost, origin = _probe_case(kind, n, G, 30)
+        capacity = max(4, n // 2)
+        want = _np_sort_dedup(cols, valid, capacity, ghost,
+                              origin if with_origin else None)
+        _same_arrays(_dedup_jit(capacity, with_origin, cols, valid, ghost,
+                                origin), want)
+        if kind == "random" and n >= 512:
+            # the case is worth its name: heads and offsets both hit
+            assert want[2] < valid.sum() and want[2] > 0
+
+    @pytest.mark.parametrize("n", [8, 512])
+    @pytest.mark.parametrize("G", [1, 2])
+    def test_under_vmap_lanes_of_different_kinds(self, G, n):
+        lanes = [_probe_case(kind, n, G, 31) for kind in
+                 ("random", "none_valid", "spanning_group", "singletons")]
+        batched = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *lanes)
+        got = jax.jit(jax.vmap(functools.partial(_dedup_jit, n // 2, True))
+                      )(*batched)
+        for i, (cols, valid, ghost, origin) in enumerate(lanes):
+            _same_arrays(jax.tree_util.tree_map(lambda x: x[i], got),
+                         _np_sort_dedup(cols, valid, n // 2, ghost, origin))
+
+    @pytest.mark.parametrize("kind", ["random", "spanning_group"])
+    @pytest.mark.parametrize("G", [1, 2])
+    def test_wide_sort_branch(self, G, kind, monkeypatch):
+        from jepsen_tpu.ops import dedup
+        monkeypatch.setattr(dedup, "WIDE_SORT_ROWS", 1)
+        cols, valid, ghost, origin = _probe_case(kind, 512, G, 32)
+        _same_arrays(
+            sort_dedup_compact(cols, valid, 200, ghost_cols=ghost,
+                               origin=origin),
+            _np_sort_dedup(cols, valid, 200, ghost, origin))
+
+    @pytest.mark.parametrize("n", [1, 2, 3, 5, 8, 100, 513])
+    def test_head_words_is_the_nearest_head_at_or_before(self, n):
+        from jepsen_tpu.ops.dedup import head_words
+        rng = np.random.default_rng(n)
+        is_head = rng.random(n) < 0.3
+        is_head[0] = True
+        cols = [rng.integers(0, 2**32, n, dtype=np.uint32) for _ in "ab"]
+        head = np.maximum.accumulate(np.where(is_head, np.arange(n), 0))
+        _same_arrays(head_words(jnp.asarray(is_head), cols),
+                     [c[head] for c in cols])
+
+    @pytest.mark.parametrize("G", [1, 2])
+    @pytest.mark.parametrize("vmapped", [False, True],
+                             ids=["plain", "vmapped"])
+    def test_lowers_to_two_sorts_and_no_gather(self, vmapped, G):
+        """What holds the gain: no gather anywhere in the dedup, and two
+        sorts a merge (the lexicographic one and ``compact_rows``'s), not
+        the three of the head-index form."""
+        args = _probe_case("random", 512, G, 33)
+        f = functools.partial(_dedup_jit, 256, True)
+        if vmapped:
+            f = jax.vmap(f)
+            args = jax.tree_util.tree_map(lambda x: jnp.stack([x] * 4),
+                                          args)
+        text = jax.jit(f).lower(*args).as_text()
+        assert "gather" not in text
+        assert text.count("stablehlo.sort") == 2
+
+    def test_the_chip_tool_runs_and_its_forms_agree(self):
+        tool = _script("probe_bench")
+        for lanes in (0, 2):
+            for what in ("probe", "merge"):
+                rows = [tool.bench(256, 2, lanes, 2, form, what)
+                        for form in tool.FORMS]
+                assert len({r["checksum"] for r in rows}) == 1, rows
+
+    @pytest.mark.parametrize("refuted", [False, True],
+                             ids=["valid", "refuted"])
+    def test_a_history_without_ghosts_never_enters_the_probe(
+            self, refuted, monkeypatch):
+        """The lean engine (no ``:info``, so no ghost column) is the bypass:
+        its search is the parent's to the count (recorded at PR 29's tree,
+        CPU backend), and the head scan is never traced."""
+        from jepsen_tpu.checker.prep import prepare
+        from jepsen_tpu.ops import dedup
+        model = get_model("cas-register")
+        h = cas_register_history(300, concurrency=6, crash_p=0.0,
+                                 seed=12 if refuted else 11)
+        if refuted:
+            h = corrupt_reads(h, n=1, seed=3)
+        assert wgl_tpu.chosen_gwords(prepare(h, model)) == 0
+
+        def never(is_head, cols):
+            raise AssertionError("the lean engine traced the head probe")
+
+        monkeypatch.setattr(dedup, "head_words", never)
+        TestCompactionInTheEngine._fresh_engines(monkeypatch)
+        r = wgl_tpu.check(model, h, capacity=128, chunk=64)
+        want = ({"valid": False, "configs-explored": 1469}
+                if refuted else
+                {"valid": True, "configs-explored": 5539,
+                 "closure-rounds": 514})
+        want.update({"analyzer": "wgl-tpu", "capacity": 512,
+                     "max-capacity-reached": 512, "window": 6})
+        assert {k: r[k] for k in want} == want
+        if refuted:
+            assert r["op"]["index"] == 215
+            assert r["witness"]["valid"] is False
+            assert r["witness"]["op"]["index"] == 215
+        else:
+            assert set(r) == set(want)
+
+
 class TestCompactRows:
     def test_matches_kept_rows_in_order(self):
         from jepsen_tpu.ops.dedup import compact_rows
@@ -128,14 +328,15 @@ def _grid_case(C, W, density, seed, model):
 
 
 @functools.lru_cache(maxsize=None)
-def _compact_bench():
-    """scripts/compact_bench.py as a module: it holds the one copy of the
-    compaction as it was before rank and select (``sort_compaction``)."""
+def _script(name):
+    """scripts/<name>.py as a module: ``compact_bench`` holds the one copy
+    of the compaction as it was before rank and select
+    (``sort_compaction``), ``probe_bench`` the head probe's gathers."""
     import importlib.util
     import pathlib
     path = pathlib.Path(__file__).resolve().parents[1] / "scripts" \
-        / "compact_bench.py"
-    spec = importlib.util.spec_from_file_location("compact_bench", path)
+        / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -145,7 +346,7 @@ def sort_compaction(step, mask, states, win_ops, cv, NC, form=None):
     """The reference: every cell's row, then one stable sort of all C*W of
     them (``compact_rows``); ``form`` is taken and ignored so that it can
     stand in for ``compact_candidates`` inside an engine."""
-    return _compact_bench().sort_compaction(step, mask, states, win_ops,
+    return _script("compact_bench").sort_compaction(step, mask, states, win_ops,
                                             cv, NC)
 
 
@@ -199,7 +400,7 @@ class TestCompactGrid:
                        self.old(model.step, *lane, 40))
 
     def test_the_chip_tool_runs_and_its_forms_agree(self):
-        tool = _compact_bench()
+        tool = _script("compact_bench")
         rows = [tool.bench(get_model("cas-register"), 32, 12, 16, lanes,
                            form, 2, 0.3)
                 for lanes in (0, 2) for form in tool.FORMS]
