@@ -41,6 +41,7 @@ device-local slice of the deduplicated global set.
 from __future__ import annotations
 
 import math
+import threading
 from collections import deque
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -120,6 +121,46 @@ MATMUL_COMPACT_MAX_C = 8192
 #: return's prune; the single-round variant's event gather.
 ENGINE_SCOPES = ("wgl.expand", "wgl.compact", "wgl.merge", "wgl.sort_dedup",
                  "wgl.prune", "wgl.gather")
+
+
+# ---------------------------------------------------------------------------
+# Counters (batch_stats idiom): what the static capacity spanned against
+# what the frontier filled of it
+# ---------------------------------------------------------------------------
+
+#: the rung ``events_consumed_16k`` counts from: the last one before the
+#: fission threshold (``engine/fission.py DEFAULT_THRESHOLD``)
+TOP_RUNG = 16384
+
+_STATS_LOCK = threading.Lock()
+
+
+def _zero_stats() -> Dict[str, int]:
+    return {"events_consumed": 0, "events_consumed_16k": 0,
+            "cap_events": 0, "peak_events": 0}
+
+
+_STATS = _zero_stats()
+
+
+def check_stats() -> Dict[str, int]:
+    """Sums over every poll a :func:`check` of this process accepted (one
+    whose carry it went on from; a chunk re-run at a larger capacity is
+    not one), weighted by the events the chunk consumed, so that closure
+    rounds count on neither side: ``events_consumed``; ``events_consumed_16k``,
+    those consumed at a capacity of ``TOP_RUNG`` or more; ``cap_events``,
+    capacity x events (every merge of a chunk pays for the static
+    capacity); ``peak_events``, the chunk's frontier high-water mark x
+    events.  ``peak_events / cap_events`` is how full the capacity was
+    over the stream: the single history's twin of ``batch_stats()``'s
+    fill."""
+    with _STATS_LOCK:
+        return dict(_STATS)
+
+
+def reset_check_stats() -> None:
+    with _STATS_LOCK:
+        _STATS.update(_zero_stats())
 
 
 def closure_budget(capacity: int) -> int:
@@ -957,11 +998,17 @@ def chunk_for_capacity(capacity: int, base_chunk: int) -> int:
 #: chunks discard more speculative work per change).  Escalation
 #: pressure has two measured drivers: ghosts (each pending crashed op
 #: can double the config set) and multi-lane state (wider state, bigger
-#: spaces).  Measured on hardware, 10k-op histories: register-easy
-#: (~3 ghosts, 1 lane) 3.08 s at 1024 vs 3.81 s at 512; register-hard
-#: (56 ghosts) 8.7 s at 512 vs 10.3 s at 1024; multi-register (7
-#: ghosts but 3 state lanes, escalates to 16384) 36.2 s at 512 vs
-#: 40.6 s at 1024.
+#: spaces).  Measured on hardware, 10k-op histories, on the code from
+#: before PR 21 (the merge has lost a C x W sort and its gathers since,
+#: so read these as the rule's origin, not as today's times):
+#: register-easy (~3 ghosts, 1 lane) 3.08 s at 1024 vs 3.81 s at 512;
+#: register-hard (56 ghosts) 8.7 s at 512 vs 10.3 s at 1024;
+#: multi-register (7 ghosts but 3 state lanes, escalates to 16384)
+#: 36.2 s at 512 vs 40.6 s at 1024.  Today (PR 31, one TPU v5 lite
+#: chip) that multi-register history takes 7.1 s a call at 512
+#: (`multireg10k.offline`; PERF.md section 6), 5.6 s of it at 16384,
+#: where the closure budget, not the chunk, ends a dispatch after about
+#: 150 events; 1024 has not been timed again.
 AUTO_CHUNK_FINE = 512
 AUTO_CHUNK_COARSE = 1024
 AUTO_CHUNK_GHOST_MAX = 8
@@ -1018,7 +1065,8 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
            growth: int) -> Dict[str, Any]:
     """:func:`check` under its ``drivers.check`` span ``sp``, which closes
     with what the driver did: dispatches, speculative chunks discarded,
-    grows, shrinks, budget-pause resumes and the longest poll."""
+    grows, shrinks, budget-pause resumes, the longest poll and the call's
+    own share of :func:`check_stats`' four sums."""
     p = prepared if prepared is not None else prepare(
         history, model, max_window=max_window)
     if chunk is None:
@@ -1071,7 +1119,7 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     pos = 0
     # what the drivers.check span closes with
     did = {"dispatches": 0, "discarded": 0, "grows": 0, "shrinks": 0,
-           "resumes": 0, "poll_max_s": 0.0}
+           "resumes": 0, "poll_max_s": 0.0, **_zero_stats()}
 
     def discard(why: str) -> None:
         """Drop the speculative chunks in flight: device work done for
@@ -1142,6 +1190,10 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
                 overflow = False
                 continue
             done = after
+            did["events_consumed"] += consumed
+            did["events_consumed_16k"] += consumed if cap >= TOP_RUNG else 0
+            did["cap_events"] += cap * consumed
+            did["peak_events"] += peak * consumed
             if failed or overflow:
                 discard("stop")
                 break
@@ -1189,6 +1241,9 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     finally:
         rung.__exit__(None, None, None)
         sp.set(max_capacity=max_cap_reached, **did)
+        with _STATS_LOCK:
+            for k in _STATS:
+                _STATS[k] += did[k]
 
     if overflow:
         # ``explored`` only accumulates at converged RETURN prunes; a

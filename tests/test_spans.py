@@ -33,6 +33,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHECK_SPANS = {"drivers.check", "prepare", "drivers.stage", "drivers.rung",
                "drivers.dispatch", "drivers.poll"}
 
+#: what ``drivers.check`` opens and closes with (docs/observability.md)
+CHECK_CLOSES_WITH = {"events", "chunk", "window", "gwords", "max_capacity",
+                     "dispatches", "discarded", "grows", "shrinks",
+                     "resumes", "poll_max_s", "events_consumed",
+                     "events_consumed_16k", "cap_events", "peak_events"}
+
 
 @pytest.fixture
 def rec():
@@ -329,6 +335,9 @@ class TestOfflinePath:
         assert len(names["drivers.dispatch"]) == did["dispatches"]
         assert did["poll_max_s"] == max(
             e["dur-s"] for e in names["drivers.poll"])
+        # the sums themselves: tests/test_multireg_config.py
+        assert set(did) == CHECK_CLOSES_WITH
+        assert 0 < did["peak_events"] <= did["cap_events"]
 
     def test_recorder_off_same_verdict_nothing_recorded(self, rec, model):
         h = cas_register_history(120, concurrency=4, crash_p=0.01, seed=3)
