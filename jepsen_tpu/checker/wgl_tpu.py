@@ -55,7 +55,9 @@ from jepsen_tpu.checker.prep import (
     EV_ENTER, EV_RETURN, PreparedHistory, WindowOverflow, prepare,
 )
 from jepsen_tpu.engine.cache import CACHE as _ENGINE_CACHE
-from jepsen_tpu.engine.ladder import round_window as _round_window
+from jepsen_tpu.engine.ladder import (
+    MIN_EVENTS_BUCKET, pow2_at_least, round_window as _round_window,
+)
 from jepsen_tpu.engine.witness import (
     WITNESS_BUDGET, cpu_witness as _cpu_witness,
 )
@@ -85,14 +87,17 @@ LOOKAHEAD = 2
 # ~60 s watchdog.  Instead each chunk carries an iteration budget
 # (CLOSURE_WORK_BUDGET / capacity); when it runs out the remaining events
 # gate to no-ops, the flags report how many events were really consumed,
-# and the host resumes mid-chunk with a fresh budget.  (4M with the delta
+# and the next dispatch resumes mid-chunk with a fresh budget.  A pause
+# costs one more dispatch and its poll, nothing else: the chunk in flight
+# behind the paused one starts at the pause (the event cursor rides on the
+# device, see _get_run_chunk), so no speculative dispatch is thrown away
+# and the poll overlaps the next chunk's compute.  (4M with the delta
 # closure's compacted merges: per-iteration cost dropped ~4x vs the block
 # closure, so the same watchdog margin affords more iterations per
-# dispatch — fewer budget pauses means fewer discarded speculative
-# dispatches; measured easy-tier 7.5 s vs 7.8 s at 3M, hard tier
-# unchanged.  At capacity 65536 this is 61 iterations/dispatch, which
-# stays inside the watchdog even when rounds take the full-grid fallback
-# merge.)
+# dispatch; measured easy-tier 7.5 s vs 7.8 s at 3M, hard tier unchanged,
+# when a pause still discarded the chunk behind it.  At capacity 65536
+# this is 61 iterations/dispatch, which stays inside the watchdog even
+# when rounds take the full-grid fallback merge.)
 CLOSURE_WORK_BUDGET = 4_000_000
 
 #: Histories with at most this many ghost (crashed/info) ops run the LEAN
@@ -911,7 +916,14 @@ def _chunk_slicer(chunk: int, axis: int = 0):
 
 
 def _get_run_chunk(model: JaxModel, window: int, capacity: int,
-                   gwords: int = 1):
+                   gwords: int, chunk: int):
+    """``(carry0, run)`` of the single-history driver: ``run(carry, cursor,
+    ev_dev) -> (carry', cursor + consumed, flags)`` is ONE program that
+    cuts its own ``chunk`` events out of the staged stream at ``cursor``,
+    a device scalar, and scans them with ``make_engine``'s ``run_chunk``.
+    The position so travels with the carry: a chunk enqueued behind one
+    that the closure budget paused starts where that one stopped, without
+    the host having seen where that is."""
     # Same-named registry models share step semantics; keying on the name +
     # variant + initial state (not the closure id) lets every get_model()
     # call reuse one compiled engine.  Entries live in the shared bounded
@@ -921,18 +933,32 @@ def _get_run_chunk(model: JaxModel, window: int, capacity: int,
     # from colliding.
     key = ("singlev", model.name, model.variant, model.state_size,
            tuple(model.init_state_array().tolist()), window, capacity,
-           gwords, _dedup.N_PROBES, _dedup.WIDE_SORT_ROWS, _dedup.SUBSUME,
-           CLOSURE_WORK_BUDGET)
+           gwords, chunk, _dedup.N_PROBES, _dedup.WIDE_SORT_ROWS,
+           _dedup.SUBSUME, CLOSURE_WORK_BUDGET)
     hit = _ENGINE_CACHE.get(key)
     if hit is not None:
         return hit
     carry0, _, run_chunk = make_engine(model, window, capacity,
                                        gwords=gwords)
+
+    def run_at(carry, cursor, ev_dev):
+        # The barrier is for the TPU compiler, and measured (PERF.md, PR
+        # 32): with a bare slice of the parameter, the capacity-1,024
+        # engine's two usual merge branches lost the prefetch of mask and
+        # states into fast memory (+0.85% on a clean 10k-op history, 12
+        # ms of device time a call); behind the barrier they keep it, and
+        # the 4,096 and 16,384 engines compile to the same loops either
+        # way.
+        events = lax.dynamic_slice_in_dim(
+            *lax.optimization_barrier((ev_dev, cursor)), chunk)
+        carry, flags = run_chunk(carry, events)
+        return carry, cursor + flags[3], flags
+
     # No donation: the overflow-resume path re-uses the chunk-boundary
     # carry snapshot after the call, and the buffers are small anyway.
     from jepsen_tpu.obs.hist import timed_first_call
     run = timed_first_call(
-        jax.jit(run_chunk),
+        jax.jit(run_at),
         f"compile:singlev:{model.name}:w{window}:c{capacity}")
     return _ENGINE_CACHE.put(key, (carry0, run))
 
@@ -986,8 +1012,8 @@ def chunk_for_capacity(capacity: int, base_chunk: int) -> int:
     enforced *inside* a single closure's fixpoint loop with mid-event
     pause/resume) now bounds a dispatch's wall-clock tightly at any
     capacity, so the chunk no longer needs to shrink: a capacity
-    escalation keeps the same dispatch granularity and the host just
-    resumes mid-chunk whenever the engine pauses."""
+    escalation keeps the same dispatch granularity and the next dispatch
+    just goes on mid-chunk whenever the engine pauses."""
     return base_chunk
 
 
@@ -1008,7 +1034,10 @@ def chunk_for_capacity(capacity: int, base_chunk: int) -> int:
 #: chip) that multi-register history takes 7.1 s a call at 512
 #: (`multireg10k.offline`; PERF.md section 6), 5.6 s of it at 16384,
 #: where the closure budget, not the chunk, ends a dispatch after about
-#: 150 events; 1024 has not been timed again.
+#: 150 events.  Since PR 32 such a pause discards nothing (the chunk
+#: behind it goes on from the pause), so there the chunk only sets how
+#: many events a dispatch may cover at most; 1024 has not been timed
+#: again.
 AUTO_CHUNK_FINE = 512
 AUTO_CHUNK_COARSE = 1024
 AUTO_CHUNK_GHOST_MAX = 8
@@ -1065,8 +1094,9 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
            growth: int) -> Dict[str, Any]:
     """:func:`check` under its ``drivers.check`` span ``sp``, which closes
     with what the driver did: dispatches, speculative chunks discarded,
-    grows, shrinks, budget-pause resumes, the longest poll and the call's
-    own share of :func:`check_stats`' four sums."""
+    grows, shrinks, budget-pause resumes, the chunks that continued from
+    such a pause, the longest poll and the call's own share of
+    :func:`check_stats`' four sums."""
     p = prepared if prepared is not None else prepare(
         history, model, max_window=max_window)
     if chunk is None:
@@ -1074,24 +1104,28 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     window = _round_window(p.window)
     gw = chosen_gwords(p)
     sp.set(events=len(p), chunk=chunk, window=window, gwords=gw)
-    # Pad the event stream to a chunk multiple PLUS one chunk-sized NOP
-    # cushion: progress is tracked in *event* units, and the cushion
-    # guarantees any in-bounds dispatch offset
-    # can slice a full chunk without clamping back into (and re-applying!)
-    # real events.  Trailing NOPs are inert.  Small-chunk callers keep
-    # their small streams — padding to a fixed 512 would multiply
-    # dispatches on short histories, and every dispatch costs a host
-    # poll.
+    # Pad the event stream to a chunk multiple PLUS at least one
+    # chunk-sized NOP cushion: progress is tracked in *event* units, and
+    # the cushion guarantees that a dispatch starting anywhere before
+    # ``n_events`` slices a full chunk without clamping back into (and
+    # re-applying!) real events.  Trailing NOPs are inert.  The staged
+    # array's length is part of the runner's compiled shape (the runner
+    # slices it), so it goes up to the event ladder's next power of two:
+    # histories of any length share a few programs, not one each.
+    # ``n_events`` stays the chunk multiple: small-chunk callers keep
+    # their small streams, and every dispatch costs a host poll.
     base = chunk
     with span("drivers.stage") as stage:
         ev = events_array(p, base)
         n_events = ev.shape[0]
-        ev = np.concatenate([ev, ev[:1].repeat(base, axis=0) * 0])
+        rows = pow2_at_least(n_events + base, MIN_EVENTS_BUCKET)
+        ev = np.concatenate([ev, np.zeros((rows - n_events, 10), np.int32)])
         ev[n_events:, 0] = EV_NOP
         # One host->device transfer for the whole stream; per-chunk slices
-        # then happen device-side.  A per-chunk jnp.asarray would be a
-        # blocking ~12 KB host→device transfer per dispatch, which can
-        # cost more than the chunk's compute on an easy history.
+        # then happen device-side, inside the runner.  A per-chunk
+        # jnp.asarray would be a blocking ~12 KB host→device transfer per
+        # dispatch, which can cost more than the chunk's compute on an
+        # easy history.
         ev_dev = jnp.asarray(ev)
         stage.set(bytes=ev.nbytes)
 
@@ -1102,7 +1136,6 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     # watchdog bounding comes from the closure work budget + mid-chunk
     # resume, not from shrinking chunks.
     cur_chunk = chunk_for_capacity(cap, chunk)
-    slice_chunk = _chunk_slicer(cur_chunk)
     # (peak, events-consumed) samples since the last capacity change.  With
     # budget pauses a dispatch can cover anywhere from 0 to cur_chunk
     # events, so shrink-back decisions weigh samples by the events they
@@ -1111,15 +1144,28 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     recent_peaks: deque = deque()
     # Pipelined dispatch: keep LOOKAHEAD chunks in flight so the
     # device→host flags transfer of chunk i overlaps with the device
-    # computing chunk i+1.  Speculation is safe: once the
-    # failed/overflow lane is set, event_step gates all updates, so
-    # speculative chunks past a failure compute nothing wrong — they are
-    # simply discarded on resume.
-    inflight: deque = deque()  # (pos, carry_before, carry_after, flags)
-    pos = 0
+    # computing chunk i+1.  The position in the stream is a device scalar
+    # that travels with the carry (``cursor``; see _get_run_chunk), so a
+    # chunk enqueued behind its predecessor starts where that one really
+    # stopped: after a full chunk, or at the RETURN where the closure
+    # budget paused it, which it then resumes with a fresh budget.  A
+    # pause so costs one poll, overlapped like any other, and no device
+    # work.  Speculation is safe: once the failed/overflow lane is set,
+    # event_step gates all updates, so speculative chunks past a failure
+    # compute nothing wrong — they are dropped (``discard``), as are those
+    # in flight when the capacity changes.
+    # (carry and cursor before the chunk, the same after it, flags)
+    inflight: deque = deque()
+    # Events the accepted polls consumed: the cursor of the oldest chunk
+    # in flight, which is all the host knows of the position.  A chunk in
+    # flight consumes at most cur_chunk, so a dispatch behind ``known +
+    # len(inflight) * cur_chunk < n_events`` starts before n_events and
+    # its slice ends inside the cushion.
+    known = 0
+    paused = False  # the last accepted poll had stopped short of its chunk
     # what the drivers.check span closes with
     did = {"dispatches": 0, "discarded": 0, "grows": 0, "shrinks": 0,
-           "resumes": 0, "poll_max_s": 0.0, **_zero_stats()}
+           "resumes": 0, "continued": 0, "poll_max_s": 0.0, **_zero_stats()}
 
     def discard(why: str) -> None:
         """Drop the speculative chunks in flight: device work done for
@@ -1136,18 +1182,18 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     def change_rung(why: str) -> None:
         """``cap`` changed: drop what is in flight and its evidence, and
         open the next ``drivers.rung`` with that capacity's engine."""
-        nonlocal rung, run_chunk
+        nonlocal rung, run
         recent_peaks.clear()
         discard(why)
         rung.__exit__(None, None, None)
         rung = span("drivers.rung", cap=cap).__enter__()
-        _, run_chunk = _get_run_chunk(model, window, cap, gw)
+        _, run = _get_run_chunk(model, window, cap, gw, cur_chunk)
 
     try:
-        carry0, run_chunk = _get_run_chunk(model, window, cap, gw)
-        carry = carry0()
-        # n_events >= 512 always, so the loop pops at least once and
-        # failed/overflow/carry are always (re)assigned before use below.
+        carry0, run = _get_run_chunk(model, window, cap, gw, cur_chunk)
+        carry, cursor = carry0(), np.int32(0)
+        # n_events >= chunk always, so the loop pops at least once and
+        # failed/overflow/done are always (re)assigned before use below.
         while True:
             # Poll cancellation before refilling the pipeline, so a lost
             # race doesn't dispatch up to LOOKAHEAD more chunks of
@@ -1156,40 +1202,45 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
                 discard("cancel")
                 return {"valid": "unknown", "analyzer": "wgl-tpu",
                         "cancelled": True}
-            while len(inflight) < LOOKAHEAD and pos < n_events:
-                prev = carry
-                with span("drivers.dispatch", pos=pos):
-                    carry, flags = run_chunk(carry, slice_chunk(ev_dev, pos))
+            while len(inflight) < LOOKAHEAD:
+                # where the next chunk starts at the latest (its cursor is
+                # the device's to know until the chunks before it are polled)
+                latest = known + len(inflight) * cur_chunk
+                if latest >= n_events:
+                    break
+                prev = (carry, cursor)
+                with span("drivers.dispatch", pos=latest):
+                    carry, cursor, flags = run(carry, cursor, ev_dev)
                 did["dispatches"] += 1
-                inflight.append((pos, prev, carry, flags))
-                pos += cur_chunk
+                inflight.append((prev, (carry, cursor), flags))
             if not inflight:
                 break
-            cpos, prev, after, flags = inflight.popleft()
+            prev, after, flags = inflight.popleft()
             with span("drivers.poll") as poll:
                 fl = np.asarray(flags)
                 failed, overflow = bool(fl[0]), bool(fl[1])
                 peak = int(fl[2])
                 consumed = int(fl[3])
-                poll.set(pos=cpos, cap=cap, peak=peak, consumed=consumed,
+                poll.set(pos=known, cap=cap, peak=peak, consumed=consumed,
                          overflow=overflow)
             did["poll_max_s"] = max(did["poll_max_s"], poll.dur_s)
             if overflow and cap < max_capacity:
                 # Grow straight to a capacity the observed peak says is
                 # enough (peak is a lower bound on the true need — it may
                 # itself have been clipped — so the loop can escalate
-                # again) and resume from the snapshot: no restart, no
-                # re-search of the prefix.
+                # again) and resume from the snapshot, carry and cursor:
+                # no restart, no re-search of the prefix.
                 while cap < max_capacity and cap < 2 * peak:
                     cap = min(cap * growth, max_capacity)
                 max_cap_reached = max(max_cap_reached, cap)
                 did["grows"] += 1
                 change_rung("grow")
-                carry = _grow_carry(prev, cap)
-                pos = cpos
+                carry, cursor = _grow_carry(prev[0], cap), prev[1]
                 overflow = False
                 continue
-            done = after
+            done = after[0]
+            known += consumed
+            did["continued"] += paused
             did["events_consumed"] += consumed
             did["events_consumed_16k"] += consumed if cap >= TOP_RUNG else 0
             did["cap_events"] += cap * consumed
@@ -1197,12 +1248,19 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
             if failed or overflow:
                 discard("stop")
                 break
+            # Closure budget exhausted mid-chunk: the unconsumed tail was
+            # gated to no-ops.  Nothing to repair: the chunk in flight
+            # behind this one took the paused carry and its cursor, and
+            # went on at the pause.  (The budget keeps one XLA program's
+            # wall time bounded by work, under the TPU worker's watchdog,
+            # regardless of config-count superlinearity.)
+            paused = consumed < cur_chunk
+            did["resumes"] += paused
             recent_peaks.append((peak, consumed))
             covered = sum(e for _, e in recent_peaks)
             while len(recent_peaks) > 1 and \
                     covered - recent_peaks[0][1] >= SHRINK_WINDOW:
                 covered -= recent_peaks.popleft()[1]
-            resumed = consumed < cur_chunk
             if cap > capacity and covered >= SHRINK_WINDOW:
                 # Crash-bursts inflate the configuration set transiently.
                 # The per-round sort cost scales with the *static*
@@ -1222,20 +1280,7 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
                     cap = target
                     did["shrinks"] += 1
                     change_rung("shrink")
-                    carry = _shrink_carry(after, cap)
-                    pos = cpos + consumed
-                    continue
-            if resumed:
-                # Closure budget exhausted mid-chunk: the unconsumed tail
-                # was gated to no-ops, and any speculative chunks skipped
-                # it — discard them and resume exactly where the engine
-                # stopped.  (Keeps one XLA program's wall time bounded by
-                # work, under the TPU worker's watchdog, regardless of
-                # config-count superlinearity.)
-                discard("resume")
-                did["resumes"] += 1
-                carry = after
-                pos = cpos + consumed
+                    carry, cursor = _shrink_carry(after[0], cap), after[1]
         carry = done
         explored = int(carry[9])
     finally:

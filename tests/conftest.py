@@ -18,6 +18,7 @@ if "xla_force_host_platform_device_count" not in xla_flags:
         xla_flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -26,3 +27,15 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: long-running; tier-1 deselects these (-m 'not slow')")
+
+
+@pytest.fixture
+def rec():
+    """The process-wide flight recorder, on and empty; restored afterwards."""
+    from jepsen_tpu.obs.recorder import RECORDER
+    was = RECORDER.enabled
+    RECORDER.enable()
+    RECORDER.clear()
+    yield RECORDER
+    RECORDER.enabled = was
+    RECORDER.clear()

@@ -22,9 +22,7 @@ from jepsen_tpu.checker.linearizable import linearizable
 from jepsen_tpu.history import History
 from jepsen_tpu.models import get_model
 from jepsen_tpu.obs import hist, recorder as rec_mod
-from jepsen_tpu.obs.recorder import (
-    RECORDER, adopt, carry, instant, span,
-)
+from jepsen_tpu.obs.recorder import adopt, carry, instant, span
 from jepsen_tpu.synth import cas_register_history, corrupt_reads
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,19 +34,8 @@ CHECK_SPANS = {"drivers.check", "prepare", "drivers.stage", "drivers.rung",
 #: what ``drivers.check`` opens and closes with (docs/observability.md)
 CHECK_CLOSES_WITH = {"events", "chunk", "window", "gwords", "max_capacity",
                      "dispatches", "discarded", "grows", "shrinks",
-                     "resumes", "poll_max_s", "events_consumed",
+                     "resumes", "continued", "poll_max_s", "events_consumed",
                      "events_consumed_16k", "cap_events", "peak_events"}
-
-
-@pytest.fixture
-def rec():
-    """The process-wide recorder, on and empty; restored afterwards."""
-    was = RECORDER.enabled
-    RECORDER.enable()
-    RECORDER.clear()
-    yield RECORDER
-    RECORDER.enabled = was
-    RECORDER.clear()
 
 
 def by_name(events):
