@@ -44,7 +44,7 @@ import math
 import threading
 from collections import deque
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -123,9 +123,10 @@ MATMUL_COMPACT_MAX_C = 8192
 #: program is the same with or without them).  In order: the expansion
 #: grid; candidate compaction; the merge's concatenate, ghost
 #: canonicalisation and its inverse; the sort-dedup-compact call; the
-#: return's prune; the single-round variant's event gather.
+#: return's prune; the single-round variant's event gather; the sharded
+#: variant's ``all_gather`` of every shard's rows (``axis_name`` only).
 ENGINE_SCOPES = ("wgl.expand", "wgl.compact", "wgl.merge", "wgl.sort_dedup",
-                 "wgl.prune", "wgl.gather")
+                 "wgl.prune", "wgl.gather", "wgl.gather_shards")
 
 
 # ---------------------------------------------------------------------------
@@ -431,13 +432,11 @@ def make_engine(model: JaxModel, window: int, capacity: int,
                 origin = jnp.concatenate([exist_origin,
                                           jnp.ones(nc, jnp.int32)])
                 if axis_name is not None:
-                    all_mask = lax.all_gather(all_mask, axis_name,
-                                              tiled=True)
-                    all_states = lax.all_gather(all_states, axis_name,
-                                                tiled=True)
-                    all_valid = lax.all_gather(all_valid, axis_name,
-                                               tiled=True)
-                    origin = lax.all_gather(origin, axis_name, tiled=True)
+                    with jax.named_scope("wgl.gather_shards"):
+                        all_mask, all_states, all_valid, origin = (
+                            lax.all_gather(x, axis_name, tiled=True)
+                            for x in (all_mask, all_states, all_valid,
+                                      origin))
                 if GW:
                     keyed = all_mask & ~ghosts[None, :]
                     gpart = canonical_compact(all_mask & ghosts[None, :],
@@ -1050,16 +1049,63 @@ def auto_chunk(p: PreparedHistory, model: JaxModel) -> int:
             else AUTO_CHUNK_FINE)
 
 
+class Snapshot(NamedTuple):
+    """Where a search stood before the chunk that outgrew its ceiling: the
+    chunk-boundary ``carry``, the event ``cursor`` that goes with it, and
+    the ``peak`` the chunk reported (a lower bound on what the frontier
+    needs).  What one placement hands to the next (``resume``): the
+    search goes on from here, not from event 0."""
+    carry: Tuple[Any, ...]
+    cursor: int
+    peak: int
+
+
+class OneDevice:
+    """Where :func:`_check` keeps a search and how it re-lays it there: the
+    default device, the frontier in one piece.  ``parallel.sharded.OnMesh``
+    is the other placement, the frontier divided over a mesh axis; the
+    loop is the same, and reads from its placement the runner of a
+    capacity (``capacity`` rows a shard), the staged stream, the carry
+    resized, the pipeline's depth and the name the verdict goes out
+    under; a placement that takes a search over (``resume``) also lays
+    the snapshot's carry out its own way (``adopt``)."""
+
+    analyzer = "wgl-tpu"
+    shards = 1
+
+    @property
+    def lookahead(self) -> int:
+        return LOOKAHEAD
+
+    def runner(self, model: JaxModel, window: int, capacity: int,
+               gwords: int, chunk: int):
+        return _get_run_chunk(model, window, capacity, gwords, chunk)
+
+    def stage(self, ev: np.ndarray):
+        return jnp.asarray(ev)
+
+    def grow(self, carry, capacity: int):
+        return _grow_carry(carry, capacity)
+
+    def shrink(self, carry, capacity: int):
+        return _shrink_carry(carry, capacity)
+
+    def polled(self, flags: np.ndarray, consumed: int) -> None:
+        """An accepted poll's flags: nothing more to read on one device."""
+
+
 def check(model: JaxModel, history: Optional[History] = None,
           prepared: Optional[PreparedHistory] = None,
           capacity: int = 1024, max_capacity: int = 65536,
           chunk: Optional[int] = None, max_window: int = 4096,
           explain: bool = True, cancel=None,
           witness_budget: int = WITNESS_BUDGET,
-          growth: int = 4) -> Dict[str, Any]:
+          growth: int = 4,
+          snapshot: Optional[List[Snapshot]] = None) -> Dict[str, Any]:
     """Decide linearizability on device.  Retries with larger configuration
     capacity on overflow; falls back to ``valid: "unknown"`` past
-    ``max_capacity``.  On refutation, optionally re-derives a witness on the
+    ``max_capacity``, and then leaves in ``snapshot``, where the caller
+    gave a list, the :class:`Snapshot` another search may resume from.  On refutation, optionally re-derives a witness on the
     failing prefix with the CPU oracle (cheap: the prefix is exactly what the
     device already searched).
 
@@ -1082,21 +1128,28 @@ def check(model: JaxModel, history: Optional[History] = None,
     the driver stops dispatching and returns ``valid: "unknown"`` with
     ``cancelled: True`` (knossos.competition loser cancellation)."""
     with span("drivers.check") as sp:
-        return _check(sp, model, history, prepared, capacity, max_capacity,
-                      chunk, max_window, explain, cancel, witness_budget,
-                      growth)
+        return _check(sp, OneDevice(), model, history, prepared, capacity,
+                      max_capacity, chunk, max_window, explain, cancel,
+                      witness_budget, growth, snapshot)
 
 
-def _check(sp: span, model: JaxModel, history: Optional[History],
+def _check(sp: span, place, model: JaxModel, history: Optional[History],
            prepared: Optional[PreparedHistory], capacity: int,
            max_capacity: int, chunk: Optional[int], max_window: int,
-           explain: bool, cancel, witness_budget: int,
-           growth: int) -> Dict[str, Any]:
+           explain: bool, cancel, witness_budget: int, growth: int,
+           snapshot: Optional[List[Snapshot]] = None,
+           resume: Optional[Snapshot] = None) -> Dict[str, Any]:
     """:func:`check` under its ``drivers.check`` span ``sp``, which closes
     with what the driver did: dispatches, speculative chunks discarded,
     grows, shrinks, budget-pause resumes, the chunks that continued from
     such a pause, the longest poll and the call's own share of
-    :func:`check_stats`' four sums."""
+    :func:`check_stats`' four sums.  ``place`` (:class:`OneDevice`, or
+    ``parallel.sharded.OnMesh`` under its ``drivers.shard`` span) says
+    where the frontier lives; ``capacity`` and ``max_capacity`` are rows
+    a shard, and ``n`` shards hold ``n`` times that.  With ``resume`` the
+    search goes on from that snapshot, at the first rung its peak asks
+    for, and what it counts (``configs-explored``, ``closure-rounds``)
+    goes on with it."""
     p = prepared if prepared is not None else prepare(
         history, model, max_window=max_window)
     if chunk is None:
@@ -1126,10 +1179,14 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
         # jnp.asarray would be a blocking ~12 KB host→device transfer per
         # dispatch, which can cost more than the chunk's compute on an
         # easy history.
-        ev_dev = jnp.asarray(ev)
+        ev_dev = place.stage(ev)
         stage.set(bytes=ev.nbytes)
 
+    n = place.shards
     cap = capacity
+    if resume is not None:
+        while cap < max_capacity and cap * n < 2 * resume.peak:
+            cap = min(cap * growth, max_capacity)
     max_cap_reached = cap  # diagnostics: how far escalation actually went
     # The chunk is capacity-INVARIANT (see chunk_for_capacity): capacity
     # changes rebuild the engine but keep the dispatch granularity, and
@@ -1161,7 +1218,7 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
     # flight consumes at most cur_chunk, so a dispatch behind ``known +
     # len(inflight) * cur_chunk < n_events`` starts before n_events and
     # its slice ends inside the cushion.
-    known = 0
+    known = 0 if resume is None else int(resume.cursor)
     paused = False  # the last accepted poll had stopped short of its chunk
     # what the drivers.check span closes with
     did = {"dispatches": 0, "discarded": 0, "grows": 0, "shrinks": 0,
@@ -1187,11 +1244,15 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
         discard(why)
         rung.__exit__(None, None, None)
         rung = span("drivers.rung", cap=cap).__enter__()
-        _, run = _get_run_chunk(model, window, cap, gw, cur_chunk)
+        _, run = place.runner(model, window, cap, gw, cur_chunk)
 
     try:
-        carry0, run = _get_run_chunk(model, window, cap, gw, cur_chunk)
-        carry, cursor = carry0(), np.int32(0)
+        carry0, run = place.runner(model, window, cap, gw, cur_chunk)
+        if resume is None:
+            carry, cursor = carry0(), np.int32(0)
+        else:
+            carry = place.adopt(resume.carry, cap)
+            cursor = np.int32(resume.cursor)
         # n_events >= chunk always, so the loop pops at least once and
         # failed/overflow/done are always (re)assigned before use below.
         while True:
@@ -1200,9 +1261,9 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
             # discarded work.
             if cancel is not None and cancel.is_set():
                 discard("cancel")
-                return {"valid": "unknown", "analyzer": "wgl-tpu",
+                return {"valid": "unknown", "analyzer": place.analyzer,
                         "cancelled": True}
-            while len(inflight) < LOOKAHEAD:
+            while len(inflight) < place.lookahead:
                 # where the next chunk starts at the latest (its cursor is
                 # the device's to know until the chunks before it are polled)
                 latest = known + len(inflight) * cur_chunk
@@ -1230,21 +1291,25 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
                 # itself have been clipped — so the loop can escalate
                 # again) and resume from the snapshot, carry and cursor:
                 # no restart, no re-search of the prefix.
-                while cap < max_capacity and cap < 2 * peak:
+                while cap < max_capacity and cap * n < 2 * peak:
                     cap = min(cap * growth, max_capacity)
                 max_cap_reached = max(max_cap_reached, cap)
                 did["grows"] += 1
                 change_rung("grow")
-                carry, cursor = _grow_carry(prev[0], cap), prev[1]
+                carry, cursor = place.grow(prev[0], cap), prev[1]
                 overflow = False
                 continue
+            if overflow and snapshot is not None:
+                snapshot.append(Snapshot(prev[0], known, peak))
             done = after[0]
             known += consumed
             did["continued"] += paused
             did["events_consumed"] += consumed
-            did["events_consumed_16k"] += consumed if cap >= TOP_RUNG else 0
-            did["cap_events"] += cap * consumed
+            did["events_consumed_16k"] += \
+                consumed if cap * n >= TOP_RUNG else 0
+            did["cap_events"] += cap * n * consumed
             did["peak_events"] += peak * consumed
+            place.polled(fl, consumed)
             if failed or overflow:
                 discard("stop")
                 break
@@ -1270,7 +1335,7 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
                 # cheaper-per-round engine (discarding speculative chunks).
                 need = 2 * max(pk for pk, _ in recent_peaks)
                 target = cap
-                while target > capacity and target // growth >= need:
+                while target > capacity and target // growth * n >= need:
                     target //= growth
                 # an escalation clamped to max_capacity can sit off the
                 # power-of-4 lattice; never shrink below the configured
@@ -1280,7 +1345,7 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
                     cap = target
                     did["shrinks"] += 1
                     change_rung("shrink")
-                    carry, cursor = _shrink_carry(after[0], cap), after[1]
+                    carry, cursor = place.shrink(after[0], cap), after[1]
         carry = done
         explored = int(carry[9])
     finally:
@@ -1290,6 +1355,7 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
             for k in _STATS:
                 _STATS[k] += did[k]
 
+    shards = {"shards": n} if n > 1 else {}
     if overflow:
         # ``explored`` only accumulates at converged RETURN prunes; a
         # history that overflows before any return prunes (the ceiling
@@ -1301,25 +1367,28 @@ def _check(sp: span, model: JaxModel, history: Optional[History],
         # "capacity-exceeded" is the structured form of the error string:
         # the fission layer keys its split-don't-escalate decision on it
         # instead of parsing the message.
-        return {"valid": "unknown", "analyzer": "wgl-tpu",
-                "error": f"configuration capacity exceeded at {cap}",
+        return {"valid": "unknown", "analyzer": place.analyzer,
+                "error": "configuration capacity exceeded at "
+                         + (f"{cap}" if n == 1 else f"{cap}x{n}"),
                 "capacity-exceeded": True,
                 "configs-explored": explored + int(carry[11]),
                 "closure-rounds": int(carry[10]),
-                "max-capacity-reached": max_cap_reached}
+                "max-capacity-reached": max_cap_reached * n, **shards}
     if not failed:
-        return {"valid": True, "analyzer": "wgl-tpu",
+        return {"valid": True, "analyzer": place.analyzer,
                 "configs-explored": explored,
                 "closure-rounds": int(carry[10]),
-                "window": p.window, "capacity": cap,
-                "max-capacity-reached": max_cap_reached}
+                "window": p.window, "capacity": cap * n,
+                "max-capacity-reached": max_cap_reached * n, **shards}
     failed_op = p.ops[int(carry[7])]
+    # (over every shard: the prune's survivors are psum'd)
     # witness: device frontier emptied on a RETURN; refuting op attached
-    res: Dict[str, Any] = {"valid": False, "analyzer": "wgl-tpu",
+    res: Dict[str, Any] = {"valid": False, "analyzer": place.analyzer,
                            "op": failed_op.to_dict(),
                            "configs-explored": explored,
-                           "window": p.window, "capacity": cap,
-                           "max-capacity-reached": max_cap_reached}
+                           "window": p.window, "capacity": cap * n,
+                           "max-capacity-reached": max_cap_reached * n,
+                           **shards}
     if explain and history is not None and model.cpu_model is not None:
         res["witness"] = _cpu_witness(model, history, failed_op,
                                       witness_budget)

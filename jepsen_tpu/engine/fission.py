@@ -11,7 +11,12 @@ configurable threshold, the search splits into sub-problems whose
 frontiers fit small, cache-hot bucket shapes, and the sub-verdicts
 recombine under the engine substrate's unknown-never-false discipline.
 
-Two splitters, applied in order:
+Two splitters, applied in order, and between them the step that needs no
+split: a history in one component whose frontier outgrew one chip, on a host
+with more chips attached, keeps its one search and divides the frontier
+over the chips (``parallel.sharded``, analyzer ``wgl-tpu-sharded``).  The
+step chooses from what it observes (the devices attached), with no knob;
+on one chip nothing changes.
 
 1. **Component split (P-compositionality, arXiv 1504.00204).**  When the
    model declares per-key independence (``JaxModel.components``), the
@@ -42,6 +47,7 @@ sub-verdicts      combined verdict
 components: any False   False — refuting op + witness from that sub-problem only
 components: all True    True
 components: else        unknown (never false)
+one component, more than one chip: shard   the sharded search's own verdict; its overflow is unknown
 ghosts: any True        True
 ghosts: all False       False — witness from the all-elided sub-problem
 ghosts: else            monolithic escalation to the caller's real ceiling
@@ -64,7 +70,7 @@ from jepsen_tpu.engine import ladder
 from jepsen_tpu.history import FAIL, History, INFO, INVOKE, OK, Op
 from jepsen_tpu.models.base import JaxModel, UNKNOWN32
 from jepsen_tpu.obs.hist import HistogramSet
-from jepsen_tpu.obs.recorder import RECORDER
+from jepsen_tpu.obs.recorder import RECORDER, instant
 
 DEFAULT_THRESHOLD = 16384
 DEFAULT_MAX_SUBPROBLEMS = 256
@@ -113,7 +119,7 @@ _STATS_LOCK = threading.Lock()
 def _zero_stats() -> Dict[str, int]:
     return {"checks": 0, "splits": 0,
             "component_splits": 0, "component_subproblems": 0,
-            "ghost_splits": 0, "ghost_subproblems": 0,
+            "ghost_splits": 0, "ghost_subproblems": 0, "shards": 0,
             "recombines": 0, "short_circuits": 0,
             "sub_overflows": 0, "escalations": 0, "errors": 0}
 
@@ -123,7 +129,8 @@ _STATS = _zero_stats()
 
 def fission_stats() -> Dict[str, int]:
     """Counters over every fission decision in this process: splits taken,
-    sub-problems spawned per splitter, recombinations, all-elided
+    sub-problems spawned per splitter, searches handed to the sharded
+    engine whole (``shards``), recombinations, all-elided
     short-circuits, sub-problems that themselves overflowed the threshold,
     and monolithic escalations (the pre-fission behavior, taken only when
     neither splitter can decide)."""
@@ -152,6 +159,7 @@ def check(model: JaxModel, history: Optional[History] = None,
           threshold: Optional[int] = None,
           max_subproblems: Optional[int] = None,
           fission: Optional[bool] = None,
+          shard_devices: Optional[Sequence[Any]] = None,
           explain: bool = True, **opts: Any) -> Dict[str, Any]:
     """Drop-in for :func:`jepsen_tpu.checker.wgl_tpu.check` with frontier
     fission above the threshold.
@@ -163,7 +171,11 @@ def check(model: JaxModel, history: Optional[History] = None,
     on capacity exhaustion the search splits (see the module docstring)
     instead of compiling ever-larger engines.  ``fission=None`` reads the
     ``JTPU_FISSION`` knob; ``threshold``/``max_subproblems`` default to
-    their env knobs.  Remaining kwargs pass through to ``wgl_tpu.check``.
+    their env knobs.  ``shard_devices`` are the devices a search in one
+    component may divide its frontier over; the default is what is
+    attached (:func:`attached_chips`), and a test on the CPU's virtual
+    devices hands them in.  Remaining kwargs pass through to
+    ``wgl_tpu.check``.
     """
     from jepsen_tpu.checker import wgl_tpu
     thr = threshold if threshold is not None else fission_threshold()
@@ -174,16 +186,19 @@ def check(model: JaxModel, history: Optional[History] = None,
                              max_capacity=max_capacity, explain=explain,
                              **opts)
     _bump(checks=1)
+    snapshot: List[wgl_tpu.Snapshot] = []
     r = wgl_tpu.check(model, history, prepared=prepared,
                       capacity=min(capacity, thr),
-                      max_capacity=thr, explain=explain, **opts)
+                      max_capacity=thr, explain=explain, snapshot=snapshot,
+                      **opts)
     if not r.get("capacity-exceeded"):
         return r
     return split_check(model, history, capacity=capacity,
                        max_capacity=max_capacity, threshold=thr,
                        max_subproblems=max_subproblems, explain=explain,
                        base_explored=int(r.get("configs-explored", 0)),
-                       **opts)
+                       shard_devices=shard_devices,
+                       resume=snapshot[0] if snapshot else None, **opts)
 
 
 def split_check(model: JaxModel, history: History,
@@ -191,28 +206,36 @@ def split_check(model: JaxModel, history: History,
                 threshold: Optional[int] = None,
                 max_subproblems: Optional[int] = None,
                 explain: bool = True, base_explored: int = 0,
-                **opts: Any) -> Dict[str, Any]:
-    """Split an already-overflowed search into sub-problems and recombine.
+                shard_devices: Optional[Sequence[Any]] = None,
+                resume: Any = None, **opts: Any) -> Dict[str, Any]:
+    """Split an already-overflowed search into sub-problems and recombine,
+    or, where there is one component and more than one chip, go on with
+    the one search sharded over the chips (:func:`_shard`), from the
+    overflowed search's snapshot (``resume``) where the caller has one.
 
     Called by :func:`check` after its threshold-clamped monolithic run
     overflowed, and by ``parallel.batch.check_batch`` for lanes whose next
     escalation rung would cross the threshold.  Any internal failure
     degrades to the monolithic escalation path (the exact pre-fission
-    behavior), never to a fabricated verdict."""
+    behavior), never to a fabricated verdict; a failure of the sharded
+    search is the device's and goes up to the caller's fallback chain."""
     thr = threshold if threshold is not None else fission_threshold()
     max_subs = (max_subproblems if max_subproblems is not None
                 else fission_max_subproblems())
     _bump(splits=1)
     t0 = time.monotonic()
+    devices = list(attached_chips() if shard_devices is None
+                   else shard_devices)
     try:
         subs = component_split(model, history)
-        if subs is not None and len(subs) >= 2:
+        whole = subs is None or len(subs) < 2
+        if not whole:
             res = _check_components(model, subs, threshold=thr,
                                     max_capacity=max_capacity,
                                     max_subproblems=max_subs,
                                     explain=explain,
                                     base_explored=base_explored, **opts)
-        else:
+        elif len(devices) < 2:
             res = _ghost_split(model, history, capacity=capacity,
                                threshold=thr, max_capacity=max_capacity,
                                max_subproblems=max_subs, explain=explain,
@@ -223,11 +246,57 @@ def split_check(model: JaxModel, history: History,
                         max_capacity=max_capacity, explain=explain,
                         why=f"fission error: {type(e).__name__}: {e}",
                         threshold=thr, **opts)
+    else:
+        if whole and len(devices) >= 2:
+            res = _shard(model, history, devices, capacity=capacity,
+                         threshold=thr, explain=explain, resume=resume,
+                         **opts)
     dt = time.monotonic() - t0
     HISTS.observe("fission:split", dt)
     RECORDER.record("fission", "split", dur_s=dt,
                     args={"verdict": str(res.get("valid")),
                           "mode": (res.get("fission") or {}).get("mode")})
+    return res
+
+
+# ---------------------------------------------------------------------------
+# One component, more than one chip: the frontier sharded
+# ---------------------------------------------------------------------------
+
+def attached_chips() -> List[Any]:
+    """The devices one search may divide its frontier over: every device
+    of the default backend where that is an accelerator, none on the CPU
+    (its devices are one host's cores under other names, and the virtual
+    ones of ``tests/conftest.py`` a test rig)."""
+    import jax
+    devices = jax.devices()
+    return [] if devices[0].platform == "cpu" else list(devices)
+
+
+def _shard(model: JaxModel, history: History, devices: Sequence[Any], *,
+           capacity: int, threshold: int, explain: bool, resume: Any,
+           **opts: Any) -> Dict[str, Any]:
+    """The search goes on as one, its frontier over ``devices``, each shard
+    on the ladder the one chip climbed, up to ``threshold`` rows a shard.
+    With ``resume`` (``fission.check`` has the overflowed search's
+    snapshot) it goes on from the chunk that overflowed, at the first rung
+    whose shards together hold twice the peak; without (a lane of
+    ``check_batch``) from event 0 and the first rung, which repeats the
+    events the one chip had consumed and compiles the small rungs' sharded
+    programs besides.  The verdict is the sharded engine's own: past its
+    ceiling ``unknown``, and no split or one-chip escalation after it,
+    which could only meet the same frontier at the same global capacity."""
+    from jepsen_tpu.parallel.sharded import check_sharded
+    _bump(shards=1)
+    instant("drivers.shard_handover",
+            event=resume.cursor if resume is not None else 0,
+            peak=resume.peak if resume is not None else 0,
+            mode="resume" if resume is not None else "restart")
+    res = check_sharded(model, history, devices=devices,
+                        capacity_per_shard=min(capacity, threshold),
+                        max_capacity_per_shard=threshold, resume=resume,
+                        explain=explain, **opts)
+    res["fission"] = {"mode": "shard", "shards": len(devices)}
     return res
 
 

@@ -9,79 +9,199 @@ all_gather over ICI, every device deduplicates the global set identically
 (replicated sort), and keeps its deterministic slice.  Failure/overflow flags
 are psum-reduced so all shards agree.
 
-The host driver mirrors the single-chip lessons (wgl_tpu.check): LOOKAHEAD
-chunks stay in flight so the per-chunk flags transfer overlaps device
-compute (each chunk-boundary poll is a device→host round trip), an
-overflow resumes from the pre-chunk snapshot at a peak-informed capacity
-instead of restarting the whole history, and the engine drops back to a
-cheaper per-round shape once a crash-burst's transient demand passes.
+There is no host loop here: the driver is ``wgl_tpu._check``, the one the
+single device has, over the placement :class:`OnMesh`.  So the sharded search
+dispatches, polls, pauses, grows and shrinks as the one-chip search does: the
+event cursor rides on the device with the carry, a chunk dispatched behind a
+budget pause goes on from the pause, an overflow resumes from the pre-chunk
+snapshot at a capacity the global peak says is enough, and the engine drops
+back to a cheaper shape once a burst has passed.  What the mesh adds is the
+runner (a ``shard_map`` of ``make_engine``'s ``run_chunk``) and the carry's
+re-laying, shard by shard.
+
+The normal path reaches it from ``engine.fission.split_check``: a history
+whose frontier outgrew one chip's last rung, with nothing to split by
+component and more than one chip attached.
 """
 
 from __future__ import annotations
 
-from collections import deque
-from typing import Any, Dict, Optional
+import threading
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map as _shard_map
 
-from jepsen_tpu.checker.prep import PreparedHistory, prepare
-from jepsen_tpu.checker.wgl_tpu import (EV_NOP, LOOKAHEAD, _chunk_slicer,
-                                        chosen_gwords, events_array,
-                                        make_engine)
+from jepsen_tpu.checker import wgl_tpu
+from jepsen_tpu.checker.prep import PreparedHistory
+from jepsen_tpu.engine.cache import CACHE as _ENGINE_CACHE
+from jepsen_tpu.engine.witness import WITNESS_BUDGET
 from jepsen_tpu.history import History
 from jepsen_tpu.models.base import JaxModel
+from jepsen_tpu.obs.hist import timed_first_call
+from jepsen_tpu.obs.recorder import span
+from jepsen_tpu.ops import dedup as _dedup
+from jepsen_tpu.parallel.mesh import make_mesh
 
-_CACHE: Dict[Any, Any] = {}
+ANALYZER = "wgl-tpu-sharded"
+
+# ---------------------------------------------------------------------------
+# Counters (check_stats idiom): how much of the stream ran sharded, and how
+# evenly the rows lay over the shards
+# ---------------------------------------------------------------------------
+
+_STATS_LOCK = threading.Lock()
 
 
-def _sharded_runner(model: JaxModel, window: int, capacity_per_shard: int,
-                    mesh: Mesh, axis: str, gwords: int = 1,
-                    work_budget: Optional[int] = None):
-    key = ("shard", model.name, model.variant, model.state_size,
-           tuple(model.init_state_array().tolist()), window,
-           capacity_per_shard, id(mesh), axis, gwords, work_budget)
-    if key in _CACHE:
-        return _CACHE[key]
-    n = mesh.shape[axis]
-    # The capacity-scaled per-dispatch closure budget (the single-chip
-    # watchdog mitigation, wgl_tpu.closure_budget) applies to the sharded
-    # engine too; the host loop below resumes mid-chunk from the
-    # consumed-events flag exactly like wgl_tpu.check.  Each shard's
-    # closure round sorts the *gathered global* set, so the per-iteration
-    # cost scales with capacity_per_shard * n — the budget divides by the
-    # global capacity, keeping one dispatch's wall-clock at the same bound
-    # regardless of shard count.
-    if work_budget is None:
-        from jepsen_tpu.checker.wgl_tpu import closure_budget
-        work_budget = closure_budget(capacity_per_shard * n)
-    _, _, run_chunk = make_engine(model, window, capacity_per_shard,
-                                  axis_name=axis, num_shards=n,
-                                  gwords=gwords, work_budget=work_budget)
-    # carry layout: (mask[C,MW], states[C,S], valid[C], win_ops, active,
-    #               dirty, failed, failed_op, overflow, explored, rounds,
-    #               peak, ghosts, budget, consumed, cl_iters, fresh[W],
-    #               cur_new[C]) — ghosts/fresh are per-slot and the
-    #               scalars are identical across shards, hence replicated;
-    #               cur_new is a per-row delta flag, sharded like valid.
-    sharded = P(axis)
-    repl = P()
-    in_specs = ((sharded, sharded, sharded) + (repl,) * 14 + (sharded,),
-                repl)
-    out_specs = ((sharded, sharded, sharded) + (repl,) * 14 + (sharded,),
-                 repl)
+def _zero_stats() -> Dict[str, int]:
+    return {"events_sharded": 0, "events_total": 0,
+            "rows_live_min": 0, "rows_live_max": 0}
+
+
+_STATS = _zero_stats()
+
+
+def sharded_stats() -> Dict[str, int]:
+    """Sums over every :func:`check_sharded` of this process:
+    ``events_sharded``, the events its accepted polls consumed;
+    ``events_total``, those and the events a one-chip search had consumed
+    before the snapshot it handed over (``resume``); ``rows_live_min`` and ``rows_live_max``,
+    the live rows of the emptiest and of the fullest shard at the end of
+    each accepted chunk, summed over the polls.  ``rows_live_min /
+    rows_live_max`` is how evenly the frontier lay over the shards: each
+    shard expands its own rows, and the fullest shard's candidates pick the
+    round's merge width for all."""
+    with _STATS_LOCK:
+        return dict(_STATS)
+
+
+def reset_sharded_stats() -> None:
+    with _STATS_LOCK:
+        _STATS.update(_zero_stats())
+
+
+# carry layout (wgl_tpu.make_engine): mask[C,MW], states[C,S], valid[C] and,
+# last, cur_new[C] hold rows and are sharded; the fourteen between are
+# per-slot arrays and scalars, identical across shards, hence replicated.
+def _carry_specs(axis: str):
+    return (P(axis),) * 3 + (P(),) * 14 + (P(axis),)
+
+
+def mesh_program(model: JaxModel, window: int, capacity: int, gwords: int,
+                 chunk: int, mesh: Mesh, axis: str, work_budget: int):
+    """The jitted ``run(carry, cursor, ev_dev) -> (carry', cursor + consumed,
+    flags)`` of :meth:`OnMesh.runner`: ``make_engine``'s ``run_chunk`` with
+    ``capacity`` rows a shard under a ``shard_map`` over ``axis``, slicing
+    its own ``chunk`` events out of the replicated stream at the cursor.
+    ``flags`` are ``run_chunk``'s five and, after them, the live rows of
+    the emptiest and of the fullest shard."""
+    _, _, run_chunk = wgl_tpu.make_engine(
+        model, window, capacity, axis_name=axis,
+        num_shards=mesh.shape[axis], gwords=gwords, work_budget=work_budget)
+
+    def run_at(carry, cursor, ev_dev):
+        # the slice behind a barrier: wgl_tpu._get_run_chunk says why
+        events = lax.dynamic_slice_in_dim(
+            *lax.optimization_barrier((ev_dev, cursor)), chunk)
+        carry, flags = run_chunk(carry, events)
+        live = lax.all_gather(carry[2].sum().astype(jnp.int32), axis)
+        flags = jnp.concatenate([flags, jnp.stack([live.min(), live.max()])])
+        return carry, cursor + flags[3], flags
+
+    specs = (_carry_specs(axis), P(), P())
     # Replication checking off (check_vma): closure dedup sorts the
     # *gathered* global row set, so every shard computes bit-identical
     # "replicated" scalars (counts, flags), but the varying-axes checker
     # can't prove that post-all_gather.
-    fn = jax.jit(_shard_map(run_chunk, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False))
-    _CACHE[key] = fn
-    return fn
+    return jax.jit(_shard_map(run_at, mesh=mesh, in_specs=specs,
+                              out_specs=specs, check_vma=False))
+
+
+class OnMesh:
+    """``wgl_tpu._check``'s placement (see :class:`wgl_tpu.OneDevice`) with
+    the frontier divided over ``mesh``'s ``axis``: ``capacity`` rows on
+    each of its ``shards`` devices, every closure round's sort over the
+    gathered ``capacity * shards``."""
+
+    analyzer = ANALYZER
+
+    def __init__(self, mesh: Mesh, axis: str = "model",
+                 work_budget: Optional[int] = None) -> None:
+        self.mesh, self.axis, self.work_budget = mesh, axis, work_budget
+        self.shards = mesh.shape[axis]
+        # this call's share of sharded_stats(), read by check_sharded
+        self.did = _zero_stats()
+
+    @property
+    def lookahead(self) -> int:
+        # Pipelining pays where the device→host flags transfer has real
+        # latency (an accelerator); on the host-platform CPU mesh the
+        # transfer is a memcpy and extra in-flight chunks only cost memory
+        # (measured ~20% slower), so keep the pipeline depth at 1 there.
+        return (wgl_tpu.LOOKAHEAD
+                if self.mesh.devices.flat[0].platform != "cpu" else 1)
+
+    def _put(self, x, spec=P()):
+        return jax.device_put(jnp.asarray(x), NamedSharding(self.mesh, spec))
+
+    def stage(self, ev: np.ndarray):
+        # the whole stream once, replicated; the runner slices it
+        return self._put(ev)
+
+    def runner(self, model: JaxModel, window: int, capacity: int,
+               gwords: int, chunk: int):
+        """``(carry0, run)`` as ``wgl_tpu._get_run_chunk`` gives them: ONE
+        program a dispatch, ``run(carry, cursor, ev_dev) -> (carry', cursor
+        + consumed, flags)``, here a ``shard_map`` over the mesh axis whose
+        flags end with the live rows of the emptiest and of the fullest
+        shard (``polled`` sums them)."""
+        n, axis, mesh = self.shards, self.axis, self.mesh
+        # Each shard's closure round sorts the *gathered global* set, so a
+        # round costs what capacity * n rows cost: the per-dispatch budget
+        # (the watchdog bound, wgl_tpu.closure_budget) divides by that.
+        budget = (self.work_budget if self.work_budget is not None
+                  else wgl_tpu.closure_budget(capacity * n))
+        key = ("shardv", model.name, model.variant, model.state_size,
+               tuple(model.init_state_array().tolist()), window, capacity,
+               gwords, chunk, mesh, axis, budget, _dedup.N_PROBES,
+               _dedup.WIDE_SORT_ROWS, _dedup.SUBSUME)
+        hit = _ENGINE_CACHE.get(key)
+        if hit is not None:
+            return hit
+        run = timed_first_call(
+            mesh_program(model, window, capacity, gwords, chunk, mesh, axis,
+                         budget),
+            f"compile:shardv:{model.name}:w{window}:c{capacity}x{n}")
+
+        def carry0():
+            return _initial_carry(model, window, capacity, n, mesh, axis)
+
+        return _ENGINE_CACHE.put(key, (carry0, run))
+
+    def grow(self, carry, capacity: int):
+        return _resize_carry_sharded(
+            carry, self.shards, carry[2].shape[0] // self.shards, capacity,
+            self.mesh, self.axis)
+
+    shrink = grow
+
+    def adopt(self, carry, capacity: int):
+        # a one-device carry's rows, cut into ``shards`` blocks where they
+        # lie (the next merge deals the gathered set anew anyway); the rest
+        # replicated
+        return self.grow(tuple(
+            self._put(np.asarray(x), spec)
+            for x, spec in zip(carry, _carry_specs(self.axis))), capacity)
+
+    def polled(self, flags: np.ndarray, consumed: int) -> None:
+        self.did["events_sharded"] += consumed
+        self.did["rows_live_min"] += int(flags[5])
+        self.did["rows_live_max"] += int(flags[6])
 
 
 def _initial_carry(model, window, cap, n, mesh, axis):
@@ -165,141 +285,48 @@ def check_sharded(model: JaxModel,
                   axis: str = "model",
                   capacity_per_shard: int = 1024,
                   max_capacity_per_shard: int = 65536,
-                  chunk: int = 2048,
+                  chunk: Optional[int] = None,
                   max_window: int = 4096,
-                  work_budget: Optional[int] = None) -> Dict[str, Any]:
-    """Frontier-sharded linearizability check of one history.
+                  work_budget: Optional[int] = None,
+                  devices: Optional[Sequence[Any]] = None,
+                  resume: Optional[wgl_tpu.Snapshot] = None,
+                  explain: bool = True, cancel=None,
+                  witness_budget: int = WITNESS_BUDGET,
+                  growth: int = 4) -> Dict[str, Any]:
+    """Frontier-sharded linearizability check of one history:
+    ``wgl_tpu.check`` with the frontier over ``mesh``'s ``axis`` (or over
+    ``devices``, one axis of them all), capacities counted in rows a shard;
+    from event 0, or from where a one-device search stopped (``resume``,
+    the :class:`wgl_tpu.Snapshot` its ``check`` left).
+    A refutation carries the refuting op and the host oracle's witness as
+    ``wgl_tpu.check``'s does; a frontier over ``max_capacity_per_shard`` on
+    every shard is ``unknown`` with ``capacity-exceeded``.
 
     ``work_budget`` overrides the per-dispatch closure-iteration budget
-    (None = the capacity-scaled default, see _sharded_runner; tests pass a
-    tiny value to force the mid-chunk pause/resume path on small meshes)."""
-    assert mesh is not None, "check_sharded requires a mesh"
-    from jepsen_tpu.checker.wgl_tpu import _round_window
-    p = prepared if prepared is not None else prepare(
-        history, model, max_window=max_window)
-    window = _round_window(p.window)
-    ev = events_array(p, chunk)
-    n_events = ev.shape[0]
-    # One chunk-sized NOP cushion so a mid-chunk resume offset can always
-    # slice a full chunk without clamping back into real events (see
-    # wgl_tpu.check).
-    ev = np.concatenate([ev, np.zeros((chunk, ev.shape[1]), ev.dtype)])
-    ev[n_events:, 0] = EV_NOP
-    n = mesh.shape[axis]
+    (None = the capacity-scaled default, see :meth:`OnMesh.runner`; tests
+    pass a tiny value to force the mid-chunk pause path on small meshes).
 
-    def put_repl(x):
-        return jax.device_put(jnp.asarray(x), NamedSharding(mesh, P()))
-
-    # Whole event stream uploaded once (replicated); chunks are sliced
-    # device-side — a per-chunk host->device put blocks the dispatch
-    # loop (see wgl_tpu.check).
-    ev_dev = put_repl(ev)
-    slice_chunk = _chunk_slicer(chunk)
-
-    gw = chosen_gwords(p)
-    cap = capacity_per_shard
-    max_cap_reached = cap  # diagnostics: how far escalation actually went
-    run = _sharded_runner(model, window, cap, mesh, axis, gw, work_budget)
-    carry = _initial_carry(model, window, cap, n, mesh, axis)
-    # (peak, events-consumed) samples since the last capacity change (see
-    # wgl_tpu.check: shrink-back weighs samples by events covered because a
-    # budget-paused dispatch can cover anywhere from 0 to chunk events).
-    SHRINK_WINDOW = 4 * chunk
-    recent_peaks: deque = deque()
-    inflight: deque = deque()  # (pos, carry_before, carry_after, flags)
-    pos = 0
-    failed = overflow = False
-    done = carry
-    # Pipelined dispatch (see wgl_tpu.check): speculation past a failure or
-    # overflow is safe because the failed/overflow lanes gate all updates in
-    # event_step — speculative chunks are simply discarded on resume.
-    # Pipelining pays where the device→host flags transfer has real latency
-    # (an accelerator); on the host-platform CPU mesh the transfer is a
-    # memcpy and extra in-flight chunks only cost memory (measured ~20%
-    # slower), so keep the pipeline depth at 1 there.
-    lookahead = (LOOKAHEAD
-                 if mesh.devices.flat[0].platform != "cpu" else 1)
-    while True:
-        while len(inflight) < lookahead and pos < n_events:
-            prev = carry
-            carry, flags = run(carry, slice_chunk(ev_dev, pos))
-            inflight.append((pos, prev, carry, flags))
-            pos += chunk
-        if not inflight:
-            break
-        cpos, prev, after, flags = inflight.popleft()
-        fl = np.asarray(flags)
-        failed, overflow = bool(fl[0]), bool(fl[1])
-        peak = int(fl[2])  # global (psum'd) distinct-config high-water mark
-        consumed = int(fl[3])
-        if overflow and cap < max_capacity_per_shard:
-            # Escalate straight to a capacity the observed global peak says
-            # is enough (peak may itself be clipped, so the loop can escalate
-            # again), and resume from the pre-chunk snapshot: no restart.
-            old = cap
-            while cap < max_capacity_per_shard and cap * n < 2 * peak:
-                cap = min(cap * 4, max_capacity_per_shard)
-            if cap == old:
-                cap = min(old * 4, max_capacity_per_shard)
-            max_cap_reached = max(max_cap_reached, cap)
-            recent_peaks.clear()
-            inflight.clear()
-            run = _sharded_runner(model, window, cap, mesh, axis, gw,
-                                  work_budget)
-            carry = _resize_carry_sharded(prev, n, old, cap, mesh, axis)
-            pos = cpos
-            overflow = False
-            continue
-        done = after
-        if failed or overflow:
-            break
-        recent_peaks.append((peak, consumed))
-        covered = sum(e for _, e in recent_peaks)
-        while len(recent_peaks) > 1 and covered - recent_peaks[0][1] >= \
-                SHRINK_WINDOW:
-            covered -= recent_peaks.popleft()[1]
-        resumed = consumed < chunk
-        if cap > capacity_per_shard and covered >= SHRINK_WINDOW:
-            # Transient crash-burst demand has passed: drop back to a
-            # cheaper-per-round engine once 2x the recent global peak fits.
-            need = 2 * max(pk for pk, _ in recent_peaks)
-            target = cap
-            while (target > capacity_per_shard
-                   and (target // 4) * n >= need):
-                target //= 4
-            # an escalation clamped to max_capacity can sit off the
-            # power-of-4 lattice; never shrink below the configured floor
-            target = max(target, capacity_per_shard)
-            if target < cap:
-                old = cap
-                cap = target
-                recent_peaks.clear()
-                inflight.clear()
-                run = _sharded_runner(model, window, cap, mesh, axis, gw,
-                                      work_budget)
-                carry = _resize_carry_sharded(after, n, old, cap, mesh, axis)
-                pos = cpos + consumed
-                continue
-        if resumed:
-            # Closure budget exhausted mid-chunk: discard speculative
-            # dispatches and resume exactly where the engine stopped (the
-            # single-chip watchdog-bound pattern, wgl_tpu.check).
-            inflight.clear()
-            carry = after
-            pos = cpos + consumed
-    carry = done
-
-    explored = int(carry[9])
-    if overflow:
-        return {"valid": "unknown", "analyzer": "wgl-tpu-sharded",
-                "error": f"capacity exceeded at {cap}x{n}",
-                "configs-explored": explored}
-    if not failed:
-        return {"valid": True, "analyzer": "wgl-tpu-sharded",
-                "configs-explored": explored, "shards": n,
-                "capacity": cap * n,
-                "max-capacity-reached": max_cap_reached * n}
-    # witness: frontier emptied across ALL shards; refuting op attached
-    return {"valid": False, "analyzer": "wgl-tpu-sharded",
-            "op": p.ops[int(carry[7])].to_dict(),
-            "configs-explored": explored, "shards": n}
+    Under its ``drivers.shard`` span, which closes with what
+    ``drivers.check`` closes with and ``shards``, ``cap_per_shard`` (the
+    largest reached), ``pauses``, ``resized``."""
+    if mesh is None:
+        if not devices:
+            raise ValueError("check_sharded requires a mesh or devices")
+        mesh = make_mesh((1, len(devices)), devices=devices)
+    place = OnMesh(mesh, axis, work_budget)
+    with span("drivers.shard", shards=place.shards) as sp:
+        try:
+            return wgl_tpu._check(
+                sp, place, model, history, prepared, capacity_per_shard,
+                max_capacity_per_shard, chunk, max_window, explain, cancel,
+                witness_budget, growth, resume=resume)
+        finally:
+            did = sp.args
+            sp.set(cap_per_shard=did.get("max_capacity"),
+                   pauses=did.get("resumes"),
+                   resized=did.get("grows", 0) + did.get("shrinks", 0))
+            place.did["events_total"] = place.did["events_sharded"] + (
+                resume.cursor if resume is not None else 0)
+            with _STATS_LOCK:
+                for k in _STATS:
+                    _STATS[k] += place.did[k]

@@ -411,10 +411,10 @@ def test_program_span_clips_to_the_window_and_reads_ctx(program_span):
 # -- device scopes ------------------------------------------------------------
 
 @pytest.mark.parametrize("variant,kw,absent", [
-    ("multi-round", {"gwords": 1}, {"wgl.gather"}),
+    ("multi-round", {"gwords": 1}, {"wgl.gather", "wgl.gather_shards"}),
     ("single-round", {"gwords": 1, "work_budget": 0,
                       "single_round_closure": True,
-                      "steps_per_dispatch": 8}, set()),
+                      "steps_per_dispatch": 8}, {"wgl.gather_shards"}),
 ])
 def test_engine_scopes_in_lowered_text(variant, kw, absent):
     """The lowered engine names each phase; the program itself (the text
