@@ -23,7 +23,8 @@ from typing import Any, Dict, List, Optional, Union
 
 from jepsen_tpu.checker import linear_cpu, wgl_cpu, wgl_tpu
 from jepsen_tpu.checker.core import Checker, UNKNOWN
-from jepsen_tpu.history import History
+from jepsen_tpu.engine.witness import WITNESS_BUDGET, cpu_witness
+from jepsen_tpu.history import History, Op
 from jepsen_tpu.models.base import JaxModel, Model
 from jepsen_tpu.obs.recorder import carry
 
@@ -96,8 +97,29 @@ class Linearizable(Checker):
         else:
             return {"valid": UNKNOWN, "error": f"unknown algorithm {algo!r}"}
         if res.get("valid") is False:
-            self._render(test, history, res, opts)
+            self.explain_refutation(test, history, res, opts)
         return res
+
+    def explain_refutation(self, test, history: History,
+                           res: Dict[str, Any], opts=None) -> None:
+        """The tail of every refutation, in place: the host-confirmed
+        witness where a device engine left the refuting op alone, then
+        linear.svg.  A device lane knows which op emptied its frontier,
+        not the path there (engine.witness); ``wgl_tpu.check`` attaches
+        the host oracle's witness itself, the batched lanes do not, so
+        ``IndependentChecker`` brings each key ``check_batch`` refuted
+        here with the key's sub-history, under this checker's ``explain``
+        and ``witness_budget``.  A host solver's refutation is its own
+        witness."""
+        jm = self._jax_model()
+        if ("witness" not in res and res.get("op")
+                and str(res.get("analyzer", "")).startswith("wgl-tpu")
+                and self.engine_opts.get("explain", True)
+                and jm is not None and jm.cpu_model is not None):
+            res["witness"] = cpu_witness(
+                jm, history, Op.from_dict(res["op"]),
+                self.engine_opts.get("witness_budget", WITNESS_BUDGET))
+        self._render(test, history, res, opts)
 
     def _tpu_fallback(self, history: History, cm: Optional[Model],
                       exc: Exception) -> Dict[str, Any]:

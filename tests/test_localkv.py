@@ -53,7 +53,7 @@ class TestLocalKv:
         svg = os.path.join(done["store_dir"], "independent", str(bad[0]),
                            "linear.svg")
         assert os.path.exists(svg)
-        # refuted keys re-derive through the single-history engine: witness
+        # a refuted key keeps the batch's leaf; the host confirms: witness
         r = done["results"]["workload"]["results"][bad[0]]
         assert r["valid"] is False and "witness" in r
 

@@ -211,9 +211,11 @@ class TestOfflinePath:
         assert res["valid"] is False and res["failures"] == [0]
         evs = rec.snapshot()
         names = by_name(evs)
-        assert set(names) - {"compile.first_call"} == CHECK_SPANS | {
+        assert set(names) - {"compile.first_call"} == {
             "entry.analyze", "entry.split", "entry.rederive",
-            "drivers.check_batch", "drivers.run_lanes", "witness.cpu"}
+            "drivers.check_batch", "drivers.run_lanes", "prepare",
+            "drivers.stage", "drivers.dispatch", "drivers.poll",
+            "witness.cpu"}
         root, = names["entry.analyze"]
         assert {e["trace-id"] for e in evs} == {root["trace-id"]}
         for n in ("entry.split", "drivers.check_batch", "entry.rederive"):
@@ -224,19 +226,27 @@ class TestOfflinePath:
         lanes_pass = names["drivers.run_lanes"][0]
         assert parent_of(evs, lanes_pass)["name"] == "drivers.check_batch"
         assert lanes_pass["args"]["lanes"] == 8
-        # the batch's lanes, the re-derivation, and the host oracle's own
+        # the batch's lanes and the host oracle's own: a refuted key is
+        # searched once, so no single-history prepare
         assert Counter(parent_of(evs, e)["name"]
                        for e in names["prepare"]) == {
-            "drivers.check_batch": 8, "drivers.check": 1, "witness.cpu": 1}
+            "drivers.check_batch": 8, "witness.cpu": 1}
         rederive, = names["entry.rederive"]
-        assert rederive["args"] == {"key": 0}
-        check, = names["drivers.check"]
-        assert parent_of(evs, check) is rederive
+        assert rederive["args"] == {"key": 0, "confirmed": True}
         witness, = names["witness.cpu"]
-        assert parent_of(evs, witness) is check
+        assert parent_of(evs, witness) is rederive
         assert witness["args"]["prefix"] > 0
         assert witness["args"]["configs"] > 0
-        assert res["results"][0]["witness"]["valid"] is False
+        # nothing of a device search under the host's re-derivation
+        for e in evs:
+            if e["name"].startswith("drivers."):
+                up = parent_of(evs, e)
+                while up is not None:
+                    assert up is not rederive, e["name"]
+                    up = parent_of(evs, up)
+        leaf = res["results"][0]
+        assert leaf["analyzer"] == "wgl-tpu-batch"
+        assert leaf["witness"]["valid"] is False
 
     def test_batch_retries_are_instants_and_a_pass_closes_with_counts(
             self, rec, model):
