@@ -343,46 +343,16 @@ def phase_served(seed: int, n_small: int = 256, small_ops: int = 200,
 # Phase 5: report
 # ---------------------------------------------------------------------------
 
-class CompileWatch:
-    """Counts JAX's own compile and persistent-cache events for the run:
-    how many programs went to the backend compiler, for how long, and
-    how many came off the disk cache instead."""
-
-    _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self) -> None:
-        import jax.monitoring as mon
-        self.events = {self._HIT: 0, self._MISS: 0}
-        self.backend_compiles = 0
-        self.backend_compile_s = 0.0
-        mon.register_event_listener(self._on_event)
-        mon.register_event_duration_secs_listener(self._on_duration)
-
-    def _on_event(self, name: str, **_: Any) -> None:
-        if name in self.events:
-            self.events[name] += 1
-
-    def _on_duration(self, name: str, secs: float, **_: Any) -> None:
-        if name == self._BACKEND_COMPILE:
-            self.backend_compiles += 1
-            self.backend_compile_s += secs
-
-    def snapshot(self) -> Dict[str, Any]:
-        return {"backend_compiles": self.backend_compiles,
-                "backend_compile_s": round(self.backend_compile_s, 1),
-                "persistent_cache_hits": self.events[self._HIT],
-                "persistent_cache_misses": self.events[self._MISS]}
-
-
-def phase_report(watch: CompileWatch) -> Dict[str, Any]:
-    """Compile accounting, and the cache directory in force must hold
+def phase_report(watch: Dict[str, Any]) -> Dict[str, Any]:
+    """Compile accounting since ``watch`` (a ``first_use_stats()`` taken
+    before the phases: JAX's own compile and persistent-cache events, as
+    the program counts them), and the cache directory in force must hold
     what this run compiled (a silently-failed cache set-up shows here)."""
     import jax
 
-    from jepsen_tpu.obs.hist import compile_hist_stats
+    from jepsen_tpu.obs.hist import compile_hist_stats, first_use_stats
     engines = compile_hist_stats()
+    now = first_use_stats()
     d = jax.config.jax_compilation_cache_dir
     entries = ([f for f in os.listdir(d) if f.endswith("-cache")]
                if d and os.path.isdir(d) else [])
@@ -396,7 +366,13 @@ def phase_report(watch: CompileWatch) -> Dict[str, Any]:
                 "engines_s": {name.removeprefix("compile:"):
                               round(float(s.get("sum-s", 0.0)), 1)
                               for name, s in engines.items()},
-                **watch.snapshot()},
+                "backend_compiles": now["programs"] - watch["programs"],
+                "backend_compile_s": round(
+                    now["load_s"] - watch["load_s"], 1),
+                "persistent_cache_hits":
+                    now["cache_hits"] - watch["cache_hits"],
+                "persistent_cache_misses":
+                    now["cache_misses"] - watch["cache_misses"]},
             "cache": {"dir": d, "entries": len(entries),
                       "from_env": bool(os.environ.get(
                           "JAX_COMPILATION_CACHE_DIR"))}}
@@ -411,7 +387,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     t_start = time.monotonic()
     out = phase_device()
-    watch = CompileWatch()
+    from jepsen_tpu.obs.hist import first_use_stats
+    from jepsen_tpu.ops.cache import init_compilation_cache
+    init_compilation_cache()        # the program's listeners, before any op
+    watch = first_use_stats()
     phases: List[Tuple[str, Callable[[int], Dict[str, Any]]]] = [
         ("offline", phase_offline), ("hard", phase_hard),
         ("keyed", phase_keyed), ("served", phase_served)]

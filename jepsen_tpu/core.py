@@ -22,8 +22,10 @@ from typing import Any, Dict, Optional
 from jepsen_tpu import control, db as jdb, nemesis as jnemesis, store
 from jepsen_tpu import os as jos
 from jepsen_tpu.checker.core import Checker, UNKNOWN, check_safe
+from jepsen_tpu.clock import mono_now
 from jepsen_tpu.generator import interpreter
 from jepsen_tpu.history import History
+from jepsen_tpu.obs.hist import observe_analyze
 from jepsen_tpu.obs.recorder import span
 
 logger = logging.getLogger("jepsen.core")
@@ -220,9 +222,17 @@ def analyze(test, history: History,
     concurrent runs share one device and one compiled-engine cache.
     Checkers the service cannot batch fall back to the direct path, and
     a service-side crash degrades to the direct path too — routing is an
-    optimization, never a verdict risk."""
-    with span("entry.analyze", entries=len(history)):
-        return _analyze(test, history, service)
+    optimization, never a verdict risk.
+
+    Each call reports its wall to ``obs.hist.first_use_stats()``, the
+    process's first apart from the later ones: what a first check costs
+    over a steady one."""
+    t0 = mono_now()
+    try:
+        with span("entry.analyze", entries=len(history)):
+            return _analyze(test, history, service)
+    finally:
+        observe_analyze(mono_now() - t0)
 
 
 def _analyze(test, history: History,

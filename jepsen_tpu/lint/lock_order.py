@@ -121,7 +121,8 @@ LOCK_ORDER: Tuple[Tuple[str, List[Tuple[str, str]]], ...] = (
      [(r"engine/shrink\.py$", r"^_STATS_LOCK$")]),
     ("obs-hist",
      [(r"obs/hist\.py$", r"^self\._lock$"),
-      (r"obs/hist\.py$", r"^_MERGE_LOCK$")]),
+      (r"obs/hist\.py$", r"^_MERGE_LOCK$"),
+      (r"obs/hist\.py$", r"^_FIRST_USE_LOCK$")]),
     ("obs-recorder",
      [(r"obs/recorder\.py$", r"^self\._lock$")]),
     ("obs-telemetry",

@@ -8,7 +8,11 @@ existing ``/metrics`` surface:
                  propagated on every wire frame, plus the Chrome
                  trace-event (Perfetto) conversion for merged traces.
 - ``hist``     — log-bucketed latency/compile-time histograms, their
-                 buckets powers of two like the engine's shape rungs.
+                 buckets powers of two like the engine's shape rungs;
+                 and ``first_use_stats()``, what the process paid once
+                 (JAX's trace, lower and load seconds by engine shape
+                 and by eager program, the first ``core.analyze``
+                 against the later ones).
 - ``recorder`` — a bounded process-wide ring of structured events with
                  an atomic Chrome-trace export (``RECORDER``), and
                  ``span``/``instant``: the checker path's layer
@@ -21,8 +25,8 @@ scope or inside a function (``tests/test_layering.py``).
 """
 
 from jepsen_tpu.obs.hist import (  # noqa: F401
-    Histogram, HistogramSet, compile_hist_stats, merge_hist_snapshots,
-    observe_compile, timed_first_call,
+    Histogram, HistogramSet, compile_hist_stats, first_use_stats,
+    merge_hist_snapshots, observe_compile, timed_first_call,
 )
 from jepsen_tpu.obs.recorder import (  # noqa: F401
     RECORDER, FlightRecorder, instant, span,
@@ -33,7 +37,7 @@ from jepsen_tpu.obs.trace import (  # noqa: F401
 )
 
 __all__ = [
-    "Histogram", "HistogramSet", "compile_hist_stats",
+    "Histogram", "HistogramSet", "compile_hist_stats", "first_use_stats",
     "merge_hist_snapshots", "observe_compile", "timed_first_call",
     "RECORDER", "FlightRecorder", "instant", "span",
     "chrome_document", "chrome_events_from_trace", "new_span_id",

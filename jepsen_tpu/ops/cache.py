@@ -32,12 +32,16 @@ def init_compilation_cache() -> str:
     """Turn JAX's persistent compilation cache on for this process: the
     one shared init of every engine build, the serve service, each bench
     tier and the CLI.  Idempotent; safe to call before or after the first
-    trace.  Never raises (a read-only filesystem or a broken JAX install
-    must not take checking down with it); returns the directory in force,
-    or "" when caching stayed off."""
+    trace.  Also where the program starts to count what JAX builds
+    (``obs.hist.first_use_stats()``).  Never raises (a read-only
+    filesystem or a broken JAX install must not take checking down with
+    it); returns the directory in force, or "" when caching stayed
+    off."""
     try:
         import jax
 
+        from jepsen_tpu.obs.hist import listen_first_use
+        listen_first_use()
         if jax.default_backend() == "cpu" \
                 and "JEPSEN_TPU_CACHE_CPU" not in os.environ:
             # CPU AOT cache entries embed exact machine features and XLA

@@ -40,6 +40,34 @@ def test_last_stdout_line_is_the_stamp_alone(monkeypatch, capsys):
     assert set(report["phases"]) == {"offline", "hard", "keyed", "served"}
 
 
+def test_report_reads_the_programs_own_compile_counters(monkeypatch):
+    """``phase_report`` takes JAX's compile and cache events from
+    ``first_use_stats()`` between two marks (no listener of its own), under
+    the keys its ``{"report": ...}`` line always had."""
+    import jax
+    import jax.numpy as jnp
+
+    from jepsen_tpu.obs import hist
+    hist.listen_first_use()
+    monkeypatch.setattr(chip_smoke, "require", lambda *a: None)  # no cache
+    watch = hist.first_use_stats()
+
+    @jax.jit
+    def smoke_op(x):
+        return x + 11
+    smoke_op(jnp.ones(2))
+    report = chip_smoke.phase_report(watch)
+    assert set(report["compile"]) == {
+        "engine_first_calls", "engine_first_call_s", "engines_s",
+        "backend_compiles", "backend_compile_s", "persistent_cache_hits",
+        "persistent_cache_misses"}
+    assert report["compile"]["backend_compiles"] == \
+        hist.first_use_stats()["programs"] - watch["programs"] >= 1
+    assert report["compile"]["persistent_cache_hits"] == 0
+    assert set(report["cache"]) == {"dir", "entries", "from_env"}
+    assert not hasattr(chip_smoke, "CompileWatch")
+
+
 def test_phase_offline_toy():
     obs = chip_smoke.phase_offline(0, n_ops=120)
     assert obs["ops"] == 120 and obs["configs_explored"] > 0

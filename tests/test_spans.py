@@ -31,6 +31,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHECK_SPANS = {"drivers.check", "prepare", "drivers.stage", "drivers.rung",
                "drivers.dispatch", "drivers.poll"}
 
+#: what a first use adds to any of them (tests/test_first_use.py)
+COMPILE_EVENTS = {"compile.first_call", "compile.trace", "compile.lower",
+                  "compile.load"}
+
 #: what ``drivers.check`` opens and closes with (docs/observability.md)
 CHECK_CLOSES_WITH = {"events", "chunk", "window", "gwords", "max_capacity",
                      "dispatches", "discarded", "grows", "shrinks",
@@ -179,7 +183,7 @@ class TestOfflinePath:
         assert res["valid"] is True
         evs = rec.snapshot()
         names = by_name(evs)
-        assert set(names) - {"compile.first_call"} == \
+        assert set(names) - COMPILE_EVENTS == \
             CHECK_SPANS | {"entry.analyze"}
         root, = names["entry.analyze"]
         assert "parent-span-id" not in root
@@ -211,7 +215,7 @@ class TestOfflinePath:
         assert res["valid"] is False and res["failures"] == [0]
         evs = rec.snapshot()
         names = by_name(evs)
-        assert set(names) - {"compile.first_call"} == {
+        assert set(names) - COMPILE_EVENTS == {
             "entry.analyze", "entry.split", "entry.rederive",
             "drivers.check_batch", "drivers.run_lanes", "prepare",
             "drivers.stage", "drivers.dispatch", "drivers.poll",
@@ -262,7 +266,7 @@ class TestOfflinePath:
         assert [r["valid"] for r in res] == [True] * 6
         evs = rec.snapshot()
         names = by_name(evs)
-        assert set(names) - {"compile.first_call"} == {
+        assert set(names) - COMPILE_EVENTS == {
             "drivers.check_batch", "prepare", "drivers.run_lanes",
             "drivers.stage", "drivers.dispatch", "drivers.poll",
             "drivers.lane_retry"}
