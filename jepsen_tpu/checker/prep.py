@@ -24,16 +24,30 @@ Rules applied here (knossos parity):
   - ``info`` pure-read ops with unknown values are dropped (unconstraining);
   - ``ok`` ops produce an ENTER event at their invocation index and a RETURN
     event at their completion index.
+
+What a call costs.  Two passes over the history it is handed and nothing
+kept between calls: one that pairs invokes with completions by process
+(``History.pair_index``'s rule, nemesis ops skipped in place), one that emits
+the events; no intermediate ``History``, and an ``Op`` is copied only where
+its ok completion brings a value the invoke did not carry (a read).  1.5 us
+an entry on the benchmark machine's host (``prepare.s_per_kop.keyed`` 0.0015
+s per 1,000 entries, 0.47 s of a 2.07 s ``keyed200.offline`` call for its 512
+lanes; the three-pass form before it: 3.0 us, 0.97 s.  PERF.md section 6,
+my chip runs, PR 36), 1.3 us in the sandbox: the pairing pass a third, the
+event pass with ``encode_op`` and the copies a half, ``np.array`` a sixth.
+``tests/test_prep.py`` keeps the three-pass form as the reference.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Set, Tuple
+from typing import Any, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from jepsen_tpu.history import History, INFO, INVOKE, OK, FAIL, Op
+from jepsen_tpu.history import (
+    FAIL, History, INFO, INVOKE, NEMESIS, OK, Op,
+)
 from jepsen_tpu.models.base import JaxModel, UNKNOWN32
 from jepsen_tpu.obs.recorder import span
 
@@ -98,76 +112,98 @@ def prepare(history: History,
 def _prepare(history: History, model: Optional[JaxModel],
              max_window: Optional[int], pure_read_names: Sequence[str],
              ) -> PreparedHistory:
-    h = history.client_ops().complete()
-    pairs = h.pair_index()
+    # Pairing pass (History.pair_index's rule, by process, nemesis ops
+    # skipped in place).  One record an invoke: [op, completion, slot,
+    # op id].  ``order`` holds a record at its invoke and again at its ok
+    # completion; a fail or info completion only fills the record.
+    order: List[list] = []
+    open_invokes: dict = {}     # process -> record of its open invoke
+    n_nemesis = 0
+    for i, op in enumerate(history.ops):
+        p = op.process
+        if p == NEMESIS:
+            n_nemesis += 1
+            continue
+        t = op.type
+        if t == INVOKE:
+            if op.index is None:
+                # History.__init__ parity: position in the client view
+                op = op.with_(index=i - n_nemesis)
+            rec = open_invokes[p] = [op, None, -1, 0]
+            order.append(rec)
+        elif t == OK or t == FAIL or t == INFO:
+            rec = open_invokes.pop(p, None)
+            if rec is not None:
+                rec[1] = op
+                if t == OK:
+                    order.append(rec)
 
+    # Event pass.  Six columns an event here; the four ghost columns are
+    # filled on the finished array, for the ghost rows only.
     events: List[Tuple[int, ...]] = []
     ops: List[Op] = []
     free: List[int] = []
     next_slot = 0
-    slot_of: dict = {}      # history position of invoke -> slot
-    opid_of: dict = {}      # history position of invoke -> op_id
     crashed: List[int] = []
-    gclasses: dict = {}     # (f, a, b) -> [ghost slots, in enter order]
+    gclasses: dict = {}     # class key -> [ghost slots, in enter order]
+    ghosts: List[Tuple[int, Any, int]] = []  # (event row, class key, rank)
     pure_fs: Set[int] = set(model.pure_read_fs) if model else set()
+    encode = model.encode_op if model is not None else None
+    f = a = b = 0
 
-    def alloc_slot() -> int:
-        nonlocal next_slot
+    for rec in order:
+        s = rec[2]
+        if s >= 0:  # second visit: the ok completion
+            events.append((EV_RETURN, s, 0, 0, 0, rec[3]))
+            free.append(s)
+            continue
+        op, comp = rec[0], rec[1]
+        ctype = comp.type if comp is not None else INFO
+        if ctype == FAIL:
+            continue  # never took effect
+        if ctype == OK:
+            # History.complete parity: the invoke adopts its ok
+            # completion's value; copied only where that changes it.
+            v = comp.value
+            if v is not None:
+                own = op.value
+                if v is not own and v != own:
+                    op = op.with_(value=v)
+        if encode is not None:
+            f, a, b = encode(op)
+            if ctype == INFO and f in pure_fs and a == UNKNOWN32:
+                continue  # crashed read, unknown value: unconstraining
+        elif ctype == INFO and op.f in pure_read_names and op.value is None:
+            continue
         if free:
-            return free.pop()
-        s = next_slot
-        next_slot += 1
-        return s
-
-    for i, op in enumerate(h):
-        if op.type == INVOKE:
-            j = pairs[i]
-            comp = h[j] if j >= 0 else None
-            ctype = comp.type if comp is not None else INFO
-            if ctype == FAIL:
-                continue  # never took effect
-            if model is not None:
-                f, a, b = model.encode_op(op)
-                if ctype == INFO and f in pure_fs and a == UNKNOWN32:
-                    continue  # crashed read, unknown value: unconstraining
-            else:
-                f = a = b = 0
-                if ctype == INFO and op.f in pure_read_names and op.value is None:
-                    continue
-            s = alloc_slot()
-            slot_of[i] = s
-            opid_of[i] = len(ops)
-            if ctype == INFO:
-                # Class key: the op's semantics.  With a model, the int32
-                # encoding; without (host tier), the raw (f, value) — the
-                # all-zero placeholder encodings must not merge classes.
-                key = (f, a, b) if model is not None else (op.f,
-                                                          repr(op.value))
-                members = gclasses.setdefault(key, [])
-                cls, rank = (members[0] if members else s), len(members)
-                members.append(s)
-                # gpos (col 9) is a placeholder here; class-grouped compact
-                # positions are assigned once all class sizes are known.
-                events.append((EV_ENTER, s, f, a, b, len(ops), 1, cls, rank,
-                               0))
-                crashed.append(s)
-            else:
-                events.append((EV_ENTER, s, f, a, b, len(ops), 0, -1, 0, 0))
-            ops.append(op)
-        elif op.type == OK:
-            j = pairs[i]
-            if j in slot_of:
-                s = slot_of[j]
-                events.append((EV_RETURN, s, 0, 0, 0, opid_of[j], 0, -1, 0,
-                               0))
-                free.append(s)
-        # FAIL completions: pair already skipped. INFO completions: op stays.
+            s = free.pop()
+        else:
+            s = next_slot
+            next_slot += 1
+        op_id = len(ops)
+        rec[2] = s
+        rec[3] = op_id
+        if ctype == INFO:
+            # Class key: the op's semantics.  With a model, the int32
+            # encoding; without (host tier), the raw (f, value) — the
+            # all-zero placeholder encodings must not merge classes.
+            key = (f, a, b) if encode is not None else (op.f,
+                                                        repr(op.value))
+            members = gclasses.setdefault(key, [])
+            ghosts.append((len(events), key, len(members)))
+            members.append(s)
+            crashed.append(s)
+        events.append((EV_ENTER, s, f, a, b, op_id))
+        ops.append(op)
 
     if max_window is not None and next_slot > max_window:
         raise WindowOverflow(
             f"history needs {next_slot} pending-window slots "
             f"(> max {max_window}); raise max_window or shard the history")
 
+    cols = np.zeros((len(events), 10), np.int32)
+    cols[:, :6] = np.array(events, np.int32).reshape(-1, 6)
+    cols[:, 7] = -1
     # Compact ghost positions: classes get contiguous ranges in discovery
     # order, each ghost at (class offset + rank).
     offsets: dict = {}
@@ -175,12 +211,8 @@ def _prepare(history: History, model: Optional[JaxModel],
     for key, members in gclasses.items():
         offsets[key] = off
         off += len(members)
-    class_off = {members[0]: offsets[key]
-                 for key, members in gclasses.items()}
-    events = [e[:9] + (class_off[e[7]] + e[8],) if e[6] else e
-              for e in events]
-
-    cols = np.array(events, np.int32).reshape(-1, 10)
+    for row, key, rank in ghosts:
+        cols[row, 6:] = (1, gclasses[key][0], rank, offsets[key] + rank)
     return PreparedHistory(
         kind=cols[:, 0], slot=cols[:, 1], f=cols[:, 2],
         a=cols[:, 3], b=cols[:, 4], op_id=cols[:, 5], ghost=cols[:, 6],
