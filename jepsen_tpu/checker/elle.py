@@ -23,20 +23,23 @@ from __future__ import annotations
 from typing import Any, Dict, Optional, Sequence
 
 from jepsen_tpu.checker.core import Checker
-from jepsen_tpu.elle import render
+from jepsen_tpu.elle import consistency, render
 from jepsen_tpu.history import History
 
 
 class ElleChecker(Checker):
     def __init__(self, workload: str = "list-append",
                  engine: str = "auto",
-                 realtime: bool = False,
+                 realtime: Optional[bool] = None,
                  consistency_models: Optional[Sequence[str]] = None,
                  budget_s: Optional[float] = None,
                  **workload_kw):
         self.workload = workload
         self.engine = engine
-        self.realtime = realtime
+        # the realtime order is part of the graph exactly when a model
+        # that speaks of it is asked for, unless the caller says otherwise
+        self.realtime = (consistency.needs_realtime(consistency_models)
+                         if realtime is None else realtime)
         self.consistency_models = consistency_models
         self.budget_s = budget_s
         self.workload_kw = workload_kw

@@ -33,7 +33,7 @@ stronger model they drag down.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Sequence, Set
+from typing import Dict, Iterable, List, Optional, Sequence, Set
 
 #: weaker -> directly-stronger edges of the model lattice.
 STRONGER: Dict[str, Set[str]] = {
@@ -97,6 +97,14 @@ def canonicalize(model: str) -> str:
         raise ValueError(f"unknown consistency model {model!r}; "
                          f"known: {CANONICAL}")
     return m
+
+
+def needs_realtime(models: Optional[Iterable[str]]) -> bool:
+    """Does judging these models take the realtime order into the graph?
+    Only strict serializability (under any of its ``ALIASES``) speaks of
+    it; ``None`` is the checkers' default, serializable."""
+    return any(canonicalize(m) == "strict-serializable"
+               for m in models or ())
 
 
 def implied(models: Iterable[str]) -> Set[str]:

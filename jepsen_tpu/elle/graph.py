@@ -271,7 +271,16 @@ def gsingle_cycles(g: Graph, cap: int = 64,
                 continue
             if budget is not None and not budget.spend(len(g)):
                 return out
-            path = _bfs_path(g, b, a, lambda kinds: bool(kinds - {"rw"}))
+            # a return path that needs no realtime edge first: the shortest
+            # one often takes a realtime shortcut, and the cycle would then
+            # be filed as G-single-realtime (refuting the strict tier only)
+            # where a plain G-single exists
+            path = _bfs_path(g, b, a,
+                             lambda kinds: bool(kinds - {"rw", "realtime"}))
+            if path is None:
+                if budget is not None and not budget.spend(len(g)):
+                    return out
+                path = _bfs_path(g, b, a, lambda kinds: bool(kinds - {"rw"}))
             if path is not None:
                 out.append([a] + path)
                 if len(out) >= cap:

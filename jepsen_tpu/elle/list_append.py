@@ -24,7 +24,8 @@ from __future__ import annotations
 
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Any, Dict, Iterator, List, Optional, Sequence, Set,
+                    Tuple)
 
 from jepsen_tpu.elle import consistency
 from jepsen_tpu.elle.graph import (Graph, SearchBudget, cycle_edge_kinds,
@@ -33,6 +34,11 @@ from jepsen_tpu.elle.graph import (Graph, SearchBudget, cycle_edge_kinds,
 from jepsen_tpu.history import FAIL, History, INFO, INVOKE, OK, Op
 
 CYCLE_SEVERITY = ["G0", "G1c", "G-single", "G-nonadjacent", "G2-item"]
+
+#: the dependency kinds a host pass infers; ``Dependencies.edges`` names a
+#: kind by its place here
+EDGE_KINDS = ("ww", "wr", "rw")
+WW, WR, RW = range(3)
 
 # Same sentinel as checker.core.UNKNOWN — spelled out so elle stays
 # importable without the checker package.
@@ -136,12 +142,19 @@ class Analysis:
 
 
 def add_realtime_edges(g: Graph, oks: List[Tuple[int, Op]],
-                       pairs: Sequence[int]) -> None:
+                       pairs: Sequence[int],
+                       budget: Optional[SearchBudget] = None) -> None:
     """T1 -> T2 iff T1's completion index precedes T2's invocation index
     (strict mode).  O(n^2) and dense — kept out of :func:`analyze` so the
     device engine can compute the same relation as a broadcast compare and
-    only materialize these edges for witness recovery."""
+    only materialize these edges for witness recovery.  ``budget``'s
+    deadline is asked once a row (no steps are charged): past it the layer
+    stays partial and the budget reads ``truncated``, so the search that
+    follows stops at once and the verdict degrades as for any cut search
+    (at 10,000 transactions the whole layer is 4.5e7 edges)."""
     for t1, (i1, _) in enumerate(oks):
+        if budget is not None and not budget.spend(0):
+            return
         for t2, (i2, _) in enumerate(oks):
             if t1 == t2:
                 continue
@@ -169,7 +182,8 @@ def check(history: History,
                               else ("serializable",))
     a = analyze(history)
     if realtime:
-        add_realtime_edges(a.graph, a.oks, a.pairs)
+        add_realtime_edges(a.graph, a.oks, a.pairs,
+                           budget=search_budget)
     truncated = collect_cycle_anomalies(a.graph, a.txn_of, a.anomalies,
                                         budget=search_budget)
     res = finish_result(a.anomalies, consistency_models, a.count,
@@ -180,10 +194,37 @@ def check(history: History,
     return res
 
 
-def analyze(history: History) -> Analysis:
-    """The linear host pass: indices, version orders, host anomalies, and
-    the ww/wr/rw dependency graph — everything but cycle search and the
-    realtime layer."""
+@dataclass
+class Dependencies:
+    """The first half of the host pass: the ok transactions, each key's
+    version order and the ww/wr/rw edges in the order the graph takes
+    them.  It is all a device needs before its closures can start;
+    :func:`analysis_of` makes the :class:`Analysis` of it (the host
+    anomalies, the graph as an object), and may do so while they run."""
+    oks: List[Tuple[int, Op]]
+    pairs: Sequence[int]
+    txn_of: Dict[int, List]
+    writer: Dict[Tuple[Any, Any], int]
+    failed_writes: Set[Tuple[Any, Any]]
+    #: every read of a known list, in history order: (txn, op, key, list)
+    reads: List[Tuple[int, Op, Any, List[Any]]]
+    duplicates: List[Dict[str, Any]]
+    #: three ints an edge, one edge after another: from, to, the kind as
+    #: its place in ``EDGE_KINDS`` (flat, so that 45,000 edges are no
+    #: 45,000 tuples for the collector to walk); an edge may repeat
+    edges: List[int]
+
+    def edge_triples(self) -> Iterator[Tuple[int, int, int]]:
+        it = iter(self.edges)
+        return zip(it, it, it)
+
+    @property
+    def count(self) -> int:
+        return len(self.oks)
+
+
+def dependencies(history: History) -> Dependencies:
+    """Indices, version orders and the ww/wr/rw dependency edges."""
     # Client ops only: a nemesis op's value (e.g. the killed node list)
     # is not a txn, and elle likewise analyzes the client subhistory
     # (elle's history preparation removes non-txn ops).
@@ -208,64 +249,24 @@ def analyze(history: History) -> Analysis:
                         (failed_writes if op.type == FAIL
                          else info_writes).add((k, v))
 
-    anomalies: Dict[str, List[Any]] = defaultdict(list)
-
-    # writer index + duplicate detection
+    # writer index + duplicate detection, the reads, per-key longest read
     writer: Dict[Tuple[Any, Any], int] = {}
     txn_of: Dict[int, List] = {}
+    duplicates: List[Dict[str, Any]] = []
+    reads: List[Tuple[int, Op, Any, List[Any]]] = []
+    longest: Dict[Any, List[Any]] = {}
     for tid, (_, op) in enumerate(oks):
         txn_of[tid] = op.value
         for f, k, v in op.value:
             if f == "append":
                 if (k, v) in writer:
-                    anomalies["duplicate-appends"].append(
-                        {"key": k, "value": v})
+                    duplicates.append({"key": k, "value": v})
                 writer[(k, v)] = tid
-
-    # per-key longest read + prefix consistency + G1a/G1b
-    longest: Dict[Any, List[Any]] = {}
-    for tid, (_, op) in enumerate(oks):
-        for f, k, v in op.value:
-            if f not in ("r", "read") or v is None:
-                continue
-            lst = list(v)
-            # G1a: observed value appended by a failed txn
-            for x in lst:
-                if (k, x) in failed_writes:
-                    anomalies["G1a"].append({"key": k, "value": x,
-                                             "reader": op.to_dict()})
-            cur = longest.get(k, [])
-            short, long_ = (lst, cur) if len(lst) <= len(cur) else (cur, lst)
-            if short != long_[:len(short)]:
-                anomalies["incompatible-order"].append(
-                    {"key": k, "a": cur, "b": lst})
-            if len(lst) > len(cur):
-                longest[k] = lst
-
-    # G1b: a read that ends inside another txn's append run
-    # (observes some but not all of a txn's appends to k, with nothing after)
-    appends_by_txn_key: Dict[Tuple[int, Any], List[Any]] = defaultdict(list)
-    for tid, (_, op) in enumerate(oks):
-        for f, k, v in op.value:
-            if f == "append":
-                appends_by_txn_key[(tid, k)].append(v)
-    for rtid, (_, op) in enumerate(oks):
-        for f, k, v in op.value:
-            if f not in ("r", "read") or not v:
-                continue
-            last = v[-1]
-            wtid = writer.get((k, last))
-            if wtid is None or wtid == rtid:
-                continue
-            run = appends_by_txn_key[(wtid, k)]
-            if run and last != run[-1]:
-                anomalies["G1b"].append({"key": k, "value": last,
-                                         "reader": op.to_dict()})
-
-    # dependency graph
-    g = Graph()
-    for tid in range(len(oks)):
-        g.add_node(tid)
+            elif f in ("r", "read") and v is not None:
+                lst = v if type(v) is list else list(v)
+                reads.append((tid, op, k, lst))
+                if len(lst) > len(longest.get(k, ())):
+                    longest[k] = lst
 
     # Values appended but never observed by any read still have a sound
     # place in the (append-only) version order: had such an append preceded
@@ -280,49 +281,109 @@ def analyze(history: History) -> Analysis:
     unobserved: Dict[Any, List[Any]] = {}
     for k, vs in by_key.items():
         obs = set(longest.get(k, []))
-        unobserved[k] = [v for v in vs if v not in obs]
+        late = [v for v in vs if v not in obs]
+        if late:
+            unobserved[k] = late
 
+    edges: List[int] = []
     for k, order in longest.items():
         # ww edges along the version order
-        for a, b in zip(order, order[1:]):
-            wa, wb = writer.get((k, a)), writer.get((k, b))
+        ws = [writer.get((k, v)) for v in order]
+        for wa, wb in zip(ws, ws[1:]):
             if wa is not None and wb is not None and wa != wb:
-                g.add_edge(wa, wb, "ww")
+                edges += (wa, wb, WW)
         if order:
-            wa = writer.get((k, order[-1]))
+            wa = ws[-1]
             for v in unobserved.get(k, ()):
                 wb = writer.get((k, v))
                 if wa is not None and wb is not None and wa != wb:
-                    g.add_edge(wa, wb, "ww")
+                    edges += (wa, wb, WW)
 
-    for rtid, (_, op) in enumerate(oks):
-        for f, k, v in op.value:
-            if f not in ("r", "read") or v is None:
-                continue
-            lst = list(v)
-            if lst:
-                w = writer.get((k, lst[-1]))
-                if w is not None and w != rtid:
-                    g.add_edge(w, rtid, "wr")
-            # rw: the next value after the observed state
-            order = longest.get(k, [])
-            nxt = order[len(lst)] if len(lst) < len(order) and \
-                order[:len(lst)] == lst else None
-            if nxt is not None:
-                w = writer.get((k, nxt))
-                if w is not None and w != rtid:
-                    g.add_edge(rtid, w, "rw")
-            # rw: every unobserved append to k follows any observed state
+    for rtid, _, k, lst in reads:
+        if lst:
+            w = writer.get((k, lst[-1]))
+            if w is not None and w != rtid:
+                edges += (w, rtid, WR)
+        # rw: the next value after the observed state
+        order = longest.get(k, [])
+        n = len(lst)
+        if n < len(order) and order[:n] == lst and order[n] is not None:
+            w = writer.get((k, order[n]))
+            if w is not None and w != rtid:
+                edges += (rtid, w, RW)
+        # rw: every unobserved append to k follows any observed state
+        late = unobserved.get(k)
+        if late:
             observed = set(lst)
-            for v in unobserved.get(k, ()):
+            for v in late:
                 if v in observed:
                     continue
                 w = writer.get((k, v))
                 if w is not None and w != rtid:
-                    g.add_edge(rtid, w, "rw")
+                    edges += (rtid, w, RW)
 
-    return Analysis(graph=g, txn_of=txn_of, anomalies=anomalies,
-                    oks=oks, pairs=pairs)
+    return Dependencies(oks=oks, pairs=pairs, txn_of=txn_of, writer=writer,
+                        failed_writes=failed_writes, reads=reads,
+                        duplicates=duplicates, edges=edges)
+
+
+def analysis_of(d: Dependencies) -> Analysis:
+    """The second half of the host pass: the host anomalies (duplicates,
+    G1a, incompatible orders, G1b) and the dependency graph as an object."""
+    anomalies: Dict[str, List[Any]] = defaultdict(list)
+    if d.duplicates:
+        anomalies["duplicate-appends"].extend(d.duplicates)
+
+    # prefix consistency against the longest read so far + G1a
+    failed_writes = d.failed_writes
+    longest: Dict[Any, List[Any]] = {}
+    for _, op, k, lst in d.reads:
+        # G1a: observed value appended by a failed txn
+        for x in lst:
+            if (k, x) in failed_writes:
+                anomalies["G1a"].append({"key": k, "value": x,
+                                         "reader": op.to_dict()})
+        cur = longest.get(k, [])
+        short, long_ = (lst, cur) if len(lst) <= len(cur) else (cur, lst)
+        if short != long_[:len(short)]:
+            anomalies["incompatible-order"].append(
+                {"key": k, "a": cur, "b": lst})
+        if len(lst) > len(cur):
+            longest[k] = lst
+
+    # G1b: a read that ends inside another txn's append run
+    # (observes some but not all of a txn's appends to k, with nothing after)
+    appends_by_txn_key: Dict[Tuple[int, Any], List[Any]] = defaultdict(list)
+    for tid, (_, op) in enumerate(d.oks):
+        for f, k, v in op.value:
+            if f == "append":
+                appends_by_txn_key[(tid, k)].append(v)
+    for rtid, op, k, lst in d.reads:
+        if not lst:
+            continue
+        last = lst[-1]
+        wtid = d.writer.get((k, last))
+        if wtid is None or wtid == rtid:
+            continue
+        run = appends_by_txn_key[(wtid, k)]
+        if run and last != run[-1]:
+            anomalies["G1b"].append({"key": k, "value": last,
+                                     "reader": op.to_dict()})
+
+    g = Graph()
+    for tid in range(len(d.oks)):
+        g.add_node(tid)
+    for a, b, kind in d.edge_triples():
+        g.add_edge(a, b, EDGE_KINDS[kind])
+    return Analysis(graph=g, txn_of=d.txn_of, anomalies=anomalies,
+                    oks=d.oks, pairs=d.pairs)
+
+
+def analyze(history: History) -> Analysis:
+    """The linear host pass: indices, version orders, host anomalies, and
+    the ww/wr/rw dependency graph — everything but cycle search and the
+    realtime layer."""
+    return analysis_of(dependencies(history))
 
 
 def finish_result(anomalies: Dict[str, List[Any]],

@@ -60,7 +60,7 @@ def check(history: History, realtime: bool = False,
     a = analyze(history, sequential_keys=sequential_keys,
                 linearizable_keys=linearizable_keys)
     if realtime:
-        add_realtime_edges(a.graph, a.oks, a.pairs)
+        add_realtime_edges(a.graph, a.oks, a.pairs, budget=search_budget)
     truncated = collect_cycle_anomalies(a.graph, a.txn_of, a.anomalies,
                                         budget=search_budget)
     res = finish_result(a.anomalies, consistency_models, a.count,

@@ -22,13 +22,18 @@ from typing import Any, Dict, Optional, Sequence
 import numpy as np
 
 from jepsen_tpu.elle.graph import SearchBudget, edge_list
-from jepsen_tpu.elle.list_append import (add_realtime_edges,
+from jepsen_tpu.elle.list_append import (CYCLE_SEVERITY, add_realtime_edges,
                                          collect_cycle_anomalies,
                                          finish_result)
 from jepsen_tpu.elle_tpu.closure import FLAG_NAMES
 from jepsen_tpu.elle_tpu.encode import EncodedHistory
+from jepsen_tpu.obs.recorder import span
 
 ANALYZER = "elle-tpu"
+
+#: every label ``collect_cycle_anomalies`` files a cycle under
+CYCLE_TYPES = frozenset(label + suffix for label in CYCLE_SEVERITY
+                        for suffix in ("", "-realtime"))
 
 
 def finish_lane(enc: EncodedHistory,
@@ -38,22 +43,29 @@ def finish_lane(enc: EncodedHistory,
                 budget: Optional[SearchBudget] = None) -> Dict[str, Any]:
     """One lane's result map from its encoding and device flag vector
     (``flags=None`` means "no device verdict — search unconditionally")."""
-    a = enc.analysis
+    a = enc.finish_analysis()
     truncated = False
-    if flags is None or bool(flags[0]):
-        if realtime:
-            add_realtime_edges(a.graph, a.oks, a.pairs)
-        truncated = collect_cycle_anomalies(a.graph, a.txn_of, a.anomalies,
-                                            budget=budget)
-    res = finish_result(a.anomalies, consistency_models, a.count,
-                        truncated=truncated)
-    res["analyzer"] = ANALYZER
-    if flags is not None:
-        res["device-flags"] = {name: bool(v)
-                               for name, v in zip(FLAG_NAMES, flags)}
-    # Complete edge list for artifact rendering (popped by
-    # elle.render.write_artifacts).  On an acyclic strict-mode lane the
-    # dense realtime layer was never materialized host-side — the list
-    # then carries the ww/wr/rw core only.
-    res["edges-full"] = edge_list(a.graph)
+    recover = flags is None or bool(flags[0])
+    if recover:
+        with span("elle.recover", txns=a.count, realtime=realtime) as sp:
+            if realtime:
+                add_realtime_edges(a.graph, a.oks, a.pairs, budget=budget)
+            truncated = collect_cycle_anomalies(a.graph, a.txn_of,
+                                                a.anomalies, budget=budget)
+            sp.set(cyclic=any(t in a.anomalies for t in CYCLE_TYPES),
+                   truncated=truncated)
+    with span("elle.render", txns=a.count):
+        res = finish_result(a.anomalies, consistency_models, a.count,
+                            truncated=truncated)
+        res["analyzer"] = ANALYZER
+        if flags is not None:
+            res["device-flags"] = {name: bool(v)
+                                   for name, v in zip(FLAG_NAMES, flags)}
+        # Complete edge list for artifact rendering (popped by
+        # elle.render.write_artifacts).  On an acyclic strict-mode lane the
+        # dense realtime layer was never materialized host-side — the list
+        # then carries the ww/wr/rw core only, as ``finish_analysis`` made
+        # it.
+        res["edges-full"] = (edge_list(a.graph) if recover and realtime
+                             else enc.edge_list)
     return res

@@ -40,6 +40,22 @@ import jax.numpy as jnp
 #: order of the per-lane flag vector returned by the kernel.
 FLAG_NAMES = ("cyclic", "g0", "g1c", "g-single")
 
+#: the ``jax.named_scope`` of each phase of a lane, in the lowered program's
+#: locations (metadata: the program itself is the same without them)
+KERNEL_SCOPES = ("elle.layers", "elle.realtime", "elle.closure",
+                 "elle.flags")
+
+#: closures a lane runs (full, nonrw, g0) and adjacency layers it builds by
+#: a one-hot product (ww, wr, rw; the realtime layer is a comparison)
+CLOSURES_PER_LANE = 3
+LAYER_BUILDS_PER_LANE = 3
+
+
+def closure_rounds(n_pad: int) -> int:
+    """Squarings one closure runs at this size: every one of them, there
+    is no early exit."""
+    return max(1, math.ceil(math.log2(n_pad)))
+
 
 def transitive_closure(adj: jnp.ndarray, n_iters: int) -> jnp.ndarray:
     """Close a 0/1 float adjacency matrix over paths of length >= 1."""
@@ -63,29 +79,33 @@ def lane_flags_fn(n_pad: int, realtime: bool):
     ``[B, len(FLAG_NAMES)]`` bools.  Edge-count ``E`` may vary between
     calls (jit retraces per shape; e_pad is quantized to multiples of 64
     by graphs.pack_group to bound the variant count)."""
-    n_iters = max(1, math.ceil(math.log2(n_pad)))
+    n_iters = closure_rounds(n_pad)
 
     def lane(src, dst, invoke, complete):
-        ww = _layer(src[0], dst[0], n_pad)
-        wr = _layer(src[1], dst[1], n_pad)
-        rw = _layer(src[2], dst[2], n_pad)
-        if realtime:
-            rt = ((complete[:, None] < invoke[None, :])
-                  & (invoke[None, :] >= 0)).astype(jnp.float32)
-        else:
-            rt = jnp.zeros((n_pad, n_pad), jnp.float32)
-        nonrw = jnp.minimum(ww + wr + rt, 1.0)
-        full = jnp.minimum(nonrw + rw, 1.0)
-        g0_adj = jnp.minimum(ww + rt, 1.0)
-        cl_full = transitive_closure(full, n_iters)
-        cl_nonrw = transitive_closure(nonrw, n_iters)
-        cl_g0 = transitive_closure(g0_adj, n_iters)
-        cyclic = jnp.trace(cl_full) > 0
-        g0 = jnp.trace(cl_g0) > 0
-        g1c = jnp.trace(cl_nonrw) > 0
-        # rw edge a->b plus a nonrw path b ->* a: cl_nonrw[b, a] read
-        # through the transpose aligns with rw[a, b].
-        g_single = jnp.sum(rw * cl_nonrw.T) > 0
-        return jnp.stack([cyclic, g0, g1c, g_single])
+        with jax.named_scope("elle.layers"):
+            ww = _layer(src[0], dst[0], n_pad)
+            wr = _layer(src[1], dst[1], n_pad)
+            rw = _layer(src[2], dst[2], n_pad)
+        with jax.named_scope("elle.realtime"):
+            if realtime:
+                rt = ((complete[:, None] < invoke[None, :])
+                      & (invoke[None, :] >= 0)).astype(jnp.float32)
+            else:
+                rt = jnp.zeros((n_pad, n_pad), jnp.float32)
+            nonrw = jnp.minimum(ww + wr + rt, 1.0)
+            full = jnp.minimum(nonrw + rw, 1.0)
+            g0_adj = jnp.minimum(ww + rt, 1.0)
+        with jax.named_scope("elle.closure"):
+            cl_full = transitive_closure(full, n_iters)
+            cl_nonrw = transitive_closure(nonrw, n_iters)
+            cl_g0 = transitive_closure(g0_adj, n_iters)
+        with jax.named_scope("elle.flags"):
+            cyclic = jnp.trace(cl_full) > 0
+            g0 = jnp.trace(cl_g0) > 0
+            g1c = jnp.trace(cl_nonrw) > 0
+            # rw edge a->b plus a nonrw path b ->* a: cl_nonrw[b, a] read
+            # through the transpose aligns with rw[a, b].
+            g_single = jnp.sum(rw * cl_nonrw.T) > 0
+            return jnp.stack([cyclic, g0, g1c, g_single])
 
     return jax.jit(jax.vmap(lane))

@@ -3,7 +3,8 @@
 exception, adoption of a request's ids, the Chrome export), the names the
 offline path emits from ``core.analyze`` down to a dispatch, the
 benchmark's ``program_span`` reader on a hand-made span list, and the
-``jax.named_scope`` names on the engine's phases.
+``jax.named_scope`` names on the engine's phases; the same for the
+list-append path (``elle.*``) down to the closure kernel's scopes.
 """
 
 import importlib.util
@@ -347,6 +348,80 @@ class TestOfflinePath:
         assert res["valid"] is True and rec.snapshot() == []
 
 
+class TestEllePath:
+    """One ``core.analyze`` of a list-append history under the workload's
+    own checker (``workloads.cycle.append_workload``), recorder on: the
+    ``elle.*`` rows of docs/observability.md and PERF.md section 3."""
+
+    ELLE_SPANS = {"elle.analyze", "elle.encode", "elle.pack",
+                  "elle.dispatch", "elle.anomalies", "elle.readback",
+                  "elle.render"}
+
+    @pytest.fixture(scope="class")
+    def checker(self):
+        from jepsen_tpu.workloads.cycle import append_workload
+        return append_workload(
+            consistency_models=("strict-serializable",))["checker"]
+
+    def test_clean_history_span_names_and_tree(self, rec, checker):
+        from jepsen_tpu.synth import list_append_history
+        h = list_append_history(60, seed=1)
+        res = core.analyze({"checker": checker}, h)
+        assert res["valid"] is True and res["analyzer"] == "elle-tpu"
+        evs = rec.snapshot()
+        names = by_name(evs)
+        assert set(names) - COMPILE_EVENTS == \
+            self.ELLE_SPANS | {"entry.analyze"}
+        root, = names["entry.analyze"]
+        assert {e["trace-id"] for e in evs} == {root["trace-id"]}
+        for n in self.ELLE_SPANS:
+            span_, = names[n]
+            assert parent_of(evs, span_) is root, n
+        order = sorted(self.ELLE_SPANS, key=lambda n: names[n][0]["ts"])
+        # the second half of the host pass follows the dispatch: the
+        # closures run under it
+        assert order == ["elle.analyze", "elle.encode", "elle.pack",
+                         "elle.dispatch", "elle.anomalies", "elle.readback",
+                         "elle.render"]
+        assert names["elle.analyze"][0]["args"] == {
+            "lanes": 1, "workload": "list-append"}
+        assert names["elle.anomalies"][0]["args"] == {"lanes": 1}
+        pack = names["elle.pack"][0]["args"]
+        assert set(pack) == {"lanes", "n_pad", "e_pad", "bytes"}
+        assert pack["n_pad"] % 32 == 0 and pack["e_pad"] % 64 == 0
+        assert names["elle.dispatch"][0]["args"] == {
+            "lanes": 1, "n_pad": pack["n_pad"], "e_pad": pack["e_pad"]}
+        assert names["elle.readback"][0]["args"] == {
+            "group": 0, "lanes": 1, "flags_set": 0}
+        assert names["elle.render"][0]["args"] == {"txns": res["count"]}
+        # a first call of the shape, if this process had not made it yet
+        for e in names.get("compile.first_call", []):
+            assert e["args"]["shape"] == \
+                f"compile:elle:n{pack['n_pad']}:rt1"
+            assert parent_of(evs, e)["name"] == "elle.dispatch"
+
+    def test_cyclic_history_recovers_under_a_span(self, rec, checker):
+        from jepsen_tpu.synth import list_append_history
+        h = list_append_history(60, seed=1, anomaly_p=0.3)
+        res = core.analyze({"checker": checker}, h)
+        assert res["valid"] is False
+        names = by_name(rec.snapshot())
+        assert set(names) - COMPILE_EVENTS == \
+            self.ELLE_SPANS | {"entry.analyze", "elle.recover"}
+        recover, = names["elle.recover"]
+        assert recover["args"] == {"txns": res["count"], "realtime": True,
+                                   "cyclic": True, "truncated": False}
+        assert names["elle.readback"][0]["args"]["flags_set"] > 0
+        assert recover["ts"] < names["elle.render"][0]["ts"]
+
+    def test_recorder_off_same_result_nothing_recorded(self, rec, checker):
+        from jepsen_tpu.synth import list_append_history
+        h = list_append_history(60, seed=1)
+        rec.disable()
+        res = core.analyze({"checker": checker}, h)
+        assert res["valid"] is True and rec.snapshot() == []
+
+
 # -- the benchmark's reader: plain arithmetic on (name, start, duration) ------
 
 @pytest.fixture(scope="module")
@@ -440,3 +515,20 @@ def test_engine_scopes_in_lowered_text(variant, kw, absent):
     for scope in wgl_tpu.ENGINE_SCOPES:
         assert (scope in with_locations) == (scope not in absent), scope
     assert "wgl." not in low.as_text()
+
+
+@pytest.mark.parametrize("realtime", [True, False])
+def test_elle_kernel_scopes_in_lowered_text(realtime):
+    """The closure kernel names its four phases, with the realtime layer
+    and without; the program's text is the same without them."""
+    from jepsen_tpu.elle_tpu import closure
+    fn = closure.lane_flags_fn(32, realtime)
+    edges = jnp.full((1, 3, 64), -1, jnp.int32)
+    times = jnp.zeros((1, 32), jnp.int32)
+    low = fn.lower(edges, edges, times, times)
+    with_locations = low.as_text(debug_info=True)
+    assert closure.KERNEL_SCOPES == ("elle.layers", "elle.realtime",
+                                     "elle.closure", "elle.flags")
+    for scope in closure.KERNEL_SCOPES:
+        assert scope in with_locations, scope
+    assert "elle." not in low.as_text()
