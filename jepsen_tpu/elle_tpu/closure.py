@@ -23,8 +23,14 @@ Construction notes:
   one fused device op.  Compiled out entirely when ``realtime=False``.
 - Closure by repeated squaring: ``R <- min(R + R@R, 1)`` doubles the
   reachable path length per iteration, so ``ceil(log2(N))`` iterations
-  close paths of any length <= N.  ``Graph.add_edge`` never stores
-  self-edges, so a nonzero closure diagonal is a genuine cycle.
+  close paths of any length <= N: that is the loop's cap, not what it
+  runs.  A squaring that sets no new cell left R closed: each closure
+  stops there, on the device, after the squarings its own graph needs
+  (3 + 1 on the 10,000-transaction append history, whose realtime layer
+  is transitive by itself).  The test compares cells (``r_new > r``),
+  never a float32 sum, which cannot see one more cell past 2^24.
+  ``Graph.add_edge`` never stores self-edges, so a nonzero closure
+  diagonal is a genuine cycle.
 - float32 0/1 instead of bool: bool matmul lowers poorly and the min()
   re-clamp keeps values exact (0.0/1.0) — no epsilon drift.
 """
@@ -45,23 +51,54 @@ FLAG_NAMES = ("cyclic", "g0", "g1c", "g-single")
 KERNEL_SCOPES = ("elle.layers", "elle.realtime", "elle.closure",
                  "elle.flags")
 
-#: closures a lane runs (full, nonrw, g0) and adjacency layers it builds by
-#: a one-hot product (ww, wr, rw; the realtime layer is a comparison)
-CLOSURES_PER_LANE = 3
+#: closures a lane runs, in the order of the rounds the kernel reports,
+#: and adjacency layers it builds by a one-hot product (ww, wr, rw; the
+#: realtime layer is a comparison)
+CLOSURE_NAMES = ("g0", "nonrw", "full")
+CLOSURES_PER_LANE = len(CLOSURE_NAMES)
 LAYER_BUILDS_PER_LANE = 3
 
 
 def closure_rounds(n_pad: int) -> int:
-    """Squarings one closure runs at this size: every one of them, there
-    is no early exit."""
+    """The cap on one closure's squarings at this size: after it every
+    path of up to ``n_pad`` nodes is closed.  A closure stops before it
+    once a squaring changes nothing (:func:`transitive_closure`)."""
     return max(1, math.ceil(math.log2(n_pad)))
 
 
-def transitive_closure(adj: jnp.ndarray, n_iters: int) -> jnp.ndarray:
-    """Close a 0/1 float adjacency matrix over paths of length >= 1."""
-    def body(_, r):
-        return jnp.minimum(r + r @ r, 1.0)
-    return jax.lax.fori_loop(0, n_iters, body, adj)
+def grew(r_new: jnp.ndarray, r: jnp.ndarray) -> jnp.ndarray:
+    """Whether a squaring set any cell of each ``[N, N]`` matrix: R only
+    grows, so this is ``r_new != r``, exactly (no sum of cells, which in
+    float32 cannot see one more past 2^24)."""
+    return jnp.any(r_new > r, axis=(-2, -1))
+
+
+def transitive_closure(adj: jnp.ndarray, cap: int):
+    """Close each 0/1 float adjacency matrix of ``adj`` (``[..., N, N]``)
+    over paths of length >= 1: ``(closures, squarings [...])``.
+
+    Squares until a squaring changes no matrix, or ``cap`` squarings have
+    run.  A matrix that a squaring left unchanged is closed, and squaring
+    it again leaves it as it is, so the batch is squared whole, with no
+    per-matrix select; each matrix's own count is the squarings up to and
+    including the one that left it unchanged (all of them at the cap),
+    and the device ran the largest of them for every matrix.  The change
+    test fuses into the product's epilogue: no pass of its own, and no
+    count taken before the loop, which would hold every layer at once."""
+    def cond(c):
+        _, active, _, k = c
+        return jnp.any(active) & (k < cap)
+
+    def body(c):
+        r, active, rounds, k = c
+        r_new = jnp.minimum(r + r @ r, 1.0)
+        return r_new, grew(r_new, r), rounds + active, k + 1
+
+    batch = adj.shape[:-2]
+    r, _, rounds, _ = jax.lax.while_loop(
+        cond, body, (adj, jnp.ones(batch, bool), jnp.zeros(batch, jnp.int32),
+                     jnp.int32(0)))
+    return r, rounds
 
 
 def _layer(src: jnp.ndarray, dst: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -76,12 +113,18 @@ def lane_flags_fn(n_pad: int, realtime: bool):
     """The jitted vmapped kernel for one (n_pad, realtime) shape class.
 
     Takes ``src/dst [B, 3, E]`` and ``invoke/complete [B, N]``; returns
-    ``[B, len(FLAG_NAMES)]`` bools.  Edge-count ``E`` may vary between
-    calls (jit retraces per shape; e_pad is quantized to multiples of 64
-    by graphs.pack_group to bound the variant count)."""
-    n_iters = closure_rounds(n_pad)
+    ``flags [B, len(FLAG_NAMES)]`` bools, ``rounds [B, 3]`` int32 (the
+    squarings each of a lane's closures needed, ``CLOSURE_NAMES`` order)
+    and ``summary [4]`` int32: the flags set over the group, then each
+    closure's most rounds over its lanes, which is what the device ran
+    for every lane (a closure's loop runs until its last lane stops).  One
+    read of ``summary`` tells the host whether any flag is set and what
+    the group cost.  Edge-count ``E`` may vary between calls (jit
+    retraces per shape; e_pad is quantized to multiples of 64 by
+    graphs.pack_group to bound the variant count)."""
+    cap = closure_rounds(n_pad)
 
-    def lane(src, dst, invoke, complete):
+    def layers(src, dst, invoke, complete):
         with jax.named_scope("elle.layers"):
             ww = _layer(src[0], dst[0], n_pad)
             wr = _layer(src[1], dst[1], n_pad)
@@ -95,10 +138,9 @@ def lane_flags_fn(n_pad: int, realtime: bool):
             nonrw = jnp.minimum(ww + wr + rt, 1.0)
             full = jnp.minimum(nonrw + rw, 1.0)
             g0_adj = jnp.minimum(ww + rt, 1.0)
-        with jax.named_scope("elle.closure"):
-            cl_full = transitive_closure(full, n_iters)
-            cl_nonrw = transitive_closure(nonrw, n_iters)
-            cl_g0 = transitive_closure(g0_adj, n_iters)
+        return g0_adj, nonrw, full, rw
+
+    def flags(cl_g0, cl_nonrw, cl_full, rw):
         with jax.named_scope("elle.flags"):
             cyclic = jnp.trace(cl_full) > 0
             g0 = jnp.trace(cl_g0) > 0
@@ -108,4 +150,18 @@ def lane_flags_fn(n_pad: int, realtime: bool):
             g_single = jnp.sum(rw * cl_nonrw.T) > 0
             return jnp.stack([cyclic, g0, g1c, g_single])
 
-    return jax.jit(jax.vmap(lane))
+    def group(src, dst, invoke, complete):
+        g0_adj, nonrw, full, rw = jax.vmap(layers)(src, dst, invoke,
+                                                   complete)
+        # three loops over the group's lanes, each stopping on its own
+        with jax.named_scope("elle.closure"):
+            cl_g0, k_g0 = transitive_closure(g0_adj, cap)
+            cl_nonrw, k_nonrw = transitive_closure(nonrw, cap)
+            cl_full, k_full = transitive_closure(full, cap)
+        out = jax.vmap(flags)(cl_g0, cl_nonrw, cl_full, rw)
+        rounds = jnp.stack([k_g0, k_nonrw, k_full], axis=1)
+        summary = jnp.concatenate([jnp.sum(out, dtype=jnp.int32)[None],
+                                   jnp.max(rounds, axis=0)])
+        return out, rounds, summary
+
+    return jax.jit(group)

@@ -66,8 +66,8 @@ _STATS_LOCK = threading.Lock()
 
 def _zero_stats() -> Dict[str, int]:
     return {"calls": 0, "lanes": 0, "groups": 0, "n_pad": 0, "e_pad": 0,
-            "closure_rounds": 0, "layer_builds": 0, "cyclic_lanes": 0,
-            "recoveries": 0, "fallbacks": 0}
+            "closure_rounds": 0, "closure_rounds_cap": 0, "layer_builds": 0,
+            "cyclic_lanes": 0, "recoveries": 0, "fallbacks": 0}
 
 
 _STATS = _zero_stats()
@@ -76,11 +76,15 @@ _STATS = _zero_stats()
 def elle_stats() -> Dict[str, int]:
     """Sums over every :func:`check_batch` of this process: ``calls``;
     ``lanes`` (histories checked); ``groups`` (device dispatches);
-    ``closure_rounds`` (squarings run, over the three closures of every
-    dispatched lane, a mesh's padding lanes too) and ``layer_builds``
-    (one-hot products run, three a dispatched lane): with ``n_pad`` and
-    ``e_pad``, the shapes of the last dispatch, the work the device was
-    handed; ``cyclic_lanes`` (lanes the device flagged cyclic);
+    ``closure_rounds`` (squarings the device ran, counted by the kernel and
+    read back with the flags: for each of a group's three closures its
+    lanes' most, times its lanes, a mesh's padding lanes too; a group that
+    fell back to the host adds none) beside ``closure_rounds_cap`` (what the
+    same groups would run at the cap, ``closure.closure_rounds``, every
+    squaring: the ratio says how often the closures stop early) and
+    ``layer_builds`` (one-hot products run, three a dispatched lane): with
+    ``n_pad`` and ``e_pad``, the shapes of the last dispatch, the work the
+    device was handed; ``cyclic_lanes`` (lanes the device flagged cyclic);
     ``recoveries`` (lanes whose cycles the host then searched for: those,
     and every lane that had no device flags); ``fallbacks`` (groups a
     device error sent to the host)."""
@@ -211,8 +215,9 @@ def _device_flags_pipelined(groups, n_pad: int, realtime: bool, mesh,
     the host never blocks between dispatches, and spends the time on the
     second half of each dispatched lane's host pass (``finish_analysis``:
     nothing in it bears on what the device was handed).  Each group's
-    readback is ONE fused scalar (the flag sum, computed device-side); the per-lane
-    ``[b, 4]`` flag array transfers only for groups where it is nonzero.
+    readback is ONE small vector the kernel computes (the flag sum, then
+    the squarings each closure ran); the per-lane ``[b, 4]`` flag array
+    transfers only for groups where the sum is nonzero.
     A zero sum means the device proved every lane anomaly-free, so the
     all-False flags are synthesized host-side — same verdicts, O(1)
     device→host traffic on the (dominant) clean path.  All groups share
@@ -224,6 +229,7 @@ def _device_flags_pipelined(groups, n_pad: int, realtime: bool, mesh,
     loop."""
     from collections import deque
 
+    from jepsen_tpu.elle_tpu import closure
     from jepsen_tpu.parallel.megabatch import staging_depth_default
 
     depth = staging_depth_default()
@@ -234,12 +240,13 @@ def _device_flags_pipelined(groups, n_pad: int, realtime: bool, mesh,
         gchain[gi] = [chain_entry("elle-tpu", e)]
 
     def _drain():
-        gi, b, flags_dev, summ_dev = inflight.popleft()
+        gi, b, b_pad, flags_dev, summ_dev = inflight.popleft()
         try:
-            # the host blocked on the chip: the flag sum is ready when the
+            # the host blocked on the chip: the summary is ready when the
             # group's closures are done
             with span("elle.readback", group=gi, lanes=b) as sp:
-                total = int(np.asarray(summ_dev))
+                summ = np.asarray(summ_dev)
+                total = int(summ[0])
                 if total == 0:
                     gflags[gi] = np.zeros((b, 4), bool)
                 else:
@@ -247,12 +254,16 @@ def _device_flags_pipelined(groups, n_pad: int, realtime: bool, mesh,
                 sp.set(flags_set=total)
         except Exception as e:  # noqa: BLE001 — runtime device trouble
             _fail(gi, b, e)
+            return
+        _count(closure_rounds=b_pad * int(summ[1:].sum()),
+               closure_rounds_cap=b_pad * closure.CLOSURES_PER_LANE
+               * closure.closure_rounds(n_pad))
 
     for gi, group in enumerate(groups):
         try:
-            flags_dev, summ_dev = _device_flags_async(
+            b_pad, flags_dev, summ_dev = _device_flags_async(
                 group, n_pad, realtime, mesh, axis)
-            inflight.append((gi, len(group), flags_dev, summ_dev))
+            inflight.append((gi, len(group), b_pad, flags_dev, summ_dev))
         except Exception as e:  # noqa: BLE001 — dispatch-time trouble
             _fail(gi, len(group), e)
         # the host's own work on this group, while its closures run
@@ -265,9 +276,10 @@ def _device_flags_pipelined(groups, n_pad: int, realtime: bool, mesh,
 
 def _device_flags_async(group: Sequence[EncodedHistory], n_pad: int,
                         realtime: bool, mesh, axis: str):
-    """Enqueue one vmapped dispatch over a lane group; returns the
-    un-read device ``[b_pad, 4]`` flag array plus its fused scalar sum —
-    no host sync happens here (JAX async dispatch)."""
+    """Enqueue one vmapped dispatch over a lane group; returns ``b_pad``
+    (the lanes dispatched) and the un-read device ``[b_pad, 4]`` flag
+    array and ``[4]`` summary — no host sync happens here (JAX async
+    dispatch)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -289,17 +301,14 @@ def _device_flags_async(group: Sequence[EncodedHistory], n_pad: int,
         e_pad = packed["src"].shape[2]
         sp.set(e_pad=e_pad, bytes=sum(v.nbytes for v in packed.values()))
     with span("elle.dispatch", lanes=b_pad, n_pad=n_pad, e_pad=e_pad):
-        flags = _timed_lane_flags(n_pad, realtime)(
+        flags, _, summ = _timed_lane_flags(n_pad, realtime)(
             arrays["src"], arrays["dst"],
             arrays["invoke"], arrays["complete"])
-        summ = jnp.sum(flags)
     with _STATS_LOCK:
         _STATS["groups"] += 1
         _STATS["n_pad"], _STATS["e_pad"] = n_pad, e_pad
-        _STATS["closure_rounds"] += (b_pad * closure.CLOSURES_PER_LANE
-                                     * closure.closure_rounds(n_pad))
         _STATS["layer_builds"] += b_pad * closure.LAYER_BUILDS_PER_LANE
-    return flags, summ
+    return b_pad, flags, summ
 
 
 @lru_cache(maxsize=None)

@@ -2,15 +2,16 @@
 
 The epoch monitor's elle side re-checks a growing prefix every epoch.
 The cold kernel (:mod:`jepsen_tpu.elle_tpu.closure`) closes each epoch's
-adjacency from scratch — ``ceil(log2 N)`` boolean squarings of an
-``[N, N]`` matrix — so per-epoch cost grows with history length.  But
+adjacency from scratch — boolean squarings of an ``[N, N]`` matrix
+until the whole graph's longest shortest path is closed, up to
+``ceil(log2 N)`` — so per-epoch cost grows with history length.  But
 the closure is monotone under edge appends: for ``S ⊇ A``,
 
     closure(S) = closure(closure(A) ∨ S)
 
 so seeding the squaring loop with the *previous epoch's closed matrix*
 OR'd over the current layers converges in however many doublings the
-NEW paths need (typically one or two), not ``log2 N``.  The three
+NEW paths need (typically one or two), not the whole graph's.  The three
 closed matrices (full / nonrw / g0) stay resident on device between
 epochs; per-anomaly flags are read off the extended matrices exactly as
 the cold lane computes them, and the result dict is assembled by the
@@ -96,18 +97,26 @@ def _seed_fn(n_pad: int, realtime: bool):
     return jax.jit(seed)
 
 
+def set_cells(r: jnp.ndarray) -> jnp.ndarray:
+    """The nonzero cells of a 0/1 matrix, as an exact int32 count."""
+    return jnp.sum(r > 0, dtype=jnp.int32)
+
+
 @lru_cache(maxsize=None)
 def _square_fn(n_pad: int):
-    """Two path-doubling rounds over the three matrices plus their sums
-    (the host's convergence probe: a closed 0/1 matrix is a fixpoint of
-    ``min(R + R@R, 1)`` iff its sum stops growing — monotone, exact)."""
+    """Two path-doubling rounds over the three matrices plus their counts
+    of set cells (the host's convergence probe: R only grows, so a 0/1
+    matrix is a fixpoint of ``min(R + R@R, 1)`` iff its count stops
+    growing; an int32 count, exact as the kernel's cell comparison is,
+    since a float32 sum cannot see a few more cells past 2^24)."""
 
     def sq(a, b, c):
         for _ in range(2):
             a = jnp.minimum(a + a @ a, 1.0)
             b = jnp.minimum(b + b @ b, 1.0)
             c = jnp.minimum(c + c @ c, 1.0)
-        return a, b, c, jnp.stack([a.sum(), b.sum(), c.sum()])
+        return a, b, c, jnp.stack([set_cells(a), set_cells(b),
+                                   set_cells(c)])
 
     return jax.jit(sq)
 
@@ -277,10 +286,11 @@ class IncrementalElleEngine(ElleEpochEngine):
         flags = np.asarray(_flags_fn(n_pad)(m_full, m_nonrw, m_g0, rw))
 
         if oracle_enabled():
-            cold = np.asarray(lane_flags_fn(n_pad, self.realtime)(
+            cold_flags, _, _ = lane_flags_fn(n_pad, self.realtime)(
                 jnp.asarray(src)[None], jnp.asarray(dst)[None],
                 jnp.asarray(enc.invoke[None]),
-                jnp.asarray(enc.complete[None])))[0]
+                jnp.asarray(enc.complete[None]))
+            cold = np.asarray(cold_flags)[0]
             if not np.array_equal(flags.astype(bool), cold.astype(bool)):
                 self.oracle_mismatches += 1
                 flags = cold    # the cold kernel wins — it IS the oracle

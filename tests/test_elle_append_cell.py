@@ -13,6 +13,9 @@ import os
 import random
 import sys
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -31,6 +34,8 @@ from jepsen_tpu import core, elle_tpu  # noqa: E402
 from jepsen_tpu.checker.elle import ElleListAppend  # noqa: E402
 from jepsen_tpu.elle import list_append  # noqa: E402
 from jepsen_tpu.elle_tpu import closure, engine  # noqa: E402
+from jepsen_tpu.elle_tpu.graphs import pack_group  # noqa: E402
+from jepsen_tpu.history import INVOKE, OK, History, Op  # noqa: E402
 from jepsen_tpu.workloads import cycle  # noqa: E402
 
 CELL = "elle-append10k.offline"
@@ -411,8 +416,8 @@ def test_elle_stats_count_the_shapes_and_the_rounds():
     engine.reset_elle_stats()
     assert engine.elle_stats() == {
         "calls": 0, "lanes": 0, "groups": 0, "n_pad": 0, "e_pad": 0,
-        "closure_rounds": 0, "layer_builds": 0, "cyclic_lanes": 0,
-        "recoveries": 0, "fallbacks": 0}
+        "closure_rounds": 0, "closure_rounds_cap": 0, "layer_builds": 0,
+        "cyclic_lanes": 0, "recoveries": 0, "fallbacks": 0}
     clean = program_history(records(150, 1))
     bad = program_history(records(150, 1, "stale_read"))
     enc = elle_tpu.encode(clean)
@@ -423,17 +428,203 @@ def test_elle_stats_count_the_shapes_and_the_rounds():
     assert enc.n <= n_pad < enc.n + 32
     assert stats["n_pad"] == n_pad and stats["e_pad"] % 64 == 0
     assert stats["e_pad"] >= enc.src.shape[1]
-    rounds = closure.closure_rounds(n_pad)
-    assert rounds == 8
+    cap = closure.closure_rounds(n_pad)
+    assert cap == 8
+    # the device ran each closure for its slowest lane, on every lane
+    packed = pack_group([enc, elle_tpu.encode(bad), enc], n_pad=n_pad)
+    most = np.max([np_lane(packed, i, n_pad, True)[1] for i in range(3)],
+                  axis=0)
+    assert 3 <= most.min() and most.max() < cap
     assert stats == {
         "calls": 1, "lanes": 3, "groups": 1, "n_pad": n_pad,
-        "e_pad": stats["e_pad"], "closure_rounds": 3 * 3 * rounds,
-        "layer_builds": 3 * 3, "cyclic_lanes": 1, "recoveries": 1,
-        "fallbacks": 0}
+        "e_pad": stats["e_pad"], "closure_rounds": 3 * int(most.sum()),
+        "closure_rounds_cap": 3 * 3 * cap, "layer_builds": 3 * 3,
+        "cyclic_lanes": 1, "recoveries": 1, "fallbacks": 0}
     elle_tpu.check(clean, engine="cpu")
     after = engine.elle_stats()
     assert (after["calls"], after["lanes"], after["groups"],
-            after["recoveries"]) == (2, 4, 1, 2)
+            after["recoveries"], after["closure_rounds"]) == (
+        2, 4, 1, 2, stats["closure_rounds"])
+
+
+def test_a_group_that_falls_back_adds_no_rounds(monkeypatch):
+    engine.reset_elle_stats()
+
+    def unreadable(n_pad, realtime):
+        def run(*args):
+            class Summary:
+                def __array__(self, dtype=None, copy=None):
+                    raise RuntimeError("the read-back failed")
+            return None, None, Summary()
+        return run
+    monkeypatch.setattr(engine, "_timed_lane_flags", unreadable)
+    got = elle_tpu.check(program_history(records(50, 0)), realtime=True)
+    assert got["analyzer"] == "elle-cpu" and "fallback-chain" in got
+    stats = engine.elle_stats()
+    assert (stats["groups"], stats["fallbacks"], stats["closure_rounds"],
+            stats["closure_rounds_cap"]) == (1, 1, 0, 0)
+
+
+# -- the kernel's stop: each closure squares until it stops changing ---------
+
+def np_closure(adj, cap):
+    """``adj`` closed by squaring until a squaring changes nothing (that
+    one counted) or ``cap`` have run: ``(closure, squarings)``."""
+    r, k = adj.astype(bool), 0
+    while k < cap:
+        nxt = r | ((r.astype(np.int64) @ r.astype(np.int64)) > 0)
+        k += 1
+        if (nxt == r).all():
+            break
+        r = nxt
+    return r, k
+
+
+def np_lane(packed, i, n_pad, realtime):
+    """One packed lane's four flags and its closures' squarings
+    (``closure.CLOSURE_NAMES`` order), from the arrays alone."""
+    layers = []
+    for kind in range(3):
+        m = np.zeros((n_pad, n_pad), bool)
+        for s, d in zip(packed["src"][i, kind], packed["dst"][i, kind]):
+            if s >= 0:
+                m[s, d] = True
+        layers.append(m)
+    ww, wr, rw = layers
+    inv, comp = packed["invoke"][i], packed["complete"][i]
+    rt = ((comp[:, None] < inv[None, :]) & (inv[None, :] >= 0)
+          if realtime else np.zeros_like(ww))
+    cap = closure.closure_rounds(n_pad)
+    cl_g0, k_g0 = np_closure(ww | rt, cap)
+    cl_nonrw, k_nonrw = np_closure(ww | wr | rt, cap)
+    cl_full, k_full = np_closure(ww | wr | rw | rt, cap)
+    flags = [bool(cl_full.diagonal().any()), bool(cl_g0.diagonal().any()),
+             bool(cl_nonrw.diagonal().any()), bool((rw & cl_nonrw.T).any())]
+    return flags, [k_g0, k_nonrw, k_full]
+
+
+@pytest.mark.parametrize("realtime", [True, False],
+                         ids=["realtime", "no-realtime"])
+@pytest.mark.parametrize("variant", [None, "stale_read", "late_reader"],
+                         ids=lambda v: v or "clean")
+@pytest.mark.parametrize("n,seed", [(120, 1), (250, 3)])
+def test_kernel_stops_at_the_fixpoint(n, seed, variant, realtime):
+    """Flags and squarings of the kernel against numpy's closure run to
+    its fixpoint: the confirming squaring counted, none past the cap."""
+    enc = elle_tpu.encode(program_history(records(n, seed, variant)))
+    n_pad = 288                    # cap 9, and room for every path here
+    packed = pack_group([enc], n_pad=n_pad)
+    flags, rounds, summary = closure.lane_flags_fn(n_pad, realtime)(
+        *(packed[k] for k in ("src", "dst", "invoke", "complete")))
+    want_flags, want_rounds = np_lane(packed, 0, n_pad, realtime)
+    assert np.asarray(flags)[0].tolist() == want_flags
+    assert np.asarray(rounds)[0].tolist() == want_rounds
+    assert np.asarray(summary).tolist() == [sum(want_flags)] + want_rounds
+    assert rounds.dtype == summary.dtype == np.int32
+    assert all(k < closure.closure_rounds(n_pad) for k in want_rounds)
+    if variant is not None and realtime:
+        assert want_flags[0] and want_flags[3]      # cyclic, g-single
+
+
+@pytest.mark.parametrize("n_pad", [32, 64])
+def test_a_path_through_every_node_runs_the_cap_and_is_closed(n_pad):
+    cap = closure.closure_rounds(n_pad)
+    path = np.eye(n_pad, k=1, dtype=np.float32)      # 0 -> 1 -> ... -> n-1
+    closed, k = jax.jit(closure.transitive_closure,
+                        static_argnums=1)(path, cap)
+    assert int(k) == cap
+    assert np.array_equal(np.asarray(closed),
+                          np.triu(np.ones((n_pad, n_pad), np.float32), 1))
+    short, k = jax.jit(closure.transitive_closure,
+                       static_argnums=1)(path, cap - 1)
+    assert int(k) == cap - 1 and np.asarray(short).sum() < closed.sum()
+    # the lane: the path in ww, closed into a ring by one wr edge
+    src = np.full((1, 3, 64), -1, np.int32)
+    dst = np.full((1, 3, 64), -1, np.int32)
+    src[0, 0, :n_pad - 1], dst[0, 0, :n_pad - 1] = \
+        np.arange(n_pad - 1), np.arange(1, n_pad)
+    src[0, 1, 0], dst[0, 1, 0] = n_pad - 1, 0
+    times = np.full((1, n_pad), -1, np.int32)
+    flags, rounds, summary = closure.lane_flags_fn(n_pad, False)(
+        src, dst, times, times)
+    # cyclic and g1c: the ring's n_pad edges; not g0 (no wr there)
+    assert np.asarray(flags)[0].tolist() == [True, False, True, False]
+    assert np.asarray(rounds)[0].tolist() == [cap] * 3
+    assert np.asarray(summary).tolist() == [2, cap, cap, cap]
+
+
+def append_chain(n):
+    """``n`` appends to one key, one after another, then a read of all:
+    a ww path of ``n - 1`` edges and a wr edge to the reader."""
+    ops = []
+    for i in range(1, n + 1):
+        ops += [Op(process=i % 5, type=INVOKE, f="txn",
+                   value=[["append", "x", i]]),
+                Op(process=i % 5, type=OK, f="txn",
+                   value=[["append", "x", i]])]
+    read = [["r", "x", list(range(1, n + 1))]]
+    ops += [Op(process=0, type=INVOKE, f="txn", value=read),
+            Op(process=0, type=OK, f="txn", value=read)]
+    return History(ops, reindex=True)
+
+
+def test_a_group_costs_its_slowest_lanes_rounds_on_every_lane():
+    """Vmapped, a loop runs until its last lane stops: the short lane's
+    own rounds stay its own, the device's work is the long lane's."""
+    long, short = append_chain(24), append_chain(2)
+    n_pad = 256                                   # cap 8
+    encs = [elle_tpu.encode(long), elle_tpu.encode(short)]
+    packed = pack_group(encs, n_pad=n_pad)
+    _, rounds, summary = closure.lane_flags_fn(n_pad, False)(
+        *(packed[k] for k in ("src", "dst", "invoke", "complete")))
+    want = [np_lane(packed, i, n_pad, False)[1] for i in range(2)]
+    assert want == [[6, 6, 6], [1, 2, 2]]   # paths of 23, 24; 1, 2 edges
+    assert np.asarray(rounds).tolist() == want
+    assert np.asarray(summary).tolist() == [0, 6, 6, 6]
+    engine.reset_elle_stats()
+    res = elle_tpu.check_batch([long, short], n_pad_floor=n_pad)
+    assert [r["valid"] for r in res] == [True, True]
+    stats = engine.elle_stats()
+    assert stats["n_pad"] == n_pad
+    assert (stats["closure_rounds"], stats["closure_rounds_cap"]) == (
+        2 * 18, 2 * 3 * 8)
+
+
+def test_a_sharded_group_runs_its_slowest_lane_on_every_shard():
+    """Over a mesh the lanes are padded to the shards and the loop stops
+    when the last lane of any shard does: the padding lanes count too."""
+    from jax.sharding import Mesh
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    hs = [append_chain(24), append_chain(2), append_chain(5)]
+    engine.reset_elle_stats()
+    res = elle_tpu.check_batch(hs, mesh=mesh, n_pad_floor=256)
+    assert [r["valid"] for r in res] == [True] * 3
+    assert [r["device-flags"]["cyclic"] for r in res] == [False] * 3
+    stats = engine.elle_stats()
+    assert (stats["closure_rounds"], stats["closure_rounds_cap"]) == (
+        4 * 18, 4 * 3 * 8)
+
+
+def test_the_change_test_sees_one_cell_past_two_to_the_24():
+    """One more set cell among 4,097^2 (over 2^24, where float32 steps by
+    2) is seen by the kernel's test and by the stream checker's count."""
+    from jepsen_tpu.elle_tpu import incremental
+    n = 4097
+    full = jnp.ones((n, n), jnp.float32)
+    less = full.at[n - 1, 0].set(0.0)
+    assert n * n > 2 ** 24
+    grew = jax.jit(closure.grew)
+    assert bool(grew(full, less)) and not bool(grew(full, full))
+    assert grew(full[None], less[None]).shape == (1,)
+    count = jax.jit(incremental.set_cells)
+    assert count(full).dtype == jnp.int32
+    assert int(count(full)) - int(count(less)) == 1
+    square = jax.eval_shape(incremental._square_fn(32),
+                            *[jax.ShapeDtypeStruct((32, 32), jnp.float32)]
+                            * 3)
+    assert square[3].dtype == jnp.int32
 
 
 @pytest.mark.parametrize("variant", ["stale_read", "late_reader"])
@@ -527,6 +718,27 @@ def test_flops_against_a_hand_count():
     assert closure_roofline.read(dict(ctx, device={}), **args) is None
 
 
+def test_closure_rounds_share_reads_the_rounds_over_the_cap(monkeypatch):
+    """``kernels.closure_rounds_share``: the squarings run over those the
+    cap allows, as a share; nothing on a program without the cap's key."""
+    spec = next(m for m in Cell(CELL).per_layer()
+                if m["name"] == "kernels.closure_rounds_share")
+    assert (spec["reader"], spec["unit"], spec["better"]) == (
+        "program_stats", "%", "lower")
+    read = plugin("readers", spec["reader"], "read")
+    engine.reset_elle_stats()
+    assert read({}, **spec["args"]) is None                  # no call yet
+    with engine._STATS_LOCK:
+        engine._STATS.update(calls=2, closure_rounds=2 * 12,
+                             closure_rounds_cap=2 * 42)
+    assert read({}, **spec["args"]) == pytest.approx(100 * 12 / 42)
+    parent = {k: v for k, v in engine.elle_stats().items()
+              if k != "closure_rounds_cap"}
+    monkeypatch.setattr(engine, "elle_stats", lambda: parent)
+    assert read({}, **spec["args"]) is None
+    engine.reset_elle_stats()
+
+
 def test_the_cells_files_load_through_the_manifest():
     cell = Cell(CELL)
     assert cell.chips == 1
@@ -540,7 +752,8 @@ def test_the_cells_files_load_through_the_manifest():
     assert all(m["moves"] in ("verdict_s", "setup_s")
                for m in cell.per_layer())
     assert {"elle.host_pass_s", "elle.readback_wait_share",
-            "kernels.closure_mxu_share", "entry.host_answers",
+            "kernels.closure_mxu_share", "kernels.closure_rounds_share",
+            "entry.host_answers",
             "device.idle_share", "device.peak_hbm_bytes",
             "drivers.launches_per_call", "compile.window_compiles",
             "compile.setup_cache_misses", "setup.warmup_excess_s",
