@@ -40,6 +40,15 @@ class ElleChecker(Checker):
         # that speaks of it is asked for, unless the caller says otherwise
         self.realtime = (consistency.needs_realtime(consistency_models)
                          if realtime is None else realtime)
+        if (workload == "rw-register"
+                and workload_kw.get("linearizable_keys") is None):
+            # strict serializability holds each key linearizable, so a
+            # register's version order may follow its writes' realtime
+            # order wherever that model is judged (the engine's default
+            # with the realtime order and no models named)
+            workload_kw["linearizable_keys"] = (
+                self.realtime if consistency_models is None
+                else consistency.needs_realtime(consistency_models))
         self.consistency_models = consistency_models
         self.budget_s = budget_s
         self.workload_kw = workload_kw

@@ -24,8 +24,10 @@ from __future__ import annotations
 
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import (Any, Dict, Iterator, List, Optional, Sequence, Set,
-                    Tuple)
+from functools import cached_property
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+
+import numpy as np
 
 from jepsen_tpu.elle import consistency
 from jepsen_tpu.elle.graph import (Graph, SearchBudget, cycle_edge_kinds,
@@ -130,15 +132,30 @@ class Analysis:
     shared front half of the CPU checker and the elle_tpu encoder — both
     paths literally analyze the same object, which is what makes their
     anomaly sets identical by construction."""
-    graph: Graph
     txn_of: Dict[int, List]
     anomalies: Dict[str, List[Any]] = field(default_factory=dict)
     oks: List[Tuple[int, Op]] = field(default_factory=list)
     pairs: Sequence[int] = ()
+    #: the ww/wr/rw edges as ``Dependencies.edges`` has them: three ints an
+    #: edge, the kind as its place in ``EDGE_KINDS``
+    edges: Sequence[int] = ()
 
     @property
     def count(self) -> int:
         return len(self.oks)
+
+    @cached_property
+    def graph(self) -> Graph:
+        """The edges as a :class:`Graph`, built on first use: a lane the
+        device proved acyclic and valid never asks for it."""
+        g = Graph()
+        for tid in range(self.count):
+            g.add_node(tid)
+        it = iter(self.edges.tolist() if isinstance(self.edges, np.ndarray)
+                  else self.edges)
+        for a, b, kind in zip(it, it, it):
+            g.add_edge(a, b, EDGE_KINDS[kind])
+        return g
 
 
 def add_realtime_edges(g: Graph, oks: List[Tuple[int, Op]],
@@ -200,7 +217,7 @@ class Dependencies:
     version order and the ww/wr/rw edges in the order the graph takes
     them.  It is all a device needs before its closures can start;
     :func:`analysis_of` makes the :class:`Analysis` of it (the host
-    anomalies, the graph as an object), and may do so while they run."""
+    anomalies), and may do so while they run."""
     oks: List[Tuple[int, Op]]
     pairs: Sequence[int]
     txn_of: Dict[int, List]
@@ -213,10 +230,6 @@ class Dependencies:
     #: its place in ``EDGE_KINDS`` (flat, so that 45,000 edges are no
     #: 45,000 tuples for the collector to walk); an edge may repeat
     edges: List[int]
-
-    def edge_triples(self) -> Iterator[Tuple[int, int, int]]:
-        it = iter(self.edges)
-        return zip(it, it, it)
 
     @property
     def count(self) -> int:
@@ -329,7 +342,8 @@ def dependencies(history: History) -> Dependencies:
 
 def analysis_of(d: Dependencies) -> Analysis:
     """The second half of the host pass: the host anomalies (duplicates,
-    G1a, incompatible orders, G1b) and the dependency graph as an object."""
+    G1a, incompatible orders, G1b); the graph follows from the edges on
+    first use (:attr:`Analysis.graph`)."""
     anomalies: Dict[str, List[Any]] = defaultdict(list)
     if d.duplicates:
         anomalies["duplicate-appends"].extend(d.duplicates)
@@ -370,13 +384,8 @@ def analysis_of(d: Dependencies) -> Analysis:
             anomalies["G1b"].append({"key": k, "value": last,
                                      "reader": op.to_dict()})
 
-    g = Graph()
-    for tid in range(len(d.oks)):
-        g.add_node(tid)
-    for a, b, kind in d.edge_triples():
-        g.add_edge(a, b, EDGE_KINDS[kind])
-    return Analysis(graph=g, txn_of=d.txn_of, anomalies=anomalies,
-                    oks=d.oks, pairs=d.pairs)
+    return Analysis(txn_of=d.txn_of, anomalies=anomalies, oks=d.oks,
+                    pairs=d.pairs, edges=d.edges)
 
 
 def analyze(history: History) -> Analysis:

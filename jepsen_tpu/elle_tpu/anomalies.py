@@ -6,7 +6,8 @@ human-readable comes from the CPU machinery, run *only when needed*:
 - acyclic lane: no cycle search at all.  The host anomalies from
   ``analyze`` (G1a/G1b/duplicates/...) plus empty cycle families are
   exactly what the CPU checker would have produced (its searches find
-  nothing in an acyclic graph), so the results agree without the work.
+  nothing in an acyclic graph), so the results agree without the work;
+  a valid one never builds the graph as an object either.
 - cyclic lane: materialize the realtime layer (if strict mode) and run
   the same ``collect_cycle_anomalies`` suite over the same graph the CPU
   checker uses — identical witnesses, identical labels.
@@ -62,10 +63,11 @@ def finish_lane(enc: EncodedHistory,
             res["device-flags"] = {name: bool(v)
                                    for name, v in zip(FLAG_NAMES, flags)}
         # Complete edge list for artifact rendering (popped by
-        # elle.render.write_artifacts).  On an acyclic strict-mode lane the
-        # dense realtime layer was never materialized host-side — the list
-        # then carries the ww/wr/rw core only, as ``finish_analysis`` made
-        # it.
-        res["edges-full"] = (edge_list(a.graph) if recover and realtime
-                             else enc.edge_list)
+        # elle.render.write_artifacts, which writes none for a valid
+        # result: so an acyclic, valid lane builds no graph).  On an
+        # acyclic strict-mode lane the dense realtime layer was never
+        # materialized host-side — the list then carries the ww/wr/rw core
+        # only.
+        if res["valid"] is not True:
+            res["edges-full"] = edge_list(a.graph)
     return res

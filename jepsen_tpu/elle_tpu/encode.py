@@ -1,14 +1,18 @@
 """History -> dense device tensors for the elle_tpu engine.
 
 The encoder is deliberately thin: it runs the *CPU checker's own* host
-pass (``elle.list_append.analyze`` / ``elle.rw_register.analyze``) and
-merely re-shapes its dependency graph into fixed-kind edge arrays, plus
-the invoke/complete index vectors the device needs to rebuild the
-realtime order as a broadcast comparison.  Sharing the host pass is the
-parity argument's foundation — both tiers literally analyze the same
-``Analysis`` object (see the package docstring).  The list-append pass
-comes in two halves: its ``Dependencies`` are enough to encode, and the
-rest (``analysis_of``) can follow the dispatch.
+pass (``elle.list_append`` / ``elle.rw_register``) and merely re-shapes
+its dependency edges into fixed-kind edge arrays, plus the
+invoke/complete index vectors the device needs to rebuild the realtime
+order as a broadcast comparison.  Sharing the host pass is the parity
+argument's foundation — both tiers literally analyze the same
+``Analysis`` object (see the package docstring).  Each workload's pass
+comes in two halves: its ``Dependencies`` (the ok transactions and the
+ww/wr/rw edges as one flat array) are enough to encode, and the rest
+(the workload's ``analysis_of``: the host anomalies) can follow the
+dispatch.  The graph as an object (``Analysis.graph``) is built from the
+same edges only for a lane that asks for it: a witness search, or an
+invalid result's artifacts.
 
 Encoding:
 
@@ -28,19 +32,22 @@ Encoding:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, List, Optional, Tuple, Union
+from typing import Optional, Union
 
 import numpy as np
 
 from jepsen_tpu.elle import list_append, rw_register
-from jepsen_tpu.elle.graph import edge_list
-from jepsen_tpu.elle.list_append import Analysis, Dependencies
+from jepsen_tpu.elle.list_append import Analysis
 from jepsen_tpu.history import History
 
 #: edge-kind layer order of the ``src``/``dst`` arrays.
 KINDS = list_append.EDGE_KINDS
 
-WORKLOADS = ("list-append", "rw-register")
+#: each workload's host pass: its first half, then its second
+PASSES = {"list-append": (list_append.dependencies, list_append.analysis_of),
+          "rw-register": (rw_register.dependencies, rw_register.analysis_of)}
+
+Dependencies = Union[list_append.Dependencies, rw_register.Dependencies]
 
 #: completion index for padding txn slots: later than any real invocation,
 #: so a padded row emits no realtime edge.
@@ -49,23 +56,19 @@ COMPLETE_PAD = np.int32(2**30)
 
 @dataclass
 class EncodedHistory:
-    """One history's device encoding plus the host ``Analysis`` it came
-    from (kept for witness recovery — the device only answers booleans).
-    An encoding made of a list-append history's ``Dependencies`` holds
-    those, and gets its ``analysis`` from :meth:`finish_analysis`: the
-    second half of the host pass, which the engine runs once the lane's
-    closures are on their way."""
-    analysis: Optional[Analysis]
+    """One history's device encoding, made of the first half of its host
+    pass (``dependencies``), and the host ``Analysis`` (kept for witness
+    recovery — the device only answers booleans), which
+    :meth:`finish_analysis` makes: the second half of the host pass, which
+    the engine runs once the lane's closures are on their way."""
+    dependencies: Dependencies
     workload: str
     src: np.ndarray        # [len(KINDS), E] int32, -1-padded
     dst: np.ndarray        # [len(KINDS), E] int32, -1-padded
     invoke: np.ndarray     # [N] int32, -1 = unknown invocation
     complete: np.ndarray   # [N] int32
     n: int                 # ok transactions
-    dependencies: Optional[Dependencies] = None
-    #: ``edge_list`` of the analysis' graph as :meth:`finish_analysis`
-    #: left it (ww/wr/rw only), for a lane that needs no recovery
-    edge_list: Optional[List[Tuple[Any, Any, List[str]]]] = None
+    analysis: Optional[Analysis] = None
 
     @property
     def n_edges(self) -> int:
@@ -73,31 +76,19 @@ class EncodedHistory:
 
     def finish_analysis(self) -> Analysis:
         if self.analysis is None:
-            self.analysis = list_append.analysis_of(self.dependencies)
-        if self.edge_list is None:
-            self.edge_list = edge_list(self.analysis.graph)
+            self.analysis = PASSES[self.workload][1](self.dependencies)
         return self.analysis
 
 
-def analyze(history: History, workload: str = "list-append",
-            **workload_kw) -> Analysis:
-    """Dispatch to the workload's host pass."""
-    if workload == "list-append":
-        return list_append.analyze(history, **workload_kw)
-    if workload == "rw-register":
-        return rw_register.analyze(history, **workload_kw)
-    raise ValueError(f"unknown elle workload {workload!r}; "
-                     f"known: {WORKLOADS}")
-
-
 def dependencies(history: History, workload: str = "list-append",
-                 **workload_kw) -> Union[Dependencies, Analysis]:
+                 **workload_kw) -> Dependencies:
     """As much of the workload's host pass as has to precede the device:
-    a list-append history's ``Dependencies``; a register history's pass is
-    one piece, so it is the whole ``Analysis``."""
-    if workload == "list-append":
-        return list_append.dependencies(history, **workload_kw)
-    return analyze(history, workload, **workload_kw)
+    its ``Dependencies`` (``rw_register``'s take ``sequential_keys`` and
+    ``linearizable_keys``)."""
+    if workload not in PASSES:
+        raise ValueError(f"unknown elle workload {workload!r}; "
+                         f"known: {tuple(PASSES)}")
+    return PASSES[workload][0](history, **workload_kw)
 
 
 def encode(history: History, workload: str = "list-append",
@@ -109,16 +100,11 @@ def encode(history: History, workload: str = "list-append",
     return enc
 
 
-def encode_analysis(a: Union[Dependencies, Analysis],
-                    workload: str) -> EncodedHistory:
-    if isinstance(a, Dependencies):
-        flat = np.fromiter(a.edges, np.int64, len(a.edges))
-    else:
-        flat = np.fromiter(
-            (x for s, bs in a.graph.out.items() for d, ks in bs.items()
-             for k in ks if k in KINDS for x in (s, d, KINDS.index(k))),
-            np.int64)
-    flat = flat.reshape(-1, 3)
+def encode_analysis(a: Dependencies, workload: str) -> EncodedHistory:
+    edges = a.edges          # a list (list-append) or an array (rw-register)
+    if not isinstance(edges, np.ndarray):
+        edges = np.fromiter(edges, np.int64, len(edges))
+    flat = edges.reshape(-1, 3)
     # a pair is one cell of its kind's layer, however often it was inferred
     pair = flat[:, 0] << 32 | flat[:, 1]
     per = [np.unique(pair[flat[:, 2] == i]) for i in range(len(KINDS))]
@@ -135,8 +121,5 @@ def encode_analysis(a: Union[Dependencies, Analysis],
         done = np.fromiter((i for i, _ in a.oks), np.int64, n)
         complete[:n] = done
         invoke[:n] = np.maximum(np.asarray(a.pairs)[done], -1)
-    half = isinstance(a, Dependencies)
-    return EncodedHistory(analysis=None if half else a,
-                          dependencies=a if half else None,
-                          workload=workload, src=src, dst=dst,
-                          invoke=invoke, complete=complete, n=n)
+    return EncodedHistory(dependencies=a, workload=workload, src=src,
+                          dst=dst, invoke=invoke, complete=complete, n=n)

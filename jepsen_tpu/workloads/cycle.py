@@ -111,7 +111,12 @@ def append_workload(keys: int = 8, consistency_models=None,
 
 def wr_workload(keys: int = 8, consistency_models=None,
                 sequential_keys: bool = False,
-                linearizable_keys: bool = False, **kw) -> Dict[str, Any]:
+                linearizable_keys: Optional[bool] = None,
+                **kw) -> Dict[str, Any]:
+    """wr.clj:9-25: ``consistency_models`` as :func:`append_workload`'s;
+    ``sequential_keys`` and ``linearizable_keys`` are elle's per-key
+    version-order assumptions, ``linearizable_keys`` by default on exactly
+    where a strict model is asked for (it implies them)."""
     return {"generator": wr_gen(keys, **kw),
             "checker": ElleRwRegister(
                 consistency_models=consistency_models,

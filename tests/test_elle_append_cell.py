@@ -374,17 +374,23 @@ def test_the_first_half_encodes_what_the_whole_analysis_would(variant):
     h = program_history(records(120, 4, variant))
     deps = list_append.dependencies(h)
     a = list_append.analysis_of(deps)
-    half, whole = (encode_analysis(x, "list-append") for x in (deps, a))
-    assert half.analysis is None and whole.analysis is a
-    for name in ("src", "dst", "invoke", "complete"):
-        assert (getattr(half, name) == getattr(whole, name)).all(), name
-    assert half.n == whole.n == a.count
-    kinds = {(x, y, list_append.EDGE_KINDS[k])
-             for x, y, k in deps.edge_triples()}
-    assert kinds == {(x, y, k) for x, ys in a.graph.out.items()
-                     for y, ks in ys.items() for k in ks}
+    half = encode_analysis(deps, "list-append")
+    assert half.analysis is None and half.dependencies is deps
+    # the finished analysis' graph, one cell a pair of each kind
+    graph = {(x, y, k) for x, ys in a.graph.out.items()
+             for y, ks in ys.items() for k in ks}
+    assert {(int(x), int(y), list_append.EDGE_KINDS[k]) for k in range(3)
+            for x, y in zip(half.src[k], half.dst[k]) if x >= 0} == graph
+    assert half.n == a.count
+    assert half.complete[:a.count].tolist() == [i for i, _ in a.oks]
+    it = iter(deps.edges)
+    kinds = {(x, y, list_append.EDGE_KINDS[k]) for x, y, k in zip(it, it, it)}
+    assert kinds == graph
+    # the second half builds no graph; the analysis does once asked
     assert half.finish_analysis() is half.finish_analysis()
-    assert half.edge_list == list_append.edge_list(half.analysis.graph)
+    assert "graph" not in vars(half.analysis)
+    assert {(x, y, k) for x, ys in half.analysis.graph.out.items()
+            for y, ks in ys.items() for k in ks} == graph
 
 
 def test_a_repeated_edge_is_one_cell_of_its_layer():
